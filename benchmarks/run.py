@@ -19,6 +19,8 @@ import os
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 BENCHES = {
     "bench_accuracy_proxy": "Tables 1-3 (greedy agreement, logit fidelity)",
     "bench_qkv_ablation": "Table 4 (CHAI-QKV share_values ablation)",
@@ -47,6 +49,7 @@ def main(argv=None):
             print(f"{name:24s} {desc}")
         return 0
     names = args.only.split(",") if args.only else list(BENCHES)
+    enable_compile_cache()
 
     failures, summaries = [], {}
     for name in names:

@@ -306,6 +306,24 @@ def reduced(cfg: ModelConfig, *, n_layers=None, d_model=64, n_heads=None,
     )
 
 
+def with_depth(cfg: ModelConfig, n_layers: int) -> ModelConfig:
+    """The first ``n_layers`` layers of ``cfg`` at its published widths
+    and dtype: the only cut a model this size needs to fit one chip.
+    Every kept layer keeps its mixer / FFN kind and its CHAI cluster
+    count (frozen from the full-depth profile, so a layer clusters the
+    same way at any depth)."""
+    if not 0 < n_layers <= cfg.n_layers:
+        raise ValueError(f"{cfg.name} has {cfg.n_layers} layers; "
+                         f"cannot keep {n_layers}")
+    lt = cfg.layer_types[:n_layers]
+    n_attn = sum(1 for t in lt if t in (ATTN_GLOBAL, ATTN_LOCAL))
+    return dataclasses.replace(
+        cfg, n_layers=n_layers, layer_types=lt,
+        ffn_types=cfg.ffn_types[:n_layers],
+        chai=dataclasses.replace(
+            cfg.chai, cluster_counts=cfg.chai_cluster_counts()[:n_attn]))
+
+
 _REGISTRY: dict = {}
 
 

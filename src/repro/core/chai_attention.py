@@ -43,6 +43,23 @@ from repro.models.layers import apply_rope, rms_norm, softcap
 USE_FUSED_DECODE = True
 
 
+def unfused(fn):
+    """``fn`` with ``USE_FUSED_DECODE`` held False while it runs: wrap a
+    step maker's function before ``jax.jit`` and every decode attention
+    it traces takes the jnp reference path (the flag is read at trace
+    time)."""
+    @functools.wraps(fn)
+    def wrapped(*args):
+        global USE_FUSED_DECODE
+        prev = USE_FUSED_DECODE
+        USE_FUSED_DECODE = False
+        try:
+            return fn(*args)
+        finally:
+            USE_FUSED_DECODE = prev
+    return wrapped
+
+
 def _rope1(x, pos, theta):
     """x: (B, n, hd) single-token heads; pos: (B,)."""
     return apply_rope(x[:, None], pos[:, None], theta)[:, 0]

@@ -397,9 +397,9 @@ def _fused_tile(pos_ref, q_ref, h2c_ref, k_ref, ks_ref, v_ref, vs_ref,
     if vs_ref is not None:   # int8: dequant V rows before the AV dot
         v = v * vs_ref[0].astype(jnp.float32)[..., None]
 
-    h2c = h2c_ref[0]                                     # (H,) int32
+    h2c = h2c_ref[0]                                     # (H, 1) int32
     h_total = h2c.shape[0]
-    oneh = (h2c[:, None] == jax.lax.broadcasted_iota(
+    oneh = (h2c == jax.lax.broadcasted_iota(
         jnp.int32, (h_total, r_total), 1)).astype(jnp.float32)   # (H, R)
 
     if share_values:
@@ -430,8 +430,8 @@ def _fused_tile(pos_ref, q_ref, h2c_ref, k_ref, ks_ref, v_ref, vs_ref,
     def _fin():
         if emit_state:
             m_ref, l_ref, acc_ref = out_refs
-            m_ref[0] = m_scr[:, 0]
-            l_ref[0] = l_scr[:, 0]
+            m_ref[0] = m_scr[...]
+            l_ref[0] = l_scr[...]
             acc_ref[0] = acc_scr[...]
         else:
             (o_ref,) = out_refs
@@ -481,7 +481,49 @@ def _fused_shapes(q_rep, v_rows, h2c, share_values):
         assert h_total % v_rows == 0, (h_total, v_rows)
         v_rep = h_total // v_rows
     rows_acc = r_total if share_values else h_total
+    # h2c rides as an (H, 1) column per slot: a (1, H) row block of a
+    # (B, H) array is not (8, 128)-tileable, and the column compares
+    # against the (H, R) iota without an in-kernel transpose.
+    h2c = h2c.astype(jnp.int32)[..., None]
     return b, r_total, hd, h2c, h_total, v_rep, rows_acc
+
+
+def _tile_major(scales, ts):
+    """(N, rows, S) per-row scales -> (N * S/ts, rows, ts), one block per
+    (slot, S-tile). A (1, rows, ts) block of the (N, rows, S) array is not
+    (8, 128)-tileable when ts < S and ts % 128 != 0; in tile-major order
+    each block spans its array's last two dims (as a paged scale pool's
+    pages already do)."""
+    n, rows, s = scales.shape
+    return (scales.reshape(n, rows, s // ts, ts).transpose(0, 2, 1, 3)
+            .reshape(n * (s // ts), rows, ts))
+
+
+def _fused_outputs(b, r_total, h_total, rows_acc, hd, emit_state):
+    """Output BlockSpecs/shapes for the fused decode (grid-rank agnostic:
+    every output block is slot ``bb``'s whole slab). ``emit_state`` m/l
+    are written as (R, 1) columns — the scratch layout — and squeezed by
+    ``_squeeze_state`` outside the kernel."""
+    def slot(bb, *_):
+        return (bb, 0, 0)
+
+    if not emit_state:
+        return (pl.BlockSpec((1, h_total, hd), slot),
+                jax.ShapeDtypeStruct((b, h_total, hd), jnp.float32))
+    specs = [pl.BlockSpec((1, r_total, 1), slot),
+             pl.BlockSpec((1, r_total, 1), slot),
+             pl.BlockSpec((1, rows_acc, hd), slot)]
+    shapes = [jax.ShapeDtypeStruct((b, r_total, 1), jnp.float32),
+              jax.ShapeDtypeStruct((b, r_total, 1), jnp.float32),
+              jax.ShapeDtypeStruct((b, rows_acc, hd), jnp.float32)]
+    return specs, shapes
+
+
+def _squeeze_state(out, emit_state):
+    if not emit_state:
+        return out
+    m, l, acc = out
+    return m[..., 0], l[..., 0], acc
 
 
 def chai_fused_decode(q_rep, k_cache, v_cache, h2c, pos, *, k_scale=None,
@@ -519,46 +561,32 @@ def chai_fused_decode(q_rep, k_cache, v_cache, h2c, pos, *, k_scale=None,
 
     in_specs = [
         pl.BlockSpec((1, r_total, hd), lambda bb, ss, pos_r: (bb, 0, 0)),
-        pl.BlockSpec((1, h_total), lambda bb, ss, pos_r: (bb, 0)),
+        pl.BlockSpec((1, h_total, 1), lambda bb, ss, pos_r: (bb, 0, 0)),
         pl.BlockSpec((1, kv_k, ts, hd), lambda bb, ss, pos_r:
                      (bb, 0, ss, 0)),
     ]
-    inputs = [q_rep, h2c.astype(jnp.int32), k_cache]
+    inputs = [q_rep, h2c, k_cache]
     if k_scale is not None:
         in_specs.append(pl.BlockSpec((1, kv_k, ts), lambda bb, ss, pos_r:
-                                     (bb, 0, ss)))
-        inputs.append(k_scale)
+                                     (bb * n_tiles + ss, 0, 0)))
+        inputs.append(_tile_major(k_scale, ts))
     in_specs.append(pl.BlockSpec((1, kv_v, ts, hd), lambda bb, ss, pos_r:
                                  (bb, 0, ss, 0)))
     inputs.append(v_cache)
     if v_scale is not None:
         in_specs.append(pl.BlockSpec((1, kv_v, ts), lambda bb, ss, pos_r:
-                                     (bb, 0, ss)))
-        inputs.append(v_scale)
+                                     (bb * n_tiles + ss, 0, 0)))
+        inputs.append(_tile_major(v_scale, ts))
 
-    if emit_state:
-        out_specs = [
-            pl.BlockSpec((1, r_total), lambda bb, ss, pos_r: (bb, 0)),
-            pl.BlockSpec((1, r_total), lambda bb, ss, pos_r: (bb, 0)),
-            pl.BlockSpec((1, rows_acc, hd),
-                         lambda bb, ss, pos_r: (bb, 0, 0)),
-        ]
-        out_shape = [
-            jax.ShapeDtypeStruct((b, r_total), jnp.float32),
-            jax.ShapeDtypeStruct((b, r_total), jnp.float32),
-            jax.ShapeDtypeStruct((b, rows_acc, hd), jnp.float32),
-        ]
-    else:
-        out_specs = pl.BlockSpec((1, h_total, hd),
-                                 lambda bb, ss, pos_r: (bb, 0, 0))
-        out_shape = jax.ShapeDtypeStruct((b, h_total, hd), jnp.float32)
+    out_specs, out_shape = _fused_outputs(b, r_total, h_total, rows_acc,
+                                          hd, emit_state)
     kernel = _fused_arg_router(
         1, k_scale is not None, v_scale is not None,
         n_out=3 if emit_state else 1, scale=scale, ts=ts,
         window=window, n_tiles=n_tiles, reps_per_group=reps_per_group,
         v_rep=v_rep, share_values=share_values, softcap=softcap,
         emit_state=emit_state)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -574,6 +602,7 @@ def chai_fused_decode(q_rep, k_cache, v_cache, h2c, pos, *, k_scale=None,
         out_shape=out_shape,
         interpret=interpret,
     )(pos.astype(jnp.int32), *inputs)
+    return _squeeze_state(out, emit_state)
 
 
 def paged_chai_fused_decode(q_rep, k_pool, bt_k, v_pool, bt_v, h2c, pos, *,
@@ -611,13 +640,13 @@ def paged_chai_fused_decode(q_rep, k_pool, bt_k, v_pool, bt_v, h2c, pos, *,
     in_specs = [
         pl.BlockSpec((1, r_total, hd),
                      lambda bb, ss, pos_r, btk_r, btv_r: (bb, 0, 0)),
-        pl.BlockSpec((1, h_total),
-                     lambda bb, ss, pos_r, btk_r, btv_r: (bb, 0)),
+        pl.BlockSpec((1, h_total, 1),
+                     lambda bb, ss, pos_r, btk_r, btv_r: (bb, 0, 0)),
         pl.BlockSpec((1, kv_k, page, hd),
                      lambda bb, ss, pos_r, btk_r, btv_r:
                      (btk_r[bb, ss], 0, 0, 0)),
     ]
-    inputs = [q_rep, h2c.astype(jnp.int32), k_pool]
+    inputs = [q_rep, h2c, k_pool]
     if k_scale_pool is not None:
         in_specs.append(pl.BlockSpec((1, kv_k, page),
                                      lambda bb, ss, pos_r, btk_r, btv_r:
@@ -633,32 +662,15 @@ def paged_chai_fused_decode(q_rep, k_pool, bt_k, v_pool, bt_v, h2c, pos, *,
                                      (btv_r[bb, ss], 0, 0)))
         inputs.append(v_scale_pool)
 
-    if emit_state:
-        out_specs = [
-            pl.BlockSpec((1, r_total),
-                         lambda bb, ss, pos_r, btk_r, btv_r: (bb, 0)),
-            pl.BlockSpec((1, r_total),
-                         lambda bb, ss, pos_r, btk_r, btv_r: (bb, 0)),
-            pl.BlockSpec((1, rows_acc, hd),
-                         lambda bb, ss, pos_r, btk_r, btv_r: (bb, 0, 0)),
-        ]
-        out_shape = [
-            jax.ShapeDtypeStruct((b, r_total), jnp.float32),
-            jax.ShapeDtypeStruct((b, r_total), jnp.float32),
-            jax.ShapeDtypeStruct((b, rows_acc, hd), jnp.float32),
-        ]
-    else:
-        out_specs = pl.BlockSpec((1, h_total, hd),
-                                 lambda bb, ss, pos_r, btk_r, btv_r:
-                                 (bb, 0, 0))
-        out_shape = jax.ShapeDtypeStruct((b, h_total, hd), jnp.float32)
+    out_specs, out_shape = _fused_outputs(b, r_total, h_total, rows_acc,
+                                          hd, emit_state)
     kernel = _fused_arg_router(
         3, k_scale_pool is not None, v_scale_pool is not None,
         n_out=3 if emit_state else 1, scale=scale,
         ts=page, window=window, n_tiles=n_pages,
         reps_per_group=reps_per_group, v_rep=v_rep,
         share_values=share_values, softcap=softcap, emit_state=emit_state)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -675,6 +687,7 @@ def paged_chai_fused_decode(q_rep, k_pool, bt_k, v_pool, bt_v, h2c, pos, *,
         interpret=interpret,
     )(pos.astype(jnp.int32), bt_k.astype(jnp.int32),
       bt_v.astype(jnp.int32), *inputs)
+    return _squeeze_state(out, emit_state)
 
 
 # --------------------------------------- relay shared-prefix decode --------
@@ -709,8 +722,8 @@ def _relay_prefix_kernel(plen_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
     k = k_ref[0].astype(jnp.float32)                     # (KV, Ts, hd)
     nr, hd = q.shape
     kv = k.shape[0]
-    k_row = krow_ref[0]                                  # (NR,) int32
-    oneh_k = (k_row[:, None] == jax.lax.broadcasted_iota(
+    k_row = krow_ref[0]                                  # (NR, 1) int32
+    oneh_k = (k_row == jax.lax.broadcasted_iota(
         jnp.int32, (nr, kv), 1)).astype(jnp.float32)     # (NR, KV)
     kg = jnp.dot(oneh_k, k.reshape(kv, ts * hd),
                  preferred_element_type=jnp.float32).reshape(nr, ts, hd)
@@ -738,15 +751,15 @@ def _relay_prefix_kernel(plen_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
     v = v_ref[0].astype(jnp.float32)                     # (VR, Ts, hd)
     if vs_ref is not None:
         v = v * vs_ref[0].astype(jnp.float32)[..., None]
-    a_row = arow_ref[0]                                  # (A,) int32
-    v_row = vrow_ref[0]                                  # (A,) int32
+    a_row = arow_ref[0]                                  # (A, 1) int32
+    v_row = vrow_ref[0]                                  # (A, 1) int32
     a_total = a_row.shape[0]
     vr = v.shape[0]
-    oneh_a = (a_row[:, None] == jax.lax.broadcasted_iota(
+    oneh_a = (a_row == jax.lax.broadcasted_iota(
         jnp.int32, (a_total, nr), 1)).astype(jnp.float32)  # (A, NR)
     p_a = jnp.dot(oneh_a, p, preferred_element_type=jnp.float32)
     alpha_a = jnp.dot(oneh_a, alpha, preferred_element_type=jnp.float32)
-    oneh_v = (v_row[:, None] == jax.lax.broadcasted_iota(
+    oneh_v = (v_row == jax.lax.broadcasted_iota(
         jnp.int32, (a_total, vr), 1)).astype(jnp.float32)  # (A, VR)
     vg = jnp.dot(oneh_v, v.reshape(vr, ts * hd),
                  preferred_element_type=jnp.float32).reshape(
@@ -758,8 +771,8 @@ def _relay_prefix_kernel(plen_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
 
     @pl.when(s == n_tiles - 1)
     def _fin():
-        m_ref[0] = m_scr[:, 0]
-        l_ref[0] = l_scr[:, 0]
+        m_ref[0] = m_scr[...]
+        l_ref[0] = l_scr[...]
         acc_ref[0] = acc_scr[...]
 
 
@@ -803,22 +816,24 @@ def relay_prefix_decode(q, k, v, k_row, a_row, v_row, plen, *,
     inputs = [q, k]
     if k_scale is not None:
         in_specs.append(pl.BlockSpec((1, kv, ts), lambda gg, ss, plen_r:
-                                     (gg, 0, ss)))
-        inputs.append(k_scale)
+                                     (gg * n_tiles + ss, 0, 0)))
+        inputs.append(_tile_major(k_scale, ts))
     in_specs.append(pl.BlockSpec((1, vr, ts, hd), lambda gg, ss, plen_r:
                                  (gg, 0, ss, 0)))
     inputs.append(v)
     if v_scale is not None:
         in_specs.append(pl.BlockSpec((1, vr, ts), lambda gg, ss, plen_r:
-                                     (gg, 0, ss)))
-        inputs.append(v_scale)
+                                     (gg * n_tiles + ss, 0, 0)))
+        inputs.append(_tile_major(v_scale, ts))
+    # Row maps ride as (rows, 1) columns and m/l come back as (NR, 1)
+    # columns: (1, rows) blocks of (G, rows) arrays are not
+    # (8, 128)-tileable, and columns match the scratch layout.
     in_specs += [
-        pl.BlockSpec((1, nr), lambda gg, ss, plen_r: (gg, 0)),
-        pl.BlockSpec((1, a_total), lambda gg, ss, plen_r: (gg, 0)),
-        pl.BlockSpec((1, a_total), lambda gg, ss, plen_r: (gg, 0)),
+        pl.BlockSpec((1, nr, 1), lambda gg, ss, plen_r: (gg, 0, 0)),
+        pl.BlockSpec((1, a_total, 1), lambda gg, ss, plen_r: (gg, 0, 0)),
+        pl.BlockSpec((1, a_total, 1), lambda gg, ss, plen_r: (gg, 0, 0)),
     ]
-    inputs += [k_row.astype(jnp.int32), a_row.astype(jnp.int32),
-               v_row.astype(jnp.int32)]
+    inputs += [x.astype(jnp.int32)[..., None] for x in (k_row, a_row, v_row)]
 
     has_ks, has_vs = k_scale is not None, v_scale is not None
 
@@ -837,15 +852,15 @@ def relay_prefix_decode(q, k, v, k_row, a_row, v_row, plen, *,
                              acc_ref, m_scr, l_scr, acc_scr, scale=scale,
                              ts=ts, n_tiles=n_tiles, softcap=softcap)
 
-    return pl.pallas_call(
+    m, l, acc = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(g, n_tiles),
             in_specs=in_specs,
             out_specs=[
-                pl.BlockSpec((1, nr), lambda gg, ss, plen_r: (gg, 0)),
-                pl.BlockSpec((1, nr), lambda gg, ss, plen_r: (gg, 0)),
+                pl.BlockSpec((1, nr, 1), lambda gg, ss, plen_r: (gg, 0, 0)),
+                pl.BlockSpec((1, nr, 1), lambda gg, ss, plen_r: (gg, 0, 0)),
                 pl.BlockSpec((1, a_total, hd), lambda gg, ss, plen_r:
                              (gg, 0, 0)),
             ],
@@ -856,9 +871,10 @@ def relay_prefix_decode(q, k, v, k_row, a_row, v_row, plen, *,
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((g, nr), jnp.float32),
-            jax.ShapeDtypeStruct((g, nr), jnp.float32),
+            jax.ShapeDtypeStruct((g, nr, 1), jnp.float32),
+            jax.ShapeDtypeStruct((g, nr, 1), jnp.float32),
             jax.ShapeDtypeStruct((g, a_total, hd), jnp.float32),
         ],
         interpret=interpret,
     )(plen.astype(jnp.int32), *inputs)
+    return m[..., 0], l[..., 0], acc

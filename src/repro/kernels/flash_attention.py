@@ -43,7 +43,7 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0, :].astype(jnp.float32)[None, :]          # (1, hd)
+    q = q_ref[0, 0].astype(jnp.float32)                      # (1, hd)
     k = k_ref[0, 0].astype(jnp.float32)                      # (Ts, hd)
     sc = jnp.dot(k, q.T, preferred_element_type=jnp.float32) * scale
     idx = s * ts + jax.lax.broadcasted_iota(jnp.int32, (ts, 1), 0)
@@ -53,22 +53,22 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
         valid &= (pos - idx) < window
     sc = jnp.where(valid, sc, NEG_INF)                       # (Ts, 1)
 
-    m_prev = m_scr[0, 0]
+    m_prev = m_scr[...]                                      # (1, 1)
     m_new = jnp.maximum(jnp.maximum(m_prev, jnp.max(sc)), -1e30)
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(sc - m_new)                                  # (Ts, 1)
-    l_new = l_scr[0, 0] * alpha + jnp.sum(p)
+    l_new = l_scr[...] * alpha + jnp.sum(p)
     v = v_ref[0, 0].astype(jnp.float32)                      # (Ts, hd)
     acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
         p.T, v, preferred_element_type=jnp.float32)          # (1, hd)
-    m_scr[0, 0] = m_new
-    l_scr[0, 0] = l_new
+    m_scr[...] = m_new
+    l_scr[...] = l_new
 
     @pl.when(s == n_tiles - 1)
     def _fin():
-        o_ref[0, 0, :] = (acc_scr[0, :]
-                          / jnp.maximum(l_scr[0, 0], 1e-37)).astype(
-                              o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...]
+                       / jnp.maximum(l_scr[...], 1e-37)).astype(
+                           o_ref.dtype)
 
 
 def flash_decode(q, k_cache, v_cache, pos, *, window=0, ts=512,
@@ -88,30 +88,31 @@ def flash_decode(q, k_cache, v_cache, pos, *, window=0, ts=512,
     grid = (b, h, n_tiles)
     kernel = functools.partial(_decode_kernel, scale=scale, window=window,
                                ts=ts, n_tiles=n_tiles)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 1, hd), lambda bb, hh, ss, pos_r:
-                             (bb, hh, 0)),
+                pl.BlockSpec((1, 1, 1, hd), lambda bb, hh, ss, pos_r:
+                             (bb, hh, 0, 0)),
                 pl.BlockSpec((1, 1, ts, hd), lambda bb, hh, ss, pos_r:
                              (bb, hh // qpk, ss, 0)),
                 pl.BlockSpec((1, 1, ts, hd), lambda bb, hh, ss, pos_r:
                              (bb, hh // qpk, ss, 0)),
             ],
-            out_specs=pl.BlockSpec((1, 1, hd), lambda bb, hh, ss, pos_r:
-                                   (bb, hh, 0)),
+            out_specs=pl.BlockSpec((1, 1, 1, hd), lambda bb, hh, ss, pos_r:
+                                   (bb, hh, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((1, 1), jnp.float32),
                 pltpu.VMEM((1, 1), jnp.float32),
                 pltpu.VMEM((1, hd), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, h, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, h, 1, hd), jnp.float32),
         interpret=interpret,
-    )(pos.astype(jnp.int32), q, k_cache, v_cache)
+    )(pos.astype(jnp.int32), q[:, :, None], k_cache, v_cache)
+    return out[:, :, 0]
 
 
 # ------------------------------------------------------------ paged decode
@@ -127,7 +128,7 @@ def _paged_decode_kernel(pos_ref, btk_ref, btv_ref, q_ref, k_ref, v_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0, :].astype(jnp.float32)[None, :]          # (1, hd)
+    q = q_ref[0, 0].astype(jnp.float32)                      # (1, hd)
     k = k_ref[0, 0].astype(jnp.float32)                      # (page, hd)
     sc = jnp.dot(k, q.T, preferred_element_type=jnp.float32) * scale
     idx = s * page + jax.lax.broadcasted_iota(jnp.int32, (page, 1), 0)
@@ -137,22 +138,22 @@ def _paged_decode_kernel(pos_ref, btk_ref, btv_ref, q_ref, k_ref, v_ref,
         valid &= (pos - idx) < window
     sc = jnp.where(valid, sc, NEG_INF)                       # (page, 1)
 
-    m_prev = m_scr[0, 0]
+    m_prev = m_scr[...]                                      # (1, 1)
     m_new = jnp.maximum(jnp.maximum(m_prev, jnp.max(sc)), -1e30)
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(sc - m_new)
-    l_new = l_scr[0, 0] * alpha + jnp.sum(p)
+    l_new = l_scr[...] * alpha + jnp.sum(p)
     v = v_ref[0, 0].astype(jnp.float32)                      # (page, hd)
     acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
         p.T, v, preferred_element_type=jnp.float32)          # (1, hd)
-    m_scr[0, 0] = m_new
-    l_scr[0, 0] = l_new
+    m_scr[...] = m_new
+    l_scr[...] = l_new
 
     @pl.when(s == n_pages - 1)
     def _fin():
-        o_ref[0, 0, :] = (acc_scr[0, :]
-                          / jnp.maximum(l_scr[0, 0], 1e-37)).astype(
-                              o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...]
+                       / jnp.maximum(l_scr[...], 1e-37)).astype(
+                           o_ref.dtype)
 
 
 def paged_decode(q, kv_pool, bt_k, bt_v, pos, *, window=0, interpret=None):
@@ -173,15 +174,15 @@ def paged_decode(q, kv_pool, bt_k, bt_v, pos, *, window=0, interpret=None):
     grid = (b, h, n_pages)
     kernel = functools.partial(_paged_decode_kernel, scale=scale,
                                window=window, page=page, n_pages=n_pages)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 1, hd),
+                pl.BlockSpec((1, 1, 1, hd),
                              lambda bb, hh, ss, pos_r, btk_r, btv_r:
-                             (bb, hh, 0)),
+                             (bb, hh, 0, 0)),
                 pl.BlockSpec((1, 1, page, hd),
                              lambda bb, hh, ss, pos_r, btk_r, btv_r:
                              (btk_r[bb, ss], hh // qpk, 0, 0)),
@@ -189,19 +190,20 @@ def paged_decode(q, kv_pool, bt_k, bt_v, pos, *, window=0, interpret=None):
                              lambda bb, hh, ss, pos_r, btk_r, btv_r:
                              (btv_r[bb, ss], hh // qpk, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, 1, hd),
+            out_specs=pl.BlockSpec((1, 1, 1, hd),
                                    lambda bb, hh, ss, pos_r, btk_r, btv_r:
-                                   (bb, hh, 0)),
+                                   (bb, hh, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((1, 1), jnp.float32),
                 pltpu.VMEM((1, 1), jnp.float32),
                 pltpu.VMEM((1, hd), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, h, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, h, 1, hd), jnp.float32),
         interpret=interpret,
     )(pos.astype(jnp.int32), bt_k.astype(jnp.int32), bt_v.astype(jnp.int32),
-      q, kv_pool, kv_pool)
+      q[:, :, None], kv_pool, kv_pool)
+    return out[:, :, 0]
 
 
 # ------------------------------------------------------------------ prefill
@@ -259,8 +261,8 @@ def _prefill_kernel(off_ref, q_ref, k_ref, v_ref, out_refs, m_scr, l_scr,
     def _fin():
         if emit_state:
             m_ref, l_ref, acc_ref = out_refs
-            m_ref[0, 0] = m_scr[:, 0]
-            l_ref[0, 0] = l_scr[:, 0]
+            m_ref[0, 0] = m_scr[...]
+            l_ref[0, 0] = l_scr[...]
             acc_ref[0, 0] = acc_scr[...]
         else:
             (o_ref,) = out_refs
@@ -311,19 +313,7 @@ def flash_prefill(q, k, v, *, offset=0, window=0, tq=256, ts=512,
              *rest[n_out:])
 
     if emit_state:
-        out_specs = [
-            pl.BlockSpec((1, 1, tq), lambda bb, hh, ii, jj, off_r:
-                         (bb, hh, ii)),
-            pl.BlockSpec((1, 1, tq), lambda bb, hh, ii, jj, off_r:
-                         (bb, hh, ii)),
-            pl.BlockSpec((1, 1, tq, hd), lambda bb, hh, ii, jj, off_r:
-                         (bb, hh, ii, 0)),
-        ]
-        out_shape = [
-            jax.ShapeDtypeStruct((b, h, t), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, t), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, t, hd), jnp.float32),
-        ]
+        out_specs, out_shape = _state_outputs(b, h, t, tq, hd)
     else:
         out_specs = pl.BlockSpec((1, 1, tq, hd),
                                  lambda bb, hh, ii, jj, off_r:
@@ -353,20 +343,49 @@ def flash_prefill(q, k, v, *, offset=0, window=0, tq=256, ts=512,
         interpret=interpret,
     )(off, qh, kh, vh)
     if emit_state:
-        return out
+        return _squeeze_state(out)
     return out.transpose(0, 2, 1, 3)
 
 
+def _state_outputs(b, h, t, tq, hd):
+    """Head-major (m, l, acc) output specs for the prefill-state kernels.
+    m/l are written as (Tq, 1) columns, the scratch layout: a (1, Tq) row
+    block of a (B, H, T) array is not (8, 128)-tileable."""
+    def tile(bb, hh, ii, *_):
+        return (bb, hh, ii, 0)
+
+    specs = [pl.BlockSpec((1, 1, tq, 1), tile),
+             pl.BlockSpec((1, 1, tq, 1), tile),
+             pl.BlockSpec((1, 1, tq, hd), tile)]
+    shapes = [jax.ShapeDtypeStruct((b, h, t, 1), jnp.float32),
+              jax.ShapeDtypeStruct((b, h, t, 1), jnp.float32),
+              jax.ShapeDtypeStruct((b, h, t, hd), jnp.float32)]
+    return specs, shapes
+
+
+def _squeeze_state(out):
+    m, l, acc = out
+    return m[..., 0], l[..., 0], acc
+
+
 # --------------------------------------------------- paged prefix attend
+def _scale_row(s_ref, kvh):
+    """KV head ``kvh``'s (1, page) scale row out of the page's whole
+    (KV, page) scale block: a (1, 1, page) block of the (nP, KV, page)
+    scale pool is not (8, 128)-tileable, so the row is picked in-kernel."""
+    return s_ref[0, pl.ds(kvh, 1), :].astype(jnp.float32)
+
+
 def _paged_prefix_kernel(plen_ref, btk_ref, btv_ref, q_ref, k_ref, ks_ref,
                          v_ref, vs_ref, m_ref, l_ref, acc_ref, m_scr,
-                         l_scr, acc_scr, *, scale, page, n_pages,
+                         l_scr, acc_scr, *, scale, page, n_pages, qpk,
                          softcap=0.0):
     """Suffix-prefill prefix pass: every suffix query attends every cached
     prefix position (< plen) — no causal constraint inside the prefix.
     Pages beyond the prefix are redirected to the null sink page by the
     index map and skipped here; emits the mergeable m/l/acc triple."""
     b = pl.program_id(0)
+    kvh = pl.program_id(1) // qpk      # KV head of this query head
     j = pl.program_id(3)               # logical page index
 
     @pl.when(j == 0)
@@ -383,7 +402,7 @@ def _paged_prefix_kernel(plen_ref, btk_ref, btv_ref, q_ref, k_ref, ks_ref,
         k = k_ref[0, 0].astype(jnp.float32)              # (page, hd)
         sc = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
         if ks_ref is not None:   # int8: per-(row, pos) K scales
-            sc = sc * ks_ref[0, 0].astype(jnp.float32)[None, :]
+            sc = sc * _scale_row(ks_ref, kvh)                # (1, page)
         sc = sc * scale
         if softcap:
             sc = softcap * jnp.tanh(sc / softcap)
@@ -397,7 +416,7 @@ def _paged_prefix_kernel(plen_ref, btk_ref, btv_ref, q_ref, k_ref, ks_ref,
         l_new = l_scr[:, 0] * alpha + jnp.sum(p, -1)
         v = v_ref[0, 0].astype(jnp.float32)              # (page, hd)
         if vs_ref is not None:
-            v = v * vs_ref[0, 0].astype(jnp.float32)[:, None]
+            v = v * _scale_row(vs_ref, kvh).T                # (page, 1)
         acc_scr[...] = acc_scr[...] * alpha[:, None] + jnp.dot(
             p, v, preferred_element_type=jnp.float32)
         m_scr[:, 0] = m_new
@@ -405,8 +424,8 @@ def _paged_prefix_kernel(plen_ref, btk_ref, btv_ref, q_ref, k_ref, ks_ref,
 
     @pl.when(j == n_pages - 1)
     def _fin():
-        m_ref[0, 0] = m_scr[:, 0]
-        l_ref[0, 0] = l_scr[:, 0]
+        m_ref[0, 0] = m_scr[...]
+        l_ref[0, 0] = l_scr[...]
         acc_ref[0, 0] = acc_scr[...]
 
 
@@ -456,8 +475,8 @@ def paged_prefix_attend(q, kv_pool, bt_k, bt_v, plen, *, k_scale_pool=None,
     inputs = [qh, kv_pool]
     if k_scale_pool is not None:
         in_specs.append(pl.BlockSpec(
-            (1, 1, page), lambda bb, hh, ii, jj, plen_r, btk_r, btv_r:
-            (_k_page(bb, jj, plen_r, btk_r, btv_r), hh // qpk, 0)))
+            (1, n_kv, page), lambda bb, hh, ii, jj, plen_r, btk_r, btv_r:
+            (_k_page(bb, jj, plen_r, btk_r, btv_r), 0, 0)))
         inputs.append(k_scale_pool)
     in_specs.append(pl.BlockSpec(
         (1, 1, page, hd), lambda bb, hh, ii, jj, plen_r, btk_r, btv_r:
@@ -465,8 +484,8 @@ def paged_prefix_attend(q, kv_pool, bt_k, bt_v, plen, *, k_scale_pool=None,
     inputs.append(kv_pool)
     if v_scale_pool is not None:
         in_specs.append(pl.BlockSpec(
-            (1, 1, page), lambda bb, hh, ii, jj, plen_r, btk_r, btv_r:
-            (_v_page(bb, jj, plen_r, btk_r, btv_r), hh // qpk, 0)))
+            (1, n_kv, page), lambda bb, hh, ii, jj, plen_r, btk_r, btv_r:
+            (_v_page(bb, jj, plen_r, btk_r, btv_r), 0, 0)))
         inputs.append(v_scale_pool)
 
     has_ks = k_scale_pool is not None
@@ -483,36 +502,23 @@ def paged_prefix_attend(q, kv_pool, bt_k, bt_v, plen, *, k_scale_pool=None,
         _paged_prefix_kernel(plen_ref, btk_ref, btv_ref, q_ref, k_ref,
                              ks_ref, v_ref, vs_ref, m_ref, l_ref, acc_ref,
                              m_scr, l_scr, acc_scr, scale=scale, page=page,
-                             n_pages=n_pages, softcap=softcap)
+                             n_pages=n_pages, qpk=qpk, softcap=softcap)
 
-    return pl.pallas_call(
+    out_specs, out_shape = _state_outputs(b, h, t, tq, hd)
+    return _squeeze_state(pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b, h, t // tq, n_pages),
             in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec((1, 1, tq),
-                             lambda bb, hh, ii, jj, plen_r, btk_r, btv_r:
-                             (bb, hh, ii)),
-                pl.BlockSpec((1, 1, tq),
-                             lambda bb, hh, ii, jj, plen_r, btk_r, btv_r:
-                             (bb, hh, ii)),
-                pl.BlockSpec((1, 1, tq, hd),
-                             lambda bb, hh, ii, jj, plen_r, btk_r, btv_r:
-                             (bb, hh, ii, 0)),
-            ],
+            out_specs=out_specs,
             scratch_shapes=[
                 pltpu.VMEM((tq, 1), jnp.float32),
                 pltpu.VMEM((tq, 1), jnp.float32),
                 pltpu.VMEM((tq, hd), jnp.float32),
             ],
         ),
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, t), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, t), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, t, hd), jnp.float32),
-        ],
+        out_shape=out_shape,
         interpret=interpret,
     )(plen.astype(jnp.int32), bt_k.astype(jnp.int32),
-      bt_v.astype(jnp.int32), *inputs)
+      bt_v.astype(jnp.int32), *inputs))

@@ -8,8 +8,8 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
 
-from repro import compat
 
 
 def _mk(shape, axes):
@@ -20,7 +20,8 @@ def _mk(shape, axes):
             f"mesh {shape} needs {n} devices, have {len(devs)} — set "
             "XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "importing jax (launch/dryrun.py does this)")
-    return compat.make_mesh(shape, axes, devices=devs)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devs)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

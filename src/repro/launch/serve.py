@@ -1,8 +1,18 @@
 """Serving driver: ``python -m repro.launch.serve --arch <id> [...]``.
 
-Runs the slot-batched CHAI serving engine on a reduced config with random
-weights + synthetic prompts, and reports TTFT / per-token latency / KV
-bytes for CHAI vs MHA — the CPU-scale analogue of the paper's Fig 11/12.
+Runs the default serving path — continuous scheduler, paged KV, CHAI on
+(PREFILL -> WARMUP -> CLUSTER -> STEADY) — with random weights drawn from
+``--seed`` and synthetic prompts, and reports TTFT / latency / KV bytes
+for CHAI vs MHA.
+
+By default the config is ``reduced`` (d_model 64, fp32) so it runs on a
+CPU. ``--full`` serves the registered config at its published widths in
+its own dtype, with only the layer count cut by ``--layers``:
+
+    python -m repro.launch.serve --arch chai-llama-7b --full --layers 16 \\
+        --slots 8 --max-seq 2048 --prompt-len 1024 --max-new 32
+
+``chip_smoke.py`` drives the same functions on a TPU.
 """
 from __future__ import annotations
 
@@ -12,37 +22,81 @@ import time
 import jax
 import numpy as np
 
-from repro.configs.base import get_config, reduced
+from repro.configs.base import get_config, reduced, with_depth
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as tfm
 from repro.serving.engine import EngineConfig, ServingEngine
+
+
+def model_config(arch, *, full=False, layers=0, chai=True):
+    """``full``: published widths and dtype, depth cut to ``layers`` (0 =
+    all). Otherwise the reduced CPU-scale config (``layers`` 0 = its
+    default depth)."""
+    cfg = get_config(arch)
+    if full:
+        cfg = with_depth(cfg, layers) if layers else cfg
+    else:
+        cfg = reduced(cfg, n_layers=layers or None)
+    return cfg.with_chai(enabled=True) if chai else cfg
+
+
+def build_engine(cfg, *, slots, max_seq, seed=0, num_pages=0, chai=True):
+    """Random weights from ``seed`` and an engine with the default layout
+    and scheduler. ``num_pages`` sizes both page pools (0 = the engine's
+    worst-case auto size)."""
+    params = tfm.init_params(cfg, jax.random.PRNGKey(seed))
+    ecfg = EngineConfig(batch_slots=slots, max_seq=max_seq, use_chai=chai,
+                        num_pages=num_pages, num_chai_pages=num_pages)
+    return ServingEngine(cfg, params, ecfg)
+
+
+def synthetic_prompts(cfg, n, lo, hi, *, seed=0):
+    """``n`` random-token prompts with lengths drawn from [lo, hi]."""
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, cfg.vocab_size, size=int(rng.integers(lo, hi + 1)))
+            for _ in range(n)]
+
+
+def serve(eng, prompts, max_new):
+    """Submit ``prompts`` and run the engine until they all finish.
+    Returns (this wave's finished requests, wall seconds). Every step
+    reads its sampled tokens back to the host, so the wall time ends
+    with the device's last step."""
+    for p in prompts:
+        eng.submit(p, max_new_tokens=max_new)
+    t0 = time.time()
+    eng.run()
+    wall = time.time() - t0
+    return eng.reap_done(), wall
 
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="chai-llama-7b")
+    ap.add_argument("--full", action="store_true",
+                    help="published widths in the config's own dtype")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="keep only the first N layers (0 = default)")
+    ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-seq", type=int, default=128)
+    ap.add_argument("--num-pages", type=int, default=0,
+                    help="pages per KV pool (0 = worst case for the slots)")
     ap.add_argument("--no-chai", action="store_true")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
-    cfg = reduced(get_config(args.arch))
-    if not args.no_chai:
-        cfg = cfg.with_chai(enabled=True)
-    params = tfm.init_params(cfg, jax.random.PRNGKey(0))
-    ecfg = EngineConfig(batch_slots=args.slots, max_seq=args.max_seq,
-                        use_chai=not args.no_chai)
-    eng = ServingEngine(cfg, params, ecfg)
-
-    rng = np.random.default_rng(0)
-    for i in range(args.requests):
-        eng.submit(rng.integers(0, cfg.vocab_size, size=args.prompt_len),
-                   max_new_tokens=args.max_new, uid=i)
-    t0 = time.time()
-    done = eng.run()
-    wall = time.time() - t0
+    cfg = model_config(args.arch, full=args.full, layers=args.layers,
+                       chai=not args.no_chai)
+    eng = build_engine(cfg, slots=args.slots, max_seq=args.max_seq,
+                       seed=args.seed, num_pages=args.num_pages,
+                       chai=not args.no_chai)
+    prompts = synthetic_prompts(cfg, args.requests, args.prompt_len,
+                                args.prompt_len, seed=args.seed)
+    done, wall = serve(eng, prompts, args.max_new)
 
     ttfts = [r.ttft for r in done]
     lats = [r.latency for r in done]

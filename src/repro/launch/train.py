@@ -19,6 +19,7 @@ import jax
 
 from repro.configs.base import get_config, reduced
 from repro.data.pipeline import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train.trainer import Trainer, TrainerConfig
 
 
@@ -36,6 +37,7 @@ def main(argv=None):
     ap.add_argument("--mesh-model", type=int, default=1,
                     help="model-parallel width for the elastic mesh")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full:

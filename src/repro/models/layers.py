@@ -4,7 +4,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import opt_barrier
 
 
 def rms_norm(x, scale, eps=1e-6):
@@ -16,7 +15,7 @@ def rms_norm(x, scale, eps=1e-6):
     # barrier: pin the f32->model-dtype cast so SPMD reshardings after
     # the norm move 2-byte values, not the hoisted f32 intermediates
     # (halves activation all-gathers; EXPERIMENTS.md §Perf cell 2).
-    return opt_barrier(out)
+    return jax.lax.optimization_barrier(out)
 
 
 def softcap(x, cap):

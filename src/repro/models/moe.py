@@ -12,7 +12,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.models.layers import activation_fn
 
 
@@ -166,7 +165,7 @@ def moe_ffn_ep(x, p, cfg, ctx, *, return_aux=False):
         shared_specs = (P(None, None), P(None, None), P(None, None))
         shared_args = (p["shared_gate"], p["shared_up"], p["shared_down"])
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(batch_spec, maxis, None),        # x: batch + seq shard
                   P(None, None),                      # router replicated

@@ -13,7 +13,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import opt_barrier
 
 CHUNK = 32
 LOG_CLAMP = 1.5          # per-step |log w| cap; CHUNK*LOG_CLAMP = 48 < 88
@@ -128,7 +127,7 @@ def rwkv_time_mix_fullseq(x, p, cfg, state):
     y = jnp.einsum("btd,de->bte", o, p["w_o"])
     # barrier: down-proj output must all-reduce in bf16; XLA otherwise
     # hoists the residual/norm f32 convert before the AR (2x wire bytes).
-    y = opt_barrier(y)
+    y = jax.lax.optimization_barrier(y)
     return y, {"shift": x[:, -1], "wkv": s_fin.astype(x.dtype)}
 
 
@@ -158,7 +157,7 @@ def rwkv_channel_mix_fullseq(x, p, last):
     xr = x + (xs - x) * mu[1]
     k = jnp.square(jax.nn.relu(jnp.einsum("...d,df->...f", xk, p["c_k"])))
     kv = jnp.einsum("...f,fd->...d", k, p["c_v"])
-    kv = opt_barrier(kv)                      # bf16 AR (see time-mix)
+    kv = jax.lax.optimization_barrier(kv)    # bf16 AR (see time-mix)
     r = jax.nn.sigmoid(jnp.einsum("...d,de->...e", xr, p["c_r"]))
     return r * kv, x[:, -1]
 

@@ -176,7 +176,9 @@ def init_params(cfg: ModelConfig, key) -> Dict[str, Any]:
         if scale == 0.0:
             params[g][n] = jnp.zeros(shape, dt)
         else:
-            params[g][n] = (jax.random.normal(k, shape, jnp.float32)
+            # Drawn in the target dtype: a full-width bf16 leaf never
+            # passes through an fp32 temporary twice its size.
+            params[g][n] = (jax.random.normal(k, shape, dt)
                             * scale).astype(dt)
     return params
 

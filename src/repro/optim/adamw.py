@@ -10,7 +10,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import opt_barrier
 
 
 class AdamWState(NamedTuple):
@@ -74,7 +73,7 @@ def update(grads, state: AdamWState, params, *, lr=None, b1=0.9, b2=0.95,
         # barrier: keep the f32->bf16 convert BEFORE the ZeRO all-gather
         # (XLA otherwise hoists the convert past it and gathers f32 —
         # 2x wire bytes; EXPERIMENTS.md §Perf iteration 4).
-        return opt_barrier(out)
+        return jax.lax.optimization_barrier(out)
 
     new_params = jax.tree.map(upd, params, m, v)
     return new_params, AdamWState(step=step, m=m, v=v), {

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 
 
 def _quantize(g):
@@ -79,6 +78,6 @@ def wrap_pod_manual(fn, mesh, in_specs, out_specs, *, pod_axis: str = "pod"):
     mechanism that lets the train step intercept the cross-pod gradient
     reduction and run it int8 (see repro.train.train_step).
     """
-    return compat.shard_map(fn, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, axis_names={pod_axis},
                          check_vma=False)

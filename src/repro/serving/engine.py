@@ -81,8 +81,7 @@ Two schedulers (``EngineConfig.scheduler``):
 
 Every Request records arrival, admission (slot id + engine step), first
 token, and completion, so per-request TTFT / ITL / latency and engine
-throughput fall out directly. On-CPU usage: reduced configs; the same
-engine code drives TPU meshes by passing ``mesh`` + shardings.
+throughput fall out directly.
 """
 from __future__ import annotations
 
@@ -267,8 +266,9 @@ class EngineConfig:
     # exportable as a Chrome trace (``step_trace()``).
     telemetry: str = "off"         # "off" | "basic" | "trace"
     # -- degraded-decode healing ----------------------------------------
-    # After a kernel-path failure flips ``degraded_decode`` the engine
-    # stays on the jnp reference jits. With decode_heal_steps = N > 0 it
+    # After an injected kernel.decode fault flips ``degraded_decode`` the
+    # engine stays on the jnp reference jits (a real fused-path failure
+    # raises EngineFault instead). With decode_heal_steps = N > 0 it
     # reverts to the fused path after N consecutive clean decode steps
     # (no kernel.decode fault observed); each revert counts in
     # ``decode_heals``. 0 (default) = never heal (the historical
@@ -335,8 +335,8 @@ class EngineCore(CohortSchedulerMixin):
         self.faults = faults           # None = no injection sites active
         self.quarantined = 0           # requests typed-failed ("error")
         self.audit_steps = 0           # step()s that ran the auditor
-        self.degraded_decode = False   # fused/relay path failed: jnp now
-        self.decode_fallbacks = 0      # kernel-path failures survived
+        self.degraded_decode = False   # injected kernel fault: jnp now
+        self.decode_fallbacks = 0      # injected kernel faults survived
         self.decode_heals = 0          # degraded->fused reverts (healing)
         self._heal_clean = 0           # consecutive clean degraded steps
         self._decode_fault_hit = False  # kernel.decode fired this step
@@ -2437,14 +2437,15 @@ class EngineCore(CohortSchedulerMixin):
                           degraded=self.degraded_decode):
                 logits, state = self._dispatch_decode(inputs, relay,
                                                       occupied)
-        except Exception as err:
-            if isinstance(err, EngineFault):
-                raise
-            # Kernel-path failure (injected or real): fall back to the
-            # jnp reference jits for this engine and retry the step
-            # (``decode_heal_steps`` can revert later). Safe on CPU
-            # (buffer donation is a no-op there); donating backends
-            # would need a state re-upload first.
+        except EngineFault:
+            raise
+        except InjectedFault:
+            # Injected kernel.decode fault (raised before any jit runs, so
+            # the donated device state is intact): fall back to the jnp
+            # reference jits and retry the step (``decode_heal_steps`` can
+            # revert later). A real failure of the fused path is NOT
+            # masked: it raises EngineFault below, so no run ever reports
+            # numbers for a decode path other than the kernel's.
             self.degraded_decode = True
             self.decode_fallbacks += 1
             self._heal_clean = 0
@@ -2462,9 +2463,11 @@ class EngineCore(CohortSchedulerMixin):
                                                           occupied)
             except Exception as err2:
                 raise EngineFault(
-                    "decode failed on the fused path AND the jnp "
-                    f"reference fallback: {err2!r} "
-                    f"(original failure: {err!r})") from err2
+                    "decode failed on the jnp reference fallback after "
+                    f"an injected kernel.decode fault: {err2!r}") from err2
+        except Exception as err:
+            raise EngineFault(
+                f"decode failed on the fused kernel path: {err!r}") from err
         else:
             if self.degraded_decode and self.ecfg.decode_heal_steps > 0:
                 self._maybe_heal()
@@ -2608,24 +2611,14 @@ class EngineCore(CohortSchedulerMixin):
                                 "fallback")
 
     def _jnp_decode_steps(self):
-        """Degraded decode jits, built lazily on the first kernel-path
-        failure: the SAME step makers, but the module flag that routes
-        decode attention to the fused Pallas kernels is held False while
-        each jit traces, so the whole phase mix runs on the jnp
+        """Degraded decode jits, built lazily on the first injected
+        kernel.decode fault: the SAME step makers, but the module flag
+        that routes decode attention to the fused Pallas kernels is held
+        False while each jit traces, so the whole phase mix runs on the jnp
         reference path (token-parity with the fused path; the relay is
         skipped — ``_build_relay`` returns None while degraded)."""
         if self._jnp_steps is None:
-            from repro.core import chai_attention as chai_mod
-
-            def unfused(fn):
-                def wrapped(*args):
-                    prev = chai_mod.USE_FUSED_DECODE
-                    chai_mod.USE_FUSED_DECODE = False
-                    try:
-                        return fn(*args)
-                    finally:
-                        chai_mod.USE_FUSED_DECODE = prev
-                return wrapped
+            from repro.core.chai_attention import unfused
 
             cfg, ts = self.cfg, self.ecfg.page_size
             steps = {"mha": jax.jit(

@@ -317,6 +317,26 @@ def test_kernel_fault_degrades_to_reference_decode_with_parity():
         assert list(f.generated) == list(c.generated)
 
 
+@pytest.mark.no_leak_gate
+def test_real_decode_failure_raises_engine_fault():
+    """A real (non-injected) decode failure is not masked by the jnp
+    reference fallback: step() raises EngineFault, and the engine never
+    reports a degraded decode it did not run."""
+    cfg, params = _model()
+    core = EngineCore(cfg, params, _ecfg())
+    core.add_request(_prompts(1, seed=6)[0], GREEDY)
+
+    def broken(*args):
+        raise RuntimeError("kernel failed to lower")
+
+    core._mha_step = core._chai_step = core._mixed_step = broken
+    with pytest.raises(EngineFault, match="fused kernel path"):
+        _drain(core)
+    fs = core.fault_stats()
+    assert fs["degraded_decode"] is False
+    assert fs["decode_fallbacks"] == 0
+
+
 def test_relay_residency_fault_dissolves_groups_not_requests():
     """A relay-formation fault falls back to per-request decode for that
     step; nobody fails and tokens match the relay-free run."""

@@ -391,13 +391,13 @@ def test_relay_jaxpr_prefix_pass_once_per_group():
             if e.primitive.name == "pallas_call"]
     # the GROUP-batched prefix state (G, Nmax*R) is produced once in the
     # layer scan body (or n_layers times if unrolled) — never scaled by
-    # the member count
+    # the member count (the kernel writes m/l as (rows, 1) columns)
     hits = [e for e in eqns
-            if any(tuple(v.aval.shape) == (g, nr) for v in e.outvars)]
+            if any(tuple(v.aval.shape) == (g, nr, 1) for v in e.outvars)]
     assert 1 <= len(hits) <= cfg.n_layers
     # a per-slot formulation would emit (G, R) prefix states per member;
     # no such kernel exists in the trace
-    assert not any(tuple(v.aval.shape) == (g, nr // nmax)
+    assert not any(tuple(v.aval.shape) == (g, nr // nmax, 1)
                    for e in eqns for v in e.outvars)
 
 
