@@ -50,6 +50,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.flash_attention import _squeeze_state
+
 NEG_INF = -2.0e38
 
 
@@ -519,13 +521,6 @@ def _fused_outputs(b, r_total, h_total, rows_acc, hd, emit_state):
     return specs, shapes
 
 
-def _squeeze_state(out, emit_state):
-    if not emit_state:
-        return out
-    m, l, acc = out
-    return m[..., 0], l[..., 0], acc
-
-
 def chai_fused_decode(q_rep, k_cache, v_cache, h2c, pos, *, k_scale=None,
                       v_scale=None, reps_per_group=1, share_values=False,
                       window=0, ts=512, softcap=0.0, emit_state=False,
@@ -602,7 +597,7 @@ def chai_fused_decode(q_rep, k_cache, v_cache, h2c, pos, *, k_scale=None,
         out_shape=out_shape,
         interpret=interpret,
     )(pos.astype(jnp.int32), *inputs)
-    return _squeeze_state(out, emit_state)
+    return _squeeze_state(out) if emit_state else out
 
 
 def paged_chai_fused_decode(q_rep, k_pool, bt_k, v_pool, bt_v, h2c, pos, *,
@@ -687,7 +682,7 @@ def paged_chai_fused_decode(q_rep, k_pool, bt_k, v_pool, bt_v, h2c, pos, *,
         interpret=interpret,
     )(pos.astype(jnp.int32), bt_k.astype(jnp.int32),
       bt_v.astype(jnp.int32), *inputs)
-    return _squeeze_state(out, emit_state)
+    return _squeeze_state(out) if emit_state else out
 
 
 # --------------------------------------- relay shared-prefix decode --------

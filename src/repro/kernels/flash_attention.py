@@ -364,6 +364,7 @@ def _state_outputs(b, h, t, tq, hd):
 
 
 def _squeeze_state(out):
+    """(m, l, acc) with m/l written as (.., rows, 1) columns -> (.., rows)."""
     m, l, acc = out
     return m[..., 0], l[..., 0], acc
 

@@ -1,8 +1,8 @@
 """Serving driver: ``python -m repro.launch.serve --arch <id> [...]``.
 
 Runs the default serving path — continuous scheduler, paged KV, CHAI on
-(PREFILL -> WARMUP -> CLUSTER -> STEADY) — with random weights drawn from
-``--seed`` and synthetic prompts, and reports TTFT / latency / KV bytes
+(PREFILL -> WARMUP -> CLUSTER -> STEADY) — with random weights from seed 0
+and synthetic prompts, and reports TTFT / latency / KV bytes
 for CHAI vs MHA.
 
 By default the config is ``reduced`` (d_model 64, fp32) so it runs on a
@@ -50,11 +50,10 @@ def build_engine(cfg, *, slots, max_seq, seed=0, num_pages=0, chai=True):
     return ServingEngine(cfg, params, ecfg)
 
 
-def synthetic_prompts(cfg, n, lo, hi, *, seed=0):
-    """``n`` random-token prompts with lengths drawn from [lo, hi]."""
+def synthetic_prompts(cfg, lengths, seed=0):
+    """One random-token prompt per entry of ``lengths``."""
     rng = np.random.default_rng(seed)
-    return [rng.integers(0, cfg.vocab_size, size=int(rng.integers(lo, hi + 1)))
-            for _ in range(n)]
+    return [rng.integers(0, cfg.vocab_size, size=n) for n in lengths]
 
 
 def serve(eng, prompts, max_new):
@@ -77,14 +76,11 @@ def main(argv=None):
                     help="published widths in the config's own dtype")
     ap.add_argument("--layers", type=int, default=0,
                     help="keep only the first N layers (0 = default)")
-    ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-seq", type=int, default=128)
-    ap.add_argument("--num-pages", type=int, default=0,
-                    help="pages per KV pool (0 = worst case for the slots)")
     ap.add_argument("--no-chai", action="store_true")
     args = ap.parse_args(argv)
     enable_compile_cache()
@@ -92,10 +88,8 @@ def main(argv=None):
     cfg = model_config(args.arch, full=args.full, layers=args.layers,
                        chai=not args.no_chai)
     eng = build_engine(cfg, slots=args.slots, max_seq=args.max_seq,
-                       seed=args.seed, num_pages=args.num_pages,
                        chai=not args.no_chai)
-    prompts = synthetic_prompts(cfg, args.requests, args.prompt_len,
-                                args.prompt_len, seed=args.seed)
+    prompts = synthetic_prompts(cfg, [args.prompt_len] * args.requests)
     done, wall = serve(eng, prompts, args.max_new)
 
     ttfts = [r.ttft for r in done]
