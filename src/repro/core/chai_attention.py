@@ -185,8 +185,8 @@ def _relay_prefix_state(relay, idxs, q_rep, *, acc_rows, use_v_scale,
 # ---------------------------------------------------------------- MHA ------
 def _chai_mha_decode(xn, p, cfg, state, idxs, chai_ctx, write_mask=None, *,
                      decode_ts=0, relay=None):
-    from repro.models.transformer import _masked_rows, tree_index, \
-        tree_update
+    from repro.models.transformer import _masked_rows, pool_slice, \
+        pool_writeback, tree_index, tree_update
     b, d = xn.shape
     ar = jnp.arange(b)
     hd, h = cfg.head_dim, cfg.n_heads
@@ -233,13 +233,13 @@ def _chai_mha_decode(xn, p, cfg, state, idxs, chai_ctx, write_mask=None, *,
     # page-gathered dense views are only built on the jnp fallback path.
     ksc = csc = None
     if paged:
-        cp = tree_index(state["cp"], idxs["global"])      # (nP, k, page, hd)
+        cp = pool_slice(state["cp"], idxs["global"])      # (nP, k, page, hd)
         page = cp.shape[2]
         pk, row = paged_token_coords(state["bt_kc"], pos, page)
         if int8:
             kq, ks = quant_rows(k_rep)
             cp = _paged_write_rows(cp, pk, row, kq, mask)
-            csc = tree_index(state["cp_scale"], idxs["global"])
+            csc = pool_slice(state["cp_scale"], idxs["global"])
             csc = _paged_write_rows(csc, pk, row, ks, mask)
         else:
             cp = _paged_write_rows(cp, pk, row, k_rep, mask)
@@ -279,12 +279,12 @@ def _chai_mha_decode(xn, p, cfg, state, idxs, chai_ctx, write_mask=None, *,
     else:
         v_new = jnp.einsum("bd,dhe->bhe", xn, p["wv"])
         if paged:
-            vp = tree_index(state["kvp"], idxs["global"])
+            vp = pool_slice(state["kvp"], idxs["global"])
             pv, vrow = paged_token_coords(state["bt_vg"], pos, page)
             if int8:
                 vq, vs = quant_rows(v_new)
                 vp = _paged_write_rows(vp, pv, vrow, vq, mask)
-                vsp = tree_index(state["kvp_scale"], idxs["global"])
+                vsp = pool_slice(state["kvp_scale"], idxs["global"])
                 vsp = _paged_write_rows(vsp, pv, vrow, vs, mask)
             else:
                 vp = _paged_write_rows(vp, pv, vrow, v_new, mask)
@@ -387,15 +387,15 @@ def _chai_mha_decode(xn, p, cfg, state, idxs, chai_ctx, write_mask=None, *,
 
     state = dict(state)
     if paged:
-        state["cp"] = tree_update(state["cp"], idxs["global"], cp)
+        state["cp"] = pool_writeback(state["cp"], idxs["global"], cp)
         if int8:
-            state["cp_scale"] = tree_update(state["cp_scale"],
-                                            idxs["global"], csc)
+            state["cp_scale"] = pool_writeback(state["cp_scale"],
+                                               idxs["global"], csc)
         if not share_v:
-            state["kvp"] = tree_update(state["kvp"], idxs["global"], vp)
+            state["kvp"] = pool_writeback(state["kvp"], idxs["global"], vp)
             if int8:
-                state["kvp_scale"] = tree_update(state["kvp_scale"],
-                                                 idxs["global"], vsp)
+                state["kvp_scale"] = pool_writeback(state["kvp_scale"],
+                                                    idxs["global"], vsp)
     else:
         state["kg_chai"] = tree_update(state["kg_chai"], idxs["global"], kc)
         if int8:

@@ -135,6 +135,7 @@ def make_sampler(top_k_cap: int = 256):
       mass matches the unsorted distribution exactly. ``top_k == 0`` and
       any nucleus extending past ``top_k_cap`` truncate to the cap.
     """
+    @jax.named_scope("sample")
     def sample(logits, temperature, top_k, top_p, seed, count):
         lg = logits.astype(jnp.float32)
         greedy_tok = jnp.argmax(lg, axis=-1).astype(jnp.int32)

@@ -312,6 +312,18 @@ def decode_state_structs(cfg: ModelConfig, batch: int, max_seq: int):
     return shapes, logical
 
 
+@jax.named_scope("kv_pool_slice")
+def pool_slice(pool, i):
+    """Layer ``i``'s KV pool out of the layer scan's stacked carry."""
+    return tree_index(pool, i)
+
+
+@jax.named_scope("kv_pool_writeback")
+def pool_writeback(pool, i, new):
+    """Write layer ``i``'s KV pool back into the stacked carry."""
+    return tree_update(pool, i, new)
+
+
 def init_decode_state(cfg: ModelConfig, batch: int, max_seq: int):
     shapes, _ = decode_state_structs(cfg, batch, max_seq)
     return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
@@ -664,6 +676,7 @@ def _mixer_decode_branch(kind, cfg, params, chai_ctx, mixed_phase=False,
                          decode_ts=0, relay=None):
     from repro.core import chai_attention as chai_mod
 
+    @jax.named_scope("decode_attn")
     def attn_branch(op, *, local):
         x, state, idxs = op     # x: (B, d)
         p = tree_index(params["attn"], idxs["attn"])
@@ -764,7 +777,7 @@ def _paged_global_write(state, idxs, k, v, pos, write_mask, cfg):
     (state, pool, scale_pool-or-None) WITHOUT densifying — the fused
     decode kernel streams the pool through its block tables directly."""
     from repro.core.cache import quant_rows
-    pool = tree_index(state["kvp"], idxs["global"])   # (nP, KV, page, hd)
+    pool = pool_slice(state["kvp"], idxs["global"])   # (nP, KV, page, hd)
     page = pool.shape[2]
     pk, row = paged_token_coords(state["bt_kg"], pos, page)
     pv, _ = paged_token_coords(state["bt_vg"], pos, page)
@@ -776,15 +789,15 @@ def _paged_global_write(state, idxs, k, v, pos, write_mask, cfg):
         vq, vs = quant_rows(v)
         pool = _paged_write_rows(pool, pk, row, kq, mask)
         pool = _paged_write_rows(pool, pv, row, vq, mask)
-        spool = tree_index(state["kvp_scale"], idxs["global"])
+        spool = pool_slice(state["kvp_scale"], idxs["global"])
         spool = _paged_write_rows(spool, pk, row, ks, mask)
         spool = _paged_write_rows(spool, pv, row, vs, mask)
-        state["kvp_scale"] = tree_update(state["kvp_scale"],
-                                         idxs["global"], spool)
+        state["kvp_scale"] = pool_writeback(state["kvp_scale"],
+                                            idxs["global"], spool)
     else:
         pool = _paged_write_rows(pool, pk, row, k, mask)
         pool = _paged_write_rows(pool, pv, row, v, mask)
-    state["kvp"] = tree_update(state["kvp"], idxs["global"], pool)
+    state["kvp"] = pool_writeback(state["kvp"], idxs["global"], pool)
     return state, pool, spool
 
 
@@ -919,6 +932,7 @@ def _ffn_decode_branch(kind, cfg, params, moe_impl="ragged"):
         xn = rms_norm(x, p["ln"], cfg.norm_eps)
         return x + mlp.dense_ffn(xn[:, None], p, cfg)[:, 0], state
 
+    @jax.named_scope("moe_ffn")
     def moe_branch(op):
         x, state, idxs = op
         p = tree_index(params["moe"], idxs["moe"])

@@ -261,10 +261,10 @@ class EngineConfig:
     # ``enabled`` guard, and the decode step stays jaxpr-identical to an
     # uninstrumented engine (claim-checked by bench_telemetry_overhead).
     # "basic": MetricsRegistry counters/gauges/histograms + per-request
-    # lifecycle timelines (TTFT / ITL / queue time). "trace":
-    # additionally records structured spans for every step() stage,
-    # exportable as a Chrome trace (``step_trace()``).
-    telemetry: str = "off"         # "off" | "basic" | "trace"
+    # lifecycle timelines (TTFT / ITL / queue time) + a span for every
+    # step() stage, also written into a jax.profiler trace when one runs
+    # and exportable as a Chrome trace (``step_trace()``).
+    telemetry: str = "off"         # "off" | "basic"
     # -- degraded-decode healing ----------------------------------------
     # After an injected kernel.decode fault flips ``degraded_decode`` the
     # engine stays on the jnp reference jits (a real fused-path failure
@@ -709,16 +709,16 @@ class EngineCore(CohortSchedulerMixin):
             t0 = time.perf_counter()
         with tel.span("step", step=self._step_calls):
             outs = self._step_inner()
-        if self.ecfg.audit_level != "off" \
-                and self.ecfg.scheduler == "continuous":
-            self.audit_steps += 1
-            with tel.span("audit", step=self._span_step):
-                vio = invariants_mod.audit(
-                    self, deep=self.ecfg.audit_level == "deep")
-            if vio:
-                raise EngineFault(
-                    f"invariant audit failed at step "
-                    f"{self.steps_executed}", violations=vio)
+            if self.ecfg.audit_level != "off" \
+                    and self.ecfg.scheduler == "continuous":
+                self.audit_steps += 1
+                with tel.span("audit", step=self._span_step):
+                    vio = invariants_mod.audit(
+                        self, deep=self.ecfg.audit_level == "deep")
+                if vio:
+                    raise EngineFault(
+                        f"invariant audit failed at step "
+                        f"{self.steps_executed}", violations=vio)
         if tel.enabled:
             tel.observe("engine_step_seconds", time.perf_counter() - t0,
                         help="Wall time of one step() iteration")
@@ -742,13 +742,11 @@ class EngineCore(CohortSchedulerMixin):
         b = self.ecfg.batch_slots
         drained = False
         self._fault_blocked = False
-        tel = self.tel
         self._span_step = self._step_calls
         self._step_calls += 1
         self._advance_prefills(outs)
         while True:
-            with tel.span("admit", step=self._span_step):
-                blocked = self._admit(outs)
+            blocked = self._admit(outs)
             active = [i for i in range(b)
                       if self._slot_req[i] is not None
                       and self._phases[i] != chai_cache.PHASE_PREFILL]
@@ -782,8 +780,7 @@ class EngineCore(CohortSchedulerMixin):
                 f"request uid={head.uid} needs {n * share} "
                 f"clustered pages; pool capacity "
                 f"{self.chai_pool.capacity}", uid=head.uid)
-        with tel.span("cluster", step=self._span_step):
-            self._cluster_transitions(active, outs)
+        self._cluster_transitions(active, outs)
         # A kernel.cluster quarantine may have retired slots mid-list.
         active = [i for i in active if self._slot_req[i] is not None]
         if active:
@@ -967,7 +964,7 @@ class EngineCore(CohortSchedulerMixin):
 
     def step_trace(self):
         """Chrome-trace JSON object of the recorded step spans (empty
-        below the "trace" tier)."""
+        when telemetry is off)."""
         return exporters_mod.to_chrome_trace(self.tel.spans)
 
     # -- continuous scheduler ----------------------------------------------
@@ -1678,73 +1675,75 @@ class EngineCore(CohortSchedulerMixin):
         blocked = False
         while self.queue and self.queue[0].t_arrival <= now:
             head = self.queue[0]
-            snap = self._eligible_snapshot(head)
-            if snap is not None and \
-                    head.max_new_tokens <= len(snap.tokens):
-                # Snapshot covers the whole request: serve it host-side
-                # without occupying a slot.
+            with self.tel.span("admit", step=self._span_step,
+                               uid=head.uid):
+                snap = self._eligible_snapshot(head)
+                if snap is not None and \
+                        head.max_new_tokens <= len(snap.tokens):
+                    # Snapshot covers the whole request: serve it host-side
+                    # without occupying a slot.
+                    req = self.queue.popleft()
+                    self._replay_request(req, snap)
+                    outs.append(StepOutput(req.uid, list(req.generated), True,
+                                           req.finish_reason))
+                    continue
+                free_slots = [i for i in range(self.ecfg.batch_slots)
+                              if self._slot_req[i] is None]
+                if not free_slots and not self._try_preempt(head):
+                    break
+                if not free_slots:      # preemption just freed a slot
+                    continue
+                try:
+                    plan = (self._plan_admission(head) if self.paged
+                            else {"kind": "cold", "pages": {}, "locked": []})
+                except RequestError as err:
+                    self._quarantine_queued(head, err, outs)
+                    continue
+                if plan is None:        # FIFO holds until pages free up
+                    if not self._fault_blocked and self._try_preempt(head):
+                        continue        # pages reclaimed — retry the plan
+                    blocked = True
+                    break
+                i = free_slots[0]
                 req = self.queue.popleft()
-                self._replay_request(req, snap)
-                outs.append(StepOutput(req.uid, list(req.generated), True,
-                                       req.finish_reason))
-                continue
-            free_slots = [i for i in range(self.ecfg.batch_slots)
-                          if self._slot_req[i] is None]
-            if not free_slots and not self._try_preempt(head):
-                break
-            if not free_slots:      # preemption just freed a slot
-                continue
-            try:
-                plan = (self._plan_admission(head) if self.paged
-                        else {"kind": "cold", "pages": {}, "locked": []})
-            except RequestError as err:
-                self._quarantine_queued(head, err, outs)
-                continue
-            if plan is None:        # FIFO holds until pages free up
-                if not self._fault_blocked and self._try_preempt(head):
-                    continue        # pages reclaimed — retry the plan
-                blocked = True
-                break
-            i = free_slots[0]
-            req = self.queue.popleft()
-            resumed = bool(req.generated)
-            gen0 = len(req.generated)
-            hit0 = (req.cache_hit, req.cached_tokens, req.prefill_tokens)
-            try:
-                self._admit_to_slot(i, req, plan)
-            except SnapshotRestoreError:
-                # Recoverable: unwind the admission, drop the damaged
-                # snapshot, and re-plan the request cold next iteration
-                # (greedy tokens are unchanged — snapshot replay is a
-                # parity guarantee, not a correctness dependency).
-                self._abort_admission(i, req, gen0, hit0)
-                self.prefix_cache.drop_snapshot(plan["snapshot"])
-                self.queue.appendleft(req)
-                continue
-            except RequestError as err:
-                self._abort_admission(i, req, gen0, hit0)
-                self._quarantine_queued(req, err, outs)
-                continue
-            if req.generated and not req.t_first_token:
-                req.t_first_token = time.time()
-            req.slot, req.admit_step = i, self.steps_executed
-            self._slot_req[i] = req
-            self._set_slot_sampling(i, req.sampling)
-            if self.tel.enabled:
-                self._tel_admit(i, req, plan, resumed)
-            if resumed:
-                continue    # tokens so far were already emitted/checked
-            trunc, reason = sampling_mod.scan_finish(
-                req.generated, req.sampling, req.max_new_tokens,
-                self.detokenizer)
-            if reason:
-                req.generated = trunc
-                self._retire_slot(i, reason)
-            if req.generated or reason:
-                # Chunked admissions have no first token yet — their
-                # StepOutput comes from the final chunk.
-                outs.append(StepOutput(req.uid, list(req.generated),
-                                       bool(reason), reason))
+                resumed = bool(req.generated)
+                gen0 = len(req.generated)
+                hit0 = (req.cache_hit, req.cached_tokens, req.prefill_tokens)
+                try:
+                    self._admit_to_slot(i, req, plan)
+                except SnapshotRestoreError:
+                    # Recoverable: unwind the admission, drop the damaged
+                    # snapshot, and re-plan the request cold next iteration
+                    # (greedy tokens are unchanged — snapshot replay is a
+                    # parity guarantee, not a correctness dependency).
+                    self._abort_admission(i, req, gen0, hit0)
+                    self.prefix_cache.drop_snapshot(plan["snapshot"])
+                    self.queue.appendleft(req)
+                    continue
+                except RequestError as err:
+                    self._abort_admission(i, req, gen0, hit0)
+                    self._quarantine_queued(req, err, outs)
+                    continue
+                if req.generated and not req.t_first_token:
+                    req.t_first_token = time.time()
+                req.slot, req.admit_step = i, self.steps_executed
+                self._slot_req[i] = req
+                self._set_slot_sampling(i, req.sampling)
+                if self.tel.enabled:
+                    self._tel_admit(i, req, plan, resumed)
+                if resumed:
+                    continue    # tokens so far were already emitted/checked
+                trunc, reason = sampling_mod.scan_finish(
+                    req.generated, req.sampling, req.max_new_tokens,
+                    self.detokenizer)
+                if reason:
+                    req.generated = trunc
+                    self._retire_slot(i, reason)
+                if req.generated or reason:
+                    # Chunked admissions have no first token yet — their
+                    # StepOutput comes from the final chunk.
+                    outs.append(StepOutput(req.uid, list(req.generated),
+                                           bool(reason), reason))
         return blocked
 
     def _admit_to_slot(self, i: int, req: Request, plan: dict):
@@ -1855,15 +1854,17 @@ class EngineCore(CohortSchedulerMixin):
             if st is None:
                 continue
             req = st["req"]
-            self._advance_chunk(i)
-            if self._slot_prefill_state[i] is not None:
-                continue                    # more chunks to go
-            # final chunk fired: the first token was just sampled
-            reason = self._finish_of(req)
-            if reason:
-                self._retire_slot(i, reason)
-            outs.append(StepOutput(req.uid, [req.generated[-1]],
-                                   bool(reason), reason))
+            with self.tel.span("prefill.advance", step=self._span_step,
+                               uid=req.uid):
+                self._advance_chunk(i)
+                if self._slot_prefill_state[i] is not None:
+                    continue                # more chunks to go
+                # final chunk fired: the first token was just sampled
+                reason = self._finish_of(req)
+                if reason:
+                    self._retire_slot(i, reason)
+                outs.append(StepOutput(req.uid, [req.generated[-1]],
+                                       bool(reason), reason))
 
     def _advance_chunk(self, i: int):
         """Prefill the next chunk of slot ``i``'s pending tokens. Chunk
@@ -2046,7 +2047,7 @@ class EngineCore(CohortSchedulerMixin):
                     for k in ("kg", "vg", "kc", "vc")]
             swap_out, _ = self._swap_fns_get()
             with self.tel.span("preempt.swap", step=self._span_step,
-                               slot=i):
+                               uid=r.uid, slot=i):
                 cols, pools = swap_out(self._dev_state, jnp.int32(i),
                                        *vecs)
             pools_host = jax.device_get(pools)
@@ -2133,53 +2134,55 @@ class EngineCore(CohortSchedulerMixin):
                     and self._phases[i] == chai_cache.PHASE_WARMUP):
                 continue
             req = self._slot_req[i]
-            if self._fault("kernel.cluster", uid=req.uid) is not None:
-                self._retire_slot(
-                    i, sampling_mod.FINISH_ERROR, index=False,
-                    error=f"injected cluster-transition failure for "
-                          f"uid={req.uid}")
-                outs.append(StepOutput(req.uid, [], True,
-                                       sampling_mod.FINISH_ERROR))
-                continue
-            self._phases[i] = chai_cache.PHASE_CLUSTER
-            self.cluster_transitions += 1
-            if self.tel.enabled:
-                self.tel.counter("cluster_transitions_total",
-                                 help="WARMUP->CLUSTER->STEADY "
-                                      "transitions executed")
-                self.tel.event(req.uid, "phase", phase="CLUSTER", slot=i)
-            if self.paged:
-                kc_vec = self._page_vec(self._slot_pages[i].get("kc", []))
-                vc_vec = self._page_vec(self._slot_pages[i].get("vc", []))
-                self._dev_state, self._dev_ctx = self._cluster_fn()(
-                    self._dev_state, self._dev_ctx, jnp.int32(i),
-                    kc_vec, vc_vec)
-                self._ctx_version += 1
-                if (self.prefix_cache is not None
-                        and self.chai_clustered
-                        and self._slot_req[i].sampling.greedy):
-                    self._capture_snapshot(i, self._slot_req[i],
-                                           self._slot_pages[i])
-                if self.chai_clustered:
-                    freed = len(self._slot_pages[i]["kg"])
-                    self.dense_pool.free(self._slot_pages[i].pop("kg"))
-                    if cfg.chai.share_values:
-                        freed += len(self._slot_pages[i]["vg"])
-                        self.dense_pool.free(self._slot_pages[i].pop("vg"))
-                    if self.tel.enabled:
-                        self.tel.counter(
-                            "chai_dense_pages_freed_total", freed,
-                            help="Dense pages freed at compaction (the "
-                                 "paper's KV saving, realized)")
-                self._record_kv_bytes(self._phases)
-            else:
-                self._dev_state, self._dev_ctx = self._cluster_fn()(
-                    self._dev_state, self._dev_ctx, jnp.int32(i))
-                self._ctx_version += 1
-            self._phases[i] = chai_cache.PHASE_STEADY
-            if self.tel.enabled:
-                self.tel.event(req.uid, "phase", phase="STEADY", slot=i)
-                self._tel_clusters(i)
+            with self.tel.span("cluster", step=self._span_step,
+                               uid=req.uid):
+                if self._fault("kernel.cluster", uid=req.uid) is not None:
+                    self._retire_slot(
+                        i, sampling_mod.FINISH_ERROR, index=False,
+                        error=f"injected cluster-transition failure for "
+                              f"uid={req.uid}")
+                    outs.append(StepOutput(req.uid, [], True,
+                                           sampling_mod.FINISH_ERROR))
+                    continue
+                self._phases[i] = chai_cache.PHASE_CLUSTER
+                self.cluster_transitions += 1
+                if self.tel.enabled:
+                    self.tel.counter("cluster_transitions_total",
+                                     help="WARMUP->CLUSTER->STEADY "
+                                          "transitions executed")
+                    self.tel.event(req.uid, "phase", phase="CLUSTER", slot=i)
+                if self.paged:
+                    kc_vec = self._page_vec(self._slot_pages[i].get("kc", []))
+                    vc_vec = self._page_vec(self._slot_pages[i].get("vc", []))
+                    self._dev_state, self._dev_ctx = self._cluster_fn()(
+                        self._dev_state, self._dev_ctx, jnp.int32(i),
+                        kc_vec, vc_vec)
+                    self._ctx_version += 1
+                    if (self.prefix_cache is not None
+                            and self.chai_clustered
+                            and self._slot_req[i].sampling.greedy):
+                        self._capture_snapshot(i, self._slot_req[i],
+                                               self._slot_pages[i])
+                    if self.chai_clustered:
+                        freed = len(self._slot_pages[i]["kg"])
+                        self.dense_pool.free(self._slot_pages[i].pop("kg"))
+                        if cfg.chai.share_values:
+                            freed += len(self._slot_pages[i]["vg"])
+                            self.dense_pool.free(self._slot_pages[i].pop("vg"))
+                        if self.tel.enabled:
+                            self.tel.counter(
+                                "chai_dense_pages_freed_total", freed,
+                                help="Dense pages freed at compaction (the "
+                                     "paper's KV saving, realized)")
+                    self._record_kv_bytes(self._phases)
+                else:
+                    self._dev_state, self._dev_ctx = self._cluster_fn()(
+                        self._dev_state, self._dev_ctx, jnp.int32(i))
+                    self._ctx_version += 1
+                self._phases[i] = chai_cache.PHASE_STEADY
+                if self.tel.enabled:
+                    self.tel.event(req.uid, "phase", phase="STEADY", slot=i)
+                    self._tel_clusters(i)
 
     # -- shared-prefix relay decode ----------------------------------------
     def _ctx_host(self):
@@ -2423,20 +2426,21 @@ class EngineCore(CohortSchedulerMixin):
         tel = self.tel
         step_no = self._span_step
         b = self.ecfg.batch_slots
-        if self._tok_dirty:
-            self._next_tok_dev = jnp.asarray(self._next_tok)
-            self._tok_dirty = False
-        inputs = {"tokens": self._next_tok_dev}
         occupied = self._phases[self._phases != chai_cache.PHASE_FREE]
         with tel.span("relay.form", step=step_no):
             relay = self._build_relay(active) if self.relay_decode \
                 else None
         self._decode_fault_hit = False
+        program = self._decode_program(relay, occupied)
         try:
-            with tel.span("decode.dispatch", step=step_no,
+            with tel.span("decode.dispatch", step=step_no, program=program,
                           degraded=self.degraded_decode):
-                logits, state = self._dispatch_decode(inputs, relay,
-                                                      occupied)
+                if self._tok_dirty:
+                    self._next_tok_dev = jnp.asarray(self._next_tok)
+                    self._tok_dirty = False
+                inputs = {"tokens": self._next_tok_dev}
+                logits, state = self._dispatch_decode(program, inputs,
+                                                      relay)
         except EngineFault:
             raise
         except InjectedFault:
@@ -2456,11 +2460,12 @@ class EngineCore(CohortSchedulerMixin):
                 tel.gauge("engine_degraded_decode", 1,
                           help="1 while decode runs the jnp reference "
                                "fallback")
+            program = self._decode_program(None, occupied)
             try:
                 with tel.span("decode.dispatch", step=step_no,
-                              degraded=True, retry=True):
-                    logits, state = self._dispatch_decode(inputs, None,
-                                                          occupied)
+                              program=program, degraded=True, retry=True):
+                    logits, state = self._dispatch_decode(program, inputs,
+                                                          None)
             except Exception as err2:
                 raise EngineFault(
                     "decode failed on the jnp reference fallback after "
@@ -2476,116 +2481,122 @@ class EngineCore(CohortSchedulerMixin):
                 if self._fault("step.logits",
                                uid=self._slot_req[i].uid) is not None:
                     logits = logits.at[i].set(jnp.nan)
-        sample_cm = tel.span("sample", step=step_no)
-        sample_cm.__enter__()
-        finite = np.asarray(self._finite_rows(logits))
-        self._dev_state = state
-        temps = self._samp_host["temperature"]
-        if not temps.any():
-            tok_dev = self._argmax(logits)      # all-greedy fast path
-        else:
-            counts = np.zeros((b,), np.int32)
-            for i in active:
-                counts[i] = len(self._slot_req[i].generated)
-            rows = np.nonzero(temps > 0.0)[0]
-            nb = 1 << (len(rows) - 1).bit_length()
-            if nb < b:
-                # Mixed batch: greedy slots skip the sampling lane
-                # (argsort + softmax + PRNG) entirely — the sampler runs
-                # on a gathered power-of-two sub-batch of the sampling
-                # rows, scattered over the batched argmax. Bitwise-
-                # identical to the full sampler: each row's draw depends
-                # only on that row's (logits, params, seed, count), and
-                # greedy rows argmax the same raw f32 logits either way.
-                idx = np.full((nb,), rows[0], np.int32)   # pad: dup row0
-                idx[:len(rows)] = rows
-                idx_dev = jnp.asarray(idx)
-                drawn = self._sampler(
-                    self._take_rows(logits, idx_dev),
-                    jnp.asarray(temps[idx]),
-                    jnp.asarray(self._samp_host["top_k"][idx]),
-                    jnp.asarray(self._samp_host["top_p"][idx]),
-                    jnp.asarray(self._samp_host["seed"][idx]),
-                    jnp.asarray(counts[idx]))
-                tok_dev = self._put_rows(self._argmax(logits), idx_dev,
-                                         drawn)
+        with tel.span("sample", step=step_no):
+            finite = np.asarray(self._finite_rows(logits))
+            self._dev_state = state
+            temps = self._samp_host["temperature"]
+            if not temps.any():
+                tok_dev = self._argmax(logits)      # all-greedy fast path
             else:
-                if self._samp_dirty:
-                    self._samp_dev = {k: jnp.asarray(v)
-                                      for k, v in self._samp_host.items()}
-                    self._samp_dirty = False
-                tok_dev = self._sampler(logits,
-                                        self._samp_dev["temperature"],
-                                        self._samp_dev["top_k"],
-                                        self._samp_dev["top_p"],
-                                        self._samp_dev["seed"],
-                                        jnp.asarray(counts))
-        self._next_tok_dev = tok_dev
-        toks = np.asarray(tok_dev)
-        self._next_tok[:] = toks
-        sample_cm.__exit__(None, None, None)
+                counts = np.zeros((b,), np.int32)
+                for i in active:
+                    counts[i] = len(self._slot_req[i].generated)
+                rows = np.nonzero(temps > 0.0)[0]
+                nb = 1 << (len(rows) - 1).bit_length()
+                if nb < b:
+                    # Mixed batch: greedy slots skip the sampling lane
+                    # (argsort + softmax + PRNG) entirely — the sampler runs
+                    # on a gathered power-of-two sub-batch of the sampling
+                    # rows, scattered over the batched argmax. Bitwise-
+                    # identical to the full sampler: each row's draw depends
+                    # only on that row's (logits, params, seed, count), and
+                    # greedy rows argmax the same raw f32 logits either way.
+                    idx = np.full((nb,), rows[0], np.int32)   # pad: dup row0
+                    idx[:len(rows)] = rows
+                    idx_dev = jnp.asarray(idx)
+                    drawn = self._sampler(
+                        self._take_rows(logits, idx_dev),
+                        jnp.asarray(temps[idx]),
+                        jnp.asarray(self._samp_host["top_k"][idx]),
+                        jnp.asarray(self._samp_host["top_p"][idx]),
+                        jnp.asarray(self._samp_host["seed"][idx]),
+                        jnp.asarray(counts[idx]))
+                    tok_dev = self._put_rows(self._argmax(logits), idx_dev,
+                                             drawn)
+                else:
+                    if self._samp_dirty:
+                        self._samp_dev = {k: jnp.asarray(v)
+                                          for k, v in self._samp_host.items()}
+                        self._samp_dirty = False
+                    tok_dev = self._sampler(logits,
+                                            self._samp_dev["temperature"],
+                                            self._samp_dev["top_k"],
+                                            self._samp_dev["top_p"],
+                                            self._samp_dev["seed"],
+                                            jnp.asarray(counts))
+            self._next_tok_dev = tok_dev
+            toks = np.asarray(tok_dev)
+            self._next_tok[:] = toks
         self.steps_executed += 1
-        retire_cm = tel.span("retire", step=step_no)
-        retire_cm.__enter__()
-        for i in active:
-            r = self._slot_req[i]
-            if not finite[i]:
-                # NaN/Inf logits: the slot's sampled token is garbage —
-                # quarantine this request; rows are independent, so the
-                # other slots' draws are exactly what they would have
-                # been.
-                self._retire_slot(
-                    i, sampling_mod.FINISH_ERROR, index=False,
-                    error=f"non-finite logits for uid={r.uid} at step "
-                          f"{self.steps_executed - 1}")
-                outs.append(StepOutput(r.uid, [], True,
-                                       sampling_mod.FINISH_ERROR))
-                continue
-            r.generated.append(int(toks[i]))
-            self._slot_count[i] += 1
-            if tel.enabled:
-                tel.counter("tokens_generated_total",
-                            help="Generated tokens emitted")
-                tel.token(r.uid)
-            reason = self._finish_of(r)
-            if reason:
-                self._retire_slot(i, reason)
-            outs.append(StepOutput(r.uid, [int(toks[i])], bool(reason),
-                                   reason))
-        retire_cm.__exit__(None, None, None)
+        with tel.span("retire", step=step_no):
+            for i in active:
+                r = self._slot_req[i]
+                if not finite[i]:
+                    # NaN/Inf logits: the slot's sampled token is garbage —
+                    # quarantine this request; rows are independent, so the
+                    # other slots' draws are exactly what they would have
+                    # been.
+                    self._retire_slot(
+                        i, sampling_mod.FINISH_ERROR, index=False,
+                        error=f"non-finite logits for uid={r.uid} at step "
+                              f"{self.steps_executed - 1}")
+                    outs.append(StepOutput(r.uid, [], True,
+                                           sampling_mod.FINISH_ERROR))
+                    continue
+                r.generated.append(int(toks[i]))
+                self._slot_count[i] += 1
+                if tel.enabled:
+                    tel.counter("tokens_generated_total",
+                                help="Generated tokens emitted")
+                    tel.token(r.uid)
+                reason = self._finish_of(r)
+                if reason:
+                    self._retire_slot(i, reason)
+                outs.append(StepOutput(r.uid, [int(toks[i])], bool(reason),
+                                       reason))
         if self.paged:
-            self._record_kv_bytes(self._phases)
+            with tel.span("kv.account", step=step_no):
+                self._record_kv_bytes(self._phases)
         return outs
 
-    def _dispatch_decode(self, inputs, relay, occupied):
-        """Host-dispatch the cheapest step jit covering the phase mix
-        (relay -> all-CHAI -> all-MHA -> mixed). ``degraded_decode``
-        swaps in the jnp reference jits (``_jnp_decode_steps``) — same
-        makers, traced with the fused kernels disabled."""
+    def _decode_program(self, relay, occupied) -> str:
+        """The cheapest decode program covering the phase mix of the
+        ``occupied`` slots: ``relay``, ``chai`` (all STEADY), ``mha`` (all
+        WARMUP, or CHAI off) or ``mixed``."""
+        if relay is not None:
+            return "relay"
+        if not self.chai_on:
+            return "mha"
+        if (occupied == chai_cache.PHASE_STEADY).all():
+            return "chai"
+        if (occupied == chai_cache.PHASE_WARMUP).all():
+            return "mha"
+        return "mixed"
+
+    def _dispatch_decode(self, program, inputs, relay):
+        """Host-dispatch the step jit ``program`` names
+        (``_decode_program``). ``degraded_decode`` swaps in the jnp
+        reference jits (``_jnp_decode_steps``) — same makers, traced with
+        the fused kernels disabled."""
         state = self._dev_state
         if self._fault("kernel.decode") is not None:
             self._decode_fault_hit = True
             if not self.degraded_decode:
                 raise InjectedFault("kernel.decode")
-        if relay is not None:
+        if program == "relay":
             self.relay_steps += 1
             return self._relay_step(self.params, inputs, state,
                                     self._dev_ctx, relay)
         if self.degraded_decode:
-            steps = self._jnp_decode_steps()
-            mha = steps["mha"]
-            chai, mixed = steps.get("chai"), steps.get("mixed")
+            step = self._jnp_decode_steps()[program]
         else:
-            mha = self._mha_step
-            chai = self._chai_step if self.chai_on else None
-            mixed = self._mixed_step if self.chai_on else None
-        if not self.chai_on:
-            return mha(self.params, inputs, state)
-        if (occupied == chai_cache.PHASE_STEADY).all():
-            return chai(self.params, inputs, state, self._dev_ctx)
-        if (occupied == chai_cache.PHASE_WARMUP).all():
-            return mha(self.params, inputs, state)
-        return mixed(self.params, inputs, state, self._dev_ctx)
+            step = {"mha": self._mha_step,
+                    "chai": self._chai_step if self.chai_on else None,
+                    "mixed": self._mixed_step if self.chai_on else None,
+                    }[program]
+        if program == "mha":
+            return step(self.params, inputs, state)
+        return step(self.params, inputs, state, self._dev_ctx)
 
     def _maybe_heal(self):
         """Degraded-decode healing: after ``decode_heal_steps``

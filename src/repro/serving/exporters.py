@@ -166,13 +166,17 @@ def to_chrome_trace(spans: Iterable[Dict[str, Any]],
 
     Span times are perf_counter seconds; Chrome wants microseconds.
     Every span becomes one complete ("ph": "X") event on pid/tid 0 with
-    the step number and any args attached.
+    the step number, its id, its parent's id, the request uid and any
+    args attached.
     """
     events = []
     for sp in spans:
         args = dict(sp.get("args") or {})
         if sp.get("step", -1) >= 0:
             args["step"] = sp["step"]
+        for key in ("id", "parent", "uid"):
+            if sp.get(key) is not None:
+                args[key] = sp[key]
         if sp.get("error"):
             args["error"] = True
         events.append({

@@ -1,4 +1,4 @@
-"""Engine-wide telemetry: metrics registry, step traces, request timelines.
+"""Engine-wide telemetry: metrics registry, step spans, request timelines.
 
 Zero-dependency observability substrate for the serving engine, tiered by
 ``EngineConfig.telemetry``:
@@ -6,16 +6,22 @@ Zero-dependency observability substrate for the serving engine, tiered by
   off    null object; every hook is a no-op and the decode hot path is
          provably untouched (jaxpr-identical step — see
          benchmarks/bench_telemetry_overhead.py).
-  basic  MetricsRegistry counters/gauges/histograms + per-request
+  basic  MetricsRegistry counters/gauges/histograms, per-request
          lifecycle timelines (enqueue → admit → phase transitions →
-         first token → finish).  No spans.
-  trace  everything in basic, plus structured spans for every
-         ``EngineCore.step()`` stage, exportable as a Chrome trace.
+         first token → finish), and a span for every stage of
+         ``EngineCore.step()``.
 
-The registry is snapshot-able (JSON-ready dict) and mergeable so a future
-sharded EngineCore can aggregate per-shard registries into one scrape.
-Export formats (Prometheus text, Chrome trace JSON, JSONL event logs)
-live in ``serving/exporters.py``.
+A span records its name, start and end (``perf_counter`` seconds), the
+step ordinal, its own id and its parent's (the span open around it), and
+the request uid where it acts on one request. Spans live in a ring of
+the newest ``max_spans``; the evicted are counted. Every span is also a
+``jax.profiler.TraceAnnotation("engine.<name>")``: under a profiler
+session it lands in the trace beside the device's operations, on the
+profiler's clock; without one it costs a no-op in C++.
+
+The registry is snapshot-able (JSON-ready dict). Export formats
+(Prometheus text, Chrome trace JSON, JSONL event logs) live in
+``serving/exporters.py``.
 
 All instrumentation hooks that allocate or format are guarded engine-side
 by ``tel.enabled`` / handed a shared null context manager, so the "off"
@@ -29,7 +35,7 @@ import threading
 import time
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
-TIERS = ("off", "basic", "trace")
+TIERS = ("off", "basic")
 
 # Default histogram buckets (seconds scale — covers sub-ms CPU decode
 # steps through multi-second prefill/queue waits).  +Inf is implicit.
@@ -48,9 +54,7 @@ class MetricsRegistry:
 
     Names follow Prometheus conventions (``snake_case``, counters end in
     ``_total``, timings in ``_seconds``).  A (name, label-set) pair is one
-    series.  ``snapshot()`` returns a plain JSON-ready dict; ``merge()``
-    folds another snapshot in (counters and histogram buckets add;
-    gauges add too, i.e. merged gauges read as cross-shard totals).
+    series.  ``snapshot()`` returns a plain JSON-ready dict.
     """
 
     def __init__(self):
@@ -138,58 +142,49 @@ class MetricsRegistry:
                     for n, m in sorted(self._histograms.items())},
             }
 
-    def merge(self, snap: Dict[str, Any]) -> None:
-        """Fold another registry's ``snapshot()`` into this one."""
-        for name, m in snap.get("counters", {}).items():
-            for s in m["series"]:
-                self.counter(name, s["value"], labels=s["labels"],
-                             help=m.get("help", ""))
-        for name, m in snap.get("gauges", {}).items():
-            for s in m["series"]:
-                key = _label_key(s["labels"])
-                with self._lock:
-                    g = self._gauges.setdefault(
-                        name, {"help": m.get("help", ""), "series": {}})
-                    g["series"][key] = g["series"].get(key, 0.0) + s["value"]
-        for name, m in snap.get("histograms", {}).items():
-            bks = tuple(m["buckets"])
-            with self._lock:
-                h = self._histograms.setdefault(
-                    name, {"help": m.get("help", ""), "buckets": bks,
-                           "series": {}})
-                if tuple(h["buckets"]) != bks:
-                    raise ValueError(
-                        f"histogram {name}: bucket mismatch on merge")
-                for s in m["series"]:
-                    key = _label_key(s["labels"])
-                    t = h["series"].get(key)
-                    if t is None:
-                        t = h["series"][key] = {
-                            "counts": [0] * (len(bks) + 1),
-                            "sum": 0.0, "count": 0}
-                    for i, c in enumerate(s["counts"]):
-                        t["counts"][i] += c
-                    t["sum"] += s["sum"]
-                    t["count"] += s["count"]
-
 
 class _Span:
-    """Context manager recording one closed span into the telemetry sink."""
+    """Context manager recording one closed span into the telemetry sink,
+    inside a profiler annotation of the same name."""
 
-    __slots__ = ("_tel", "name", "step", "args", "t0")
+    __slots__ = ("_tel", "name", "step", "uid", "args", "id", "parent",
+                 "t0", "_ann")
 
-    def __init__(self, tel, name, step, args):
-        self._tel, self.name, self.step, self.args = tel, name, step, args
-        self.t0 = 0.0
+    def __init__(self, tel, name, step, uid, args):
+        self._tel, self.name, self.step = tel, name, step
+        self.uid, self.args = uid, args
 
     def __enter__(self):
+        tel = self._tel
+        self.id = tel._next_id
+        tel._next_id += 1
+        self.parent = tel._open[-1] if tel._open else None
+        tel._open.append(self.id)
+        meta = dict(self.args) if self.args else {}
+        if self.uid is not None:
+            meta["uid"] = self.uid
+        self._ann = tel._profiler.TraceAnnotation("engine." + self.name,
+                                                  **meta)
+        self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter()
-        self._tel._add_span(self.name, self.step, self.t0, t1,
-                            self.args, error=exc_type is not None)
+        self._ann.__exit__(exc_type, exc, tb)
+        tel = self._tel
+        tel._open.pop()
+        sp = {"name": self.name, "step": self.step, "t0": self.t0,
+              "t1": t1, "id": self.id, "parent": self.parent}
+        if self.uid is not None:
+            sp["uid"] = self.uid
+        if self.args:
+            sp["args"] = self.args
+        if exc_type is not None:
+            sp["error"] = True
+        if len(tel.spans) == tel.max_spans:
+            tel.spans_evicted += 1
+        tel.spans.append(sp)
         return False
 
 
@@ -238,26 +233,29 @@ def summarize_timeline(events: List[Dict[str, Any]]) -> Dict[str, Any]:
 
 
 class Telemetry:
-    """Live telemetry sink for one EngineCore (basic and trace tiers).
+    """Live telemetry sink for one EngineCore (the basic tier).
 
-    Spans (trace tier) are bounded: once ``max_spans`` are held, further
-    spans are counted in ``spans_dropped`` instead of stored.  Finished
-    request timelines are kept in an LRU of ``max_timelines``; in-flight
+    Spans are bounded: a ring keeps the newest ``max_spans`` and counts
+    the older ones it evicts in ``spans_evicted``. Finished request
+    timelines are kept in an LRU of ``max_timelines``; in-flight
     timelines are unbounded but naturally small (≤ queue + slots).
     """
 
-    def __init__(self, tier: str = "basic", *, max_spans: int = 1 << 16,
+    tier = "basic"
+    enabled = True
+
+    def __init__(self, *, max_spans: int = 1 << 16,
                  max_timelines: int = 1024):
-        if tier not in TIERS or tier == "off":
-            raise ValueError(f"Telemetry tier must be basic|trace, got {tier}")
-        self.tier = tier
-        self.enabled = True
-        self.tracing = tier == "trace"
+        import jax.profiler
+        self._profiler = jax.profiler
         self.registry = MetricsRegistry()
         self.max_spans = max_spans
         self.max_timelines = max_timelines
-        self.spans: List[Dict[str, Any]] = []
-        self.spans_dropped = 0
+        self.spans: "collections.deque[Dict[str, Any]]" = \
+            collections.deque(maxlen=max_spans)
+        self.spans_evicted = 0
+        self._next_id = 0
+        self._open: List[int] = []      # open span ids, outermost first
         self._active: Dict[str, List[Dict[str, Any]]] = {}
         self._finished: "collections.OrderedDict[str, List[Dict[str, Any]]]" \
             = collections.OrderedDict()
@@ -275,21 +273,10 @@ class Telemetry:
                               buckets=buckets, help=help)
 
     # -- spans -----------------------------------------------------------
-    def span(self, name: str, step: int = -1, **args):
-        if not self.tracing:
-            return _NULL_CM
-        return _Span(self, name, step, args or None)
-
-    def _add_span(self, name, step, t0, t1, args, error=False):
-        if len(self.spans) >= self.max_spans:
-            self.spans_dropped += 1
-            return
-        sp = {"name": name, "step": step, "t0": t0, "t1": t1}
-        if args:
-            sp["args"] = args
-        if error:
-            sp["error"] = True
-        self.spans.append(sp)
+    def span(self, name: str, step: int = -1, uid=None, **args):
+        """A span of one engine stage; ``uid`` names the request it acts
+        on, ``args`` are kept with it (and in the profiler's trace)."""
+        return _Span(self, name, step, uid, args or None)
 
     # -- request timelines ----------------------------------------------
     def event(self, uid: str, name: str, t: Optional[float] = None, **data):
@@ -348,7 +335,7 @@ class Telemetry:
     def snapshot(self) -> Dict[str, Any]:
         snap = self.registry.snapshot()
         snap["meta"] = {"tier": self.tier, "spans": len(self.spans),
-                        "spans_dropped": self.spans_dropped,
+                        "spans_evicted": self.spans_evicted,
                         "timelines": len(self._active) + len(self._finished)}
         return snap
 
@@ -359,10 +346,9 @@ class NullTelemetry:
 
     tier = "off"
     enabled = False
-    tracing = False
     registry = None
-    spans: List[Dict[str, Any]] = []
-    spans_dropped = 0
+    spans: Tuple[Dict[str, Any], ...] = ()
+    spans_evicted = 0
 
     def counter(self, *a, **k):
         pass
@@ -404,4 +390,4 @@ def make_telemetry(tier: str):
         raise ValueError(f"telemetry tier must be one of {TIERS}, got {tier!r}")
     if tier == "off":
         return NullTelemetry()
-    return Telemetry(tier)
+    return Telemetry()

@@ -47,7 +47,7 @@ def _ecfg(**kw):
     kw.setdefault("max_seq", 64)
     kw.setdefault("page_size", 8)
     kw.setdefault("audit_level", "deep")
-    kw.setdefault("telemetry", "trace")
+    kw.setdefault("telemetry", "basic")
     return EngineConfig(**kw)
 
 
@@ -99,25 +99,6 @@ def test_registry_counter_gauge_histogram_series():
         reg.counter("neg_total", -1)
 
 
-def test_registry_merge_adds_counters_and_buckets():
-    a, b = MetricsRegistry(), MetricsRegistry()
-    for reg, n in ((a, 2), (b, 5)):
-        reg.counter("req_total", n, labels={"kind": "x"})
-        reg.observe("lat_seconds", 0.002, buckets=(0.001, 0.01))
-        reg.gauge("depth", n)
-    a.merge(b.snapshot())
-    snap = a.snapshot()
-    assert _counter_value(snap, "req_total", kind="x") == 7
-    h = snap["histograms"]["lat_seconds"]["series"][0]
-    assert h["count"] == 2 and h["counts"][1] == 2
-    # merged gauges read as cross-shard totals
-    assert snap["gauges"]["depth"]["series"][0]["value"] == 7
-    bad = MetricsRegistry()
-    bad.observe("lat_seconds", 0.002, buckets=(0.5,))
-    with pytest.raises(ValueError):
-        a.merge(bad.snapshot())
-
-
 def test_summarize_timeline_derivations():
     evs = [
         {"uid": 1, "ev": "enqueue", "t": 10.0},
@@ -143,10 +124,11 @@ def test_make_telemetry_tiers():
     assert isinstance(make_telemetry("off"), NullTelemetry)
     assert not make_telemetry("off").enabled
     assert isinstance(make_telemetry("basic"), Telemetry)
-    assert make_telemetry("trace").tracing
-    assert not make_telemetry("basic").tracing
-    with pytest.raises(ValueError):
-        make_telemetry("verbose")
+    assert make_telemetry("basic").enabled
+    # The tiers are off | basic: spans are recorded at basic.
+    for tier in ("trace", "verbose"):
+        with pytest.raises(ValueError):
+            make_telemetry(tier)
     cfg, params = _model()
     with pytest.raises(ValueError):
         EngineCore(cfg, params, _ecfg(telemetry="loud"))
@@ -223,10 +205,10 @@ def test_off_tier_is_noop():
 def test_off_tier_decode_step_jaxpr_identical():
     """The telemetry tier never reaches the device program (fast unit
     variant of the bench gate): identical decode-step jaxpr text for an
-    off engine and a trace engine."""
+    off engine and a basic engine, which records spans."""
     cfg, params = _model()
     off = EngineCore(cfg, params, _ecfg(telemetry="off"))
-    trc = EngineCore(cfg, params, _ecfg(telemetry="trace"))
+    trc = EngineCore(cfg, params, _ecfg(telemetry="basic"))
     off.add_request(_prompts(1)[0], GREEDY)
     _drain(off)
     ex = (off.params, {"tokens": off._next_tok_dev}, off._dev_state)
@@ -237,6 +219,9 @@ def test_off_tier_decode_step_jaxpr_identical():
 
 
 def test_trace_tier_step_spans_cover_stages():
+    """At basic every decode step records one step span holding one
+    decode.dispatch, sample, retire, kv.account and audit; admissions and
+    CLUSTER transitions record one span per request."""
     cfg, params = _model()
     core = EngineCore(cfg, params, _ecfg())
     [core.add_request(p, GREEDY) for p in _prompts(2)]
@@ -248,18 +233,138 @@ def test_trace_tier_step_spans_cover_stages():
                     if "decode.dispatch" in n}
     assert decode_steps, by_step
     for names in decode_steps.values():
-        assert names.count("admit") >= 1
-        for stage in ("cluster", "decode.dispatch", "sample", "retire",
+        for stage in ("decode.dispatch", "sample", "retire", "kv.account",
                       "step", "audit"):
             assert names.count(stage) == 1, (stage, names)
+    names = [sp["name"] for sp in core.tel.spans]
+    assert names.count("admit") == 2 and names.count("cluster") == 2
     # step ordinals are unique per step() call, monotone
     steps = sorted(by_step)
     assert steps == list(range(steps[0], steps[0] + len(steps)))
-    # basic tier records no spans at all
-    core2 = EngineCore(cfg, params, _ecfg(telemetry="basic"))
+    # off records no spans at all
+    core2 = EngineCore(cfg, params, _ecfg(telemetry="off"))
     core2.add_request(_prompts(1)[0], GREEDY)
     _drain(core2)
-    assert core2.tel.spans == []
+    assert list(core2.tel.spans) == []
+
+
+def test_step_spans_nest_with_parent_ids_and_uids():
+    """Each stage span's parent is the span open around it: the decode
+    stages and the per-request stages sit under their step's ``step``
+    span, and the per-request ones (chunked prefill, admission, CLUSTER)
+    carry the request's uid. ``decode.dispatch`` names the program."""
+    cfg, params = _model()
+    core = EngineCore(cfg, params, _ecfg(prefill_chunk_tokens=8))
+    reqs = [core.add_request(p, GREEDY)
+            for p in _prompts(2, length=(12, 20), seed=11)]
+    _drain(core)
+    uids = {r.uid for r in reqs}
+    spans = list(core.tel.spans)
+    by_id = {sp["id"]: sp for sp in spans}
+    assert len(by_id) == len(spans)
+    steps = {sp["step"]: sp for sp in spans if sp["name"] == "step"}
+    for sp in spans:
+        if sp["name"] == "step":
+            assert sp["parent"] is None
+            continue
+        parent = by_id[sp["parent"]]
+        assert parent["t0"] <= sp["t0"] <= sp["t1"] <= parent["t1"]
+        if sp["name"] in ("prefill.advance", "admit", "cluster",
+                          "decode.dispatch", "sample", "retire",
+                          "kv.account", "audit"):
+            assert parent is steps[sp["step"]], sp
+        if sp["name"] in ("prefill.advance", "admit", "cluster"):
+            assert sp["uid"] in uids, sp
+    names = [sp["name"] for sp in spans]
+    for stage in ("prefill.advance", "admit", "cluster"):
+        assert names.count(stage) >= len(reqs), (stage, names)
+    programs = {sp["args"]["program"] for sp in spans
+                if sp["name"] == "decode.dispatch"}
+    assert "chai" in programs and programs <= {"chai", "mha", "mixed"}
+    # The Chrome export carries the ids, parents and uids.
+    evs = core.step_trace()["traceEvents"]
+    assert {e["args"]["uid"] for e in evs if e["name"] == "admit"} == uids
+    assert all("parent" in e["args"] for e in evs if e["name"] == "sample")
+
+
+def test_off_tier_creates_no_profiler_annotation(monkeypatch):
+    """``off`` hands out the shared null context manager: no span and no
+    ``jax.profiler.TraceAnnotation``. ``basic`` makes one annotation per
+    span, named ``engine.<stage>``."""
+    made = []
+
+    class Counting(jax.profiler.TraceAnnotation):
+        def __init__(self, name, **kw):
+            made.append(name)
+            super().__init__(name, **kw)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    cfg, params = _model()
+    off = EngineCore(cfg, params, _ecfg(telemetry="off"))
+    off.add_request(_prompts(1)[0], GREEDY)
+    _drain(off)
+    assert made == [] and list(off.tel.spans) == []
+    on = EngineCore(cfg, params, _ecfg(telemetry="basic"))
+    on.add_request(_prompts(1)[0], GREEDY)
+    _drain(on)
+    assert made == ["engine." + sp["name"] for sp in
+                    sorted(on.tel.spans, key=lambda sp: sp["id"])]
+
+
+def test_span_ring_keeps_newest_and_counts_evicted():
+    tel = Telemetry(max_spans=4)
+    for i in range(10):
+        with tel.span(f"s{i}", step=i):
+            pass
+    assert [sp["name"] for sp in tel.spans] == ["s6", "s7", "s8", "s9"]
+    assert [sp["id"] for sp in tel.spans] == [6, 7, 8, 9]
+    assert tel.spans_evicted == 6
+    assert tel.snapshot()["meta"]["spans_evicted"] == 6
+    # Nesting gives parent ids; a raising span is recorded as an error
+    # and closes, so the next span's parent is again the outer one.
+    with tel.span("outer") as outer:
+        with pytest.raises(RuntimeError):
+            with tel.span("inner", uid=7):
+                raise RuntimeError("boom")
+        with tel.span("next"):
+            pass
+    inner, nxt, out = list(tel.spans)[-3:]
+    assert inner["parent"] == outer.id and inner["error"] is True
+    assert inner["uid"] == 7
+    assert nxt["parent"] == outer.id and out["parent"] is None
+
+
+def test_engine_spans_land_in_profiler_trace(tmp_path):
+    """Under ``jax.profiler.trace`` the engine's spans are host events of
+    the trace (``engine.<stage>``), on the profiler's clock, each child
+    inside its step; the program writes no ``bench.*`` event."""
+    from jax.profiler import ProfileData
+    cfg, params = _model()
+    core = EngineCore(cfg, params, _ecfg())
+    core.add_request(_prompts(1)[0], GREEDY)
+    core.step()                               # compile outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        for _ in range(3):
+            core.step()
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    host = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            host += [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                     for ev in line.events]
+    assert not [n for n, _, _ in host if n.startswith("bench.")]
+    ev = {}
+    for n, a, b in host:
+        ev.setdefault(n, []).append((a, b))
+    steps = ev["engine.step"]
+    assert len(steps) == 3
+    for child in ("engine.decode.dispatch", "engine.sample",
+                  "engine.retire"):
+        assert len(ev[child]) == 3, child
+        for a, b in ev[child]:
+            assert any(s <= a and b <= e for s, e in steps), child
 
 
 def test_request_timeline_lifecycle_and_metrics():
@@ -444,7 +549,7 @@ def test_kernel_cluster_fault_quarantines_transitioning_request():
 def test_soak_report_carries_telemetry_section():
     from repro.serving.soak import run_soak
     cfg, params = _model()
-    ecfg = _ecfg(batch_slots=3, prefix_cache=True, telemetry="trace")
+    ecfg = _ecfg(batch_slots=3, prefix_cache=True, telemetry="basic")
     report = run_soak(cfg, params, ecfg, seed=3, n_requests=8)
     tel = report["telemetry"]
     assert tel["metrics"]["counters"]["engine_steps_total"]
@@ -469,7 +574,7 @@ def test_async_metrics_and_timeline_accessors():
 
     async def main():
         kw = dict(batch_slots=2, max_seq=64, page_size=8,
-                  telemetry="trace")
+                  telemetry="basic")
         async with AsyncLLM(cfg, params, EngineConfig(**kw)) as llm:
             out = await llm.generate(prompt, GREEDY)
             assert len(out.token_ids) == 8
