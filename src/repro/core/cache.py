@@ -33,6 +33,12 @@ Three layouts, one phase machine (PREFILL -> WARMUP -> CLUSTER -> STEADY):
    saving at the allocator level (``paged_kv_bytes``) instead of only
    analytically. int8 caches keep per-row scales in mirror-shaped scale
    pools (``kvp_scale``/``cp_scale``) indexed by the same block tables.
+   Every pool is one array stacked by global layer, (L, nP, rows,
+   page[, hd]); decode steps read and write it in place at (layer, page):
+   the fused decode kernel and the token-row write kernel
+   (``ops.paged_kv_write_rows``) take the layer as a scalar-prefetched
+   index, and the jnp paths gather ``pool[layer, bt]`` — no layer is ever
+   sliced out of the stack and written back.
 
 ``quant_rows``/``dequant_rows`` implement the per-(head, position)
 symmetric int8 cache quantization shared by all layouts.
@@ -423,14 +429,16 @@ def pages_needed(tokens: int, page_size: int):
     return -(-int(tokens) // int(page_size))
 
 
-def gather_pages(pool, bt):
+def gather_pages(pool, bt, layer=None):
     """Dense logical view of one pool through block tables.
 
-    pool: (nP, rows, page[, hd]); bt: (B, P) int32 ->
-    (B, rows, P*page[, hd]). Entries pointing at the null page yield
-    garbage rows — callers mask by ``pos`` validity, exactly as the dense
-    rectangles mask their zero tail."""
-    g = pool[bt]                                  # (B, P, rows, page[, hd])
+    pool: (nP, rows, page[, hd]), or the decode state's stack of such
+    pools (L, nP, ...) read at ``layer`` in the same indexing op (never a
+    sliced-out layer); bt: (B, P) int32 -> (B, rows, P*page[, hd]).
+    Entries pointing at the null page yield garbage rows — callers mask by
+    ``pos`` validity, exactly as the dense rectangles mask their zero
+    tail."""
+    g = pool[bt] if layer is None else pool[layer, bt]   # (B, P, rows, ...)
     m = jnp.moveaxis(g, 2, 1)                     # (B, rows, P, page[, hd])
     b, rows, p, ps = m.shape[:4]
     return m.reshape((b, rows, p * ps) + m.shape[4:])

@@ -185,8 +185,8 @@ def _relay_prefix_state(relay, idxs, q_rep, *, acc_rows, use_v_scale,
 # ---------------------------------------------------------------- MHA ------
 def _chai_mha_decode(xn, p, cfg, state, idxs, chai_ctx, write_mask=None, *,
                      decode_ts=0, relay=None):
-    from repro.models.transformer import _masked_rows, pool_slice, \
-        pool_writeback, tree_index, tree_update
+    from repro.models.transformer import _masked_rows, tree_index, \
+        tree_update
     b, d = xn.shape
     ar = jnp.arange(b)
     hd, h = cfg.head_dim, cfg.n_heads
@@ -224,25 +224,25 @@ def _chai_mha_decode(xn, p, cfg, state, idxs, chai_ctx, write_mask=None, *,
     paged = "cp" in state
     if paged:
         from repro.core.cache import gather_pages
-        from repro.models.transformer import (_paged_write_rows,
-                                              paged_token_coords)
-        mask = functools.partial(_masked_rows, write_mask)
+        from repro.models.transformer import paged_token_coords, paged_write
+        lay = idxs["global"]
+        page = state["cp"].shape[3]
 
     # Clustered K cache update (k rows, not H). The fused kernel reads
     # the raw (possibly int8) buffers directly, so the dequantized /
     # page-gathered dense views are only built on the jnp fallback path.
-    ksc = csc = None
+    # Paged writes go in place into the layer-stacked pools.
+    ksc = None
     if paged:
-        cp = pool_slice(state["cp"], idxs["global"])      # (nP, k, page, hd)
-        page = cp.shape[2]
         pk, row = paged_token_coords(state["bt_kc"], pos, page)
         if int8:
             kq, ks = quant_rows(k_rep)
-            cp = _paged_write_rows(cp, pk, row, kq, mask)
-            csc = pool_slice(state["cp_scale"], idxs["global"])
-            csc = _paged_write_rows(csc, pk, row, ks, mask)
+            state = paged_write(state, "cp", lay, pk, row, kq, write_mask)
+            state = paged_write(state, "cp_scale", lay, pk, row, ks,
+                                write_mask)
         else:
-            cp = _paged_write_rows(cp, pk, row, k_rep, mask)
+            state = paged_write(state, "cp", lay, pk, row, k_rep,
+                                write_mask)
     else:
         kc = tree_index(state["kg_chai"], idxs["global"])   # (B, k, S, hd)
         if int8:
@@ -258,7 +258,7 @@ def _chai_mha_decode(xn, p, cfg, state, idxs, chai_ctx, write_mask=None, *,
                              kc[ar, :, pos, :]))
 
     # V: full per-head (or clustered for the CHAI-QKV ablation).
-    vsc = vsp = None
+    vsc = None
     if share_v:
         if batched:
             v = jnp.einsum("bd,dhe->bhe", xn, p["wv"])
@@ -270,7 +270,8 @@ def _chai_mha_decode(xn, p, cfg, state, idxs, chai_ctx, write_mask=None, *,
             # Clustered V pages live in the same cp pool (scale-less,
             # mirroring the unified vg_chai gather).
             pv, vrow = paged_token_coords(state["bt_vc"], pos, page)
-            cp = _paged_write_rows(cp, pv, vrow, v_new, mask)
+            state = paged_write(state, "cp", lay, pv, vrow, v_new,
+                                write_mask)
         else:
             vc = tree_index(state["vg_chai"], idxs["global"])
             vc = vc.at[ar, :, pos, :].set(
@@ -279,15 +280,16 @@ def _chai_mha_decode(xn, p, cfg, state, idxs, chai_ctx, write_mask=None, *,
     else:
         v_new = jnp.einsum("bd,dhe->bhe", xn, p["wv"])
         if paged:
-            vp = pool_slice(state["kvp"], idxs["global"])
             pv, vrow = paged_token_coords(state["bt_vg"], pos, page)
             if int8:
                 vq, vs = quant_rows(v_new)
-                vp = _paged_write_rows(vp, pv, vrow, vq, mask)
-                vsp = pool_slice(state["kvp_scale"], idxs["global"])
-                vsp = _paged_write_rows(vsp, pv, vrow, vs, mask)
+                state = paged_write(state, "kvp", lay, pv, vrow, vq,
+                                    write_mask)
+                state = paged_write(state, "kvp_scale", lay, pv, vrow, vs,
+                                    write_mask)
             else:
-                vp = _paged_write_rows(vp, pv, vrow, v_new, mask)
+                state = paged_write(state, "kvp", lay, pv, vrow, v_new,
+                                    write_mask)
         else:
             vc = tree_index(state["vg"], idxs["global"])
             if int8:
@@ -309,6 +311,7 @@ def _chai_mha_decode(xn, p, cfg, state, idxs, chai_ctx, write_mask=None, *,
         cap = float(cfg.attn_logit_softcap or 0.0)
         if paged:
             relay_on = relay is not None
+            v_key = "cp" if share_v else "kvp"
             bt_kc = state["bt_kc"]
             bt_v = state["bt_vc"] if share_v else state["bt_vg"]
             pos_k = pos
@@ -320,16 +323,11 @@ def _chai_mha_decode(xn, p, cfg, state, idxs, chai_ctx, write_mask=None, *,
                 bt_kc = _roll_bt(bt_kc, shift)
                 bt_v = _roll_bt(bt_v, shift)
                 pos_k = pos - relay["len"]
-            if share_v:
-                out = kops.paged_chai_decode_attention(
-                    q_rep, cp, bt_kc, cp, bt_v,
-                    gather_idx, pos_k, k_scale_pool=csc, share_values=True,
-                    softcap=cap, emit_state=relay_on)
-            else:
-                out = kops.paged_chai_decode_attention(
-                    q_rep, cp, bt_kc, vp, bt_v,
-                    gather_idx, pos_k, k_scale_pool=csc, v_scale_pool=vsp,
-                    softcap=cap, emit_state=relay_on)
+            out = kops.paged_chai_decode_attention(
+                q_rep, state["cp"], bt_kc, state[v_key], bt_v, gather_idx,
+                pos_k, layer=lay, k_scale_pool=state.get("cp_scale"),
+                v_scale_pool=None if share_v else state.get("kvp_scale"),
+                share_values=share_v, softcap=cap, emit_state=relay_on)
             if relay_on:
                 pref = _relay_prefix_state(
                     relay, idxs, q_rep,
@@ -348,17 +346,17 @@ def _chai_mha_decode(xn, p, cfg, state, idxs, chai_ctx, write_mask=None, *,
         # jnp fallback (softcap configs / reference path): densify and
         # dequantize, then the pre-fusion three-step math.
         if paged:
-            kc_f = gather_pages(cp, state["bt_kc"])
+            kc_f = gather_pages(state["cp"], state["bt_kc"], lay)
             if int8:
-                kc_f = dequant_rows(kc_f, gather_pages(csc,
-                                                       state["bt_kc"]))
+                kc_f = dequant_rows(kc_f, gather_pages(
+                    state["cp_scale"], state["bt_kc"], lay))
             if share_v:
-                vc_f = gather_pages(cp, state["bt_vc"])
+                vc_f = gather_pages(state["cp"], state["bt_vc"], lay)
             else:
-                vc_f = gather_pages(vp, state["bt_vg"])
+                vc_f = gather_pages(state["kvp"], state["bt_vg"], lay)
                 if int8:
                     vc_f = dequant_rows(vc_f, gather_pages(
-                        vsp, state["bt_vg"]))
+                        state["kvp_scale"], state["bt_vg"], lay))
         else:
             kc_f = dequant_rows(kc, ksc) if int8 else kc
             if share_v:
@@ -386,17 +384,7 @@ def _chai_mha_decode(xn, p, cfg, state, idxs, chai_ctx, write_mask=None, *,
                              vc_f.astype(jnp.float32))
 
     state = dict(state)
-    if paged:
-        state["cp"] = pool_writeback(state["cp"], idxs["global"], cp)
-        if int8:
-            state["cp_scale"] = pool_writeback(state["cp_scale"],
-                                               idxs["global"], csc)
-        if not share_v:
-            state["kvp"] = pool_writeback(state["kvp"], idxs["global"], vp)
-            if int8:
-                state["kvp_scale"] = pool_writeback(state["kvp_scale"],
-                                                    idxs["global"], vsp)
-    else:
+    if not paged:       # paged: the writes above went into the stacks
         state["kg_chai"] = tree_update(state["kg_chai"], idxs["global"], kc)
         if int8:
             state["kg_chai_scale"] = tree_update(state["kg_chai_scale"],
@@ -486,22 +474,24 @@ def _chai_gqa_decode(xn, p, cfg, state, idxs, chai_ctx, *, local,
         from repro.models.transformer import (_paged_global_write,
                                               _paged_global_update)
         if fused:
-            state, pool, spool = _paged_global_write(
-                state, idxs, k_new, v_new, pos, write_mask, cfg)
+            state = _paged_global_write(state, idxs, k_new, v_new, pos,
+                                        write_mask, cfg)
             q_flat, h2c_flat = _flat_qrep_h2c()
             from repro.kernels import ops as kops
             cap = float(cfg.attn_logit_softcap or 0.0)
             relay_on = relay is not None
             bt_kg, bt_vg, pos_k = state["bt_kg"], state["bt_vg"], pos
             if relay_on:
-                shift = relay["len"] // pool.shape[2]
+                shift = relay["len"] // state["kvp"].shape[3]
                 bt_kg = _roll_bt(bt_kg, shift)
                 bt_vg = _roll_bt(bt_vg, shift)
                 pos_k = pos - relay["len"]
+            spool = state.get("kvp_scale")
             out = kops.paged_chai_decode_attention(
-                q_flat, pool, bt_kg, pool, bt_vg,
-                h2c_flat, pos_k, k_scale_pool=spool, v_scale_pool=spool,
-                reps_per_group=r, softcap=cap, emit_state=relay_on)
+                q_flat, state["kvp"], bt_kg, state["kvp"], bt_vg,
+                h2c_flat, pos_k, layer=idxs["global"], k_scale_pool=spool,
+                v_scale_pool=spool, reps_per_group=r, softcap=cap,
+                emit_state=relay_on)
             if relay_on:
                 pref = _relay_prefix_state(relay, idxs, q_flat,
                                            acc_rows=h, use_v_scale=True,

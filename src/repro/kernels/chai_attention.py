@@ -33,12 +33,15 @@ Fused dataflow per (batch, S-tile) grid step:
                                      [share_values: acc stays (R, hd) and
                                       the h2c gather moves to finalize]
 
-Paged variants (``paged_chai_fused_decode``): K/V live in page pools
-addressed through scalar-prefetched int32 block tables (one S-tile == one
-page) driving the BlockSpec index maps, so the serving engine's clustered
-pages stream HBM->VMEM straight from the ``PagePool`` layout without
-densification. int8 pools dequantize in-VMEM from the mirror-shaped scale
-pools — the HBM byte saving happens on the stream, where it counts.
+Paged variants (``paged_chai_fused_decode``): K/V live in layer-stacked
+page pools addressed through a scalar-prefetched layer index and int32
+block tables (one S-tile == one page) driving the BlockSpec index maps,
+so the serving engine's clustered pages stream HBM->VMEM straight from
+the ``PagePool`` layout without densification or a per-layer slice. int8
+pools dequantize in-VMEM from the mirror-shaped scale pools — the HBM
+byte saving happens on the stream, where it counts. ``paged_write_rows``
+writes a decode step's token rows into the same stacked pools in place
+(aliased output, one page block per slot).
 """
 from __future__ import annotations
 
@@ -453,8 +456,9 @@ def _fused_arg_router(n_prefetch, has_ks, has_vs, *, n_out=1, **flags):
 
     Kernel signature: [scalar-prefetch refs] q, h2c, k, [ks], v, [vs],
     <n_out output refs>, m, l, acc — the first prefetch ref is always
-    ``pos``; paged adds the two block tables (consumed by the index maps
-    only). ``n_out`` is 1 (finalized output) or 3 (emit_state m/l/acc)."""
+    ``pos``; paged adds the two block tables and the layer (consumed by
+    the index maps only). ``n_out`` is 1 (finalized output) or 3
+    (emit_state m/l/acc)."""
     def kernel(*refs):
         pos_ref = refs[0]
         rest = list(refs[n_prefetch:])
@@ -601,20 +605,24 @@ def chai_fused_decode(q_rep, k_cache, v_cache, h2c, pos, *, k_scale=None,
 
 
 def paged_chai_fused_decode(q_rep, k_pool, bt_k, v_pool, bt_v, h2c, pos, *,
-                            k_scale_pool=None, v_scale_pool=None,
+                            layer=0, k_scale_pool=None, v_scale_pool=None,
                             reps_per_group=1, share_values=False, window=0,
                             softcap=0.0, emit_state=False, interpret=None):
     """One-pass fused clustered decode over block-table page pools.
 
-    q_rep: (B, R, hd); k_pool: (nP, KVk, page, hd) clustered pages (MHA:
-    KVk == k_max) or the dense pool (GQA: KVk == n_kv_heads); v_pool:
-    (nP, KVv, page, hd) — the dense per-head pool, or the clustered pool
-    itself under ``share_values``; bt_k/bt_v: (B, P) int32 block tables
-    (scalar-prefetched: they drive the K/V BlockSpec index maps, so pool
-    pages stream HBM->VMEM exactly like dense tiles); h2c: (B, H) or
-    (H,); pos: (B,). int8 pools pass ``k_scale_pool``/``v_scale_pool``
-    (nP, rows, page) mirrors. Returns (B, H, hd) fp32 — one launch, no
-    (B, R, S) scores, no densified pool gather.
+    q_rep: (B, R, hd); k_pool: (L, nP, KVk, page, hd) clustered pages
+    (MHA: KVk == k_max) or the dense pool (GQA: KVk == n_kv_heads), stacked
+    by layer as the decode state holds them; v_pool: (L, nP, KVv, page,
+    hd) — the dense per-head pool, or the clustered pool itself under
+    ``share_values``; ``layer``: int32 scalar, the layer to read. The
+    layer and the block tables bt_k/bt_v (B, P) int32 are scalar-prefetched
+    and drive the K/V BlockSpec index maps ``(layer, bt[b, s])``, so pool
+    pages stream HBM->VMEM straight out of the stack: no layer is ever
+    sliced out or copied. A single layer's (nP, KVk, page, hd) pools are
+    read as a stack of one. h2c: (B, H) or (H,); pos: (B,). int8 pools
+    pass ``k_scale_pool``/``v_scale_pool`` (L, nP, rows, page) mirrors.
+    Returns (B, H, hd) fp32 — one launch, no (B, R, S) scores, no
+    densified pool gather.
 
     ``emit_state``: return (m (B, R), l (B, R), acc (B, rows_acc, hd))
     unfinalized — the relay suffix pass runs this over the private pages
@@ -623,8 +631,14 @@ def paged_chai_fused_decode(q_rep, k_pool, bt_k, v_pool, bt_v, h2c, pos, *,
         interpret = _interpret_default()
     assert not (share_values and reps_per_group > 1), \
         "clustered V is an MHA-only ablation"
-    kv_k, page = k_pool.shape[1], k_pool.shape[2]
-    kv_v = v_pool.shape[1]
+    if k_pool.ndim == 4:                     # one layer: a stack of one
+        k_pool, v_pool = k_pool[None], v_pool[None]
+        if k_scale_pool is not None:
+            k_scale_pool = k_scale_pool[None]
+        if v_scale_pool is not None:
+            v_scale_pool = v_scale_pool[None]
+    kv_k, page = k_pool.shape[2], k_pool.shape[3]
+    kv_v = v_pool.shape[2]
     b, r_total, hd, h2c, h_total, v_rep, rows_acc = _fused_shapes(
         q_rep, kv_v, h2c, share_values)
     assert kv_k * reps_per_group == r_total, (kv_k, reps_per_group, r_total)
@@ -632,35 +646,35 @@ def paged_chai_fused_decode(q_rep, k_pool, bt_k, v_pool, bt_v, h2c, pos, *,
     assert bt_v.shape == bt_k.shape == (b, n_pages)
     scale = 1.0 / math.sqrt(hd)
 
+    def slot(bb, *_):
+        return (bb, 0, 0)
+
+    def pages(table, block):
+        """Pool BlockSpec: the layer axis squeezed, one page per S-tile."""
+        def index(bb, ss, pos_r, btk_r, btv_r, lay_r):
+            bt_r = btk_r if table == "k" else btv_r
+            return (lay_r[0], bt_r[bb, ss]) + (0,) * len(block)
+        return pl.BlockSpec((pl.squeezed, 1) + block, index)
+
     in_specs = [
-        pl.BlockSpec((1, r_total, hd),
-                     lambda bb, ss, pos_r, btk_r, btv_r: (bb, 0, 0)),
-        pl.BlockSpec((1, h_total, 1),
-                     lambda bb, ss, pos_r, btk_r, btv_r: (bb, 0, 0)),
-        pl.BlockSpec((1, kv_k, page, hd),
-                     lambda bb, ss, pos_r, btk_r, btv_r:
-                     (btk_r[bb, ss], 0, 0, 0)),
+        pl.BlockSpec((1, r_total, hd), slot),
+        pl.BlockSpec((1, h_total, 1), slot),
+        pages("k", (kv_k, page, hd)),
     ]
     inputs = [q_rep, h2c, k_pool]
     if k_scale_pool is not None:
-        in_specs.append(pl.BlockSpec((1, kv_k, page),
-                                     lambda bb, ss, pos_r, btk_r, btv_r:
-                                     (btk_r[bb, ss], 0, 0)))
+        in_specs.append(pages("k", (kv_k, page)))
         inputs.append(k_scale_pool)
-    in_specs.append(pl.BlockSpec((1, kv_v, page, hd),
-                                 lambda bb, ss, pos_r, btk_r, btv_r:
-                                 (btv_r[bb, ss], 0, 0, 0)))
+    in_specs.append(pages("v", (kv_v, page, hd)))
     inputs.append(v_pool)
     if v_scale_pool is not None:
-        in_specs.append(pl.BlockSpec((1, kv_v, page),
-                                     lambda bb, ss, pos_r, btk_r, btv_r:
-                                     (btv_r[bb, ss], 0, 0)))
+        in_specs.append(pages("v", (kv_v, page)))
         inputs.append(v_scale_pool)
 
     out_specs, out_shape = _fused_outputs(b, r_total, h_total, rows_acc,
                                           hd, emit_state)
     kernel = _fused_arg_router(
-        3, k_scale_pool is not None, v_scale_pool is not None,
+        4, k_scale_pool is not None, v_scale_pool is not None,
         n_out=3 if emit_state else 1, scale=scale,
         ts=page, window=window, n_tiles=n_pages,
         reps_per_group=reps_per_group, v_rep=v_rep,
@@ -668,7 +682,7 @@ def paged_chai_fused_decode(q_rep, k_pool, bt_k, v_pool, bt_v, h2c, pos, *,
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(b, n_pages),
             in_specs=in_specs,
             out_specs=out_specs,
@@ -681,8 +695,62 @@ def paged_chai_fused_decode(q_rep, k_pool, bt_k, v_pool, bt_v, h2c, pos, *,
         out_shape=out_shape,
         interpret=interpret,
     )(pos.astype(jnp.int32), bt_k.astype(jnp.int32),
-      bt_v.astype(jnp.int32), *inputs)
+      bt_v.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
+      *inputs)
     return _squeeze_state(out) if emit_state else out
+
+
+# ------------------------------------------------- paged token write -------
+def _write_rows_kernel(layer_ref, page_ref, row_ref, new_ref, pool_ref,
+                       out_ref):
+    """Merge slot b's new token row into its page (iota select over the
+    in-page row axis, axis 1 of the (rows, page[, hd]) block)."""
+    b = pl.program_id(0)
+    blk = pool_ref[...]
+    in_row = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 1) == row_ref[b]
+    new = new_ref[...]
+    new = new[:, None, :] if blk.ndim == 3 else new      # (rows, 1[, hd])
+    out_ref[...] = jnp.where(in_row, new, blk)
+
+
+def paged_write_rows(pool, layer, page_idx, row, new, *, interpret=None):
+    """Write one token row per slot into a stacked page pool, in place.
+
+    pool: (L, nP, rows, page, hd) K/V pages or (L, nP, rows, page) int8
+    scale pages; layer: int32 scalar; page_idx/row: (B,) int32 physical
+    page and in-page row; new: (B, rows, hd) or (B, rows). Grid (B,): slot
+    b's page block ``(layer, page_idx[b])`` streams through VMEM, takes its
+    row and goes back to the same HBM buffer (the pool is aliased to the
+    output), so nothing else of the pool is read, copied or written. Pages
+    must be distinct except the null sink page 0, whose contents are
+    unspecified afterwards: callers send masked-out slots there."""
+    if interpret is None:
+        interpret = _interpret_default()
+    b = page_idx.shape[0]
+    blk = pool.shape[2:]
+    sq = pl.squeezed
+    new = new.astype(pool.dtype)
+    if new.ndim == 2:                       # scale rows ride as columns
+        new = new[..., None]
+
+    def page_map(bb, lay_r, page_r, row_r):
+        return (lay_r[0], page_r[bb]) + (0,) * len(blk)
+
+    return pl.pallas_call(
+        _write_rows_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b,),
+            in_specs=[pl.BlockSpec((sq,) + new.shape[1:],
+                                   lambda bb, *_: (bb, 0, 0)),
+                      pl.BlockSpec((sq, sq) + blk, page_map)],
+            out_specs=pl.BlockSpec((sq, sq) + blk, page_map),
+        ),
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        input_output_aliases={4: 0},
+        interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+      page_idx.astype(jnp.int32), row.astype(jnp.int32), new, pool)
 
 
 # --------------------------------------- relay shared-prefix decode --------

@@ -96,24 +96,37 @@ def paged_decode_attention(q, kv_pool, bt_k, bt_v, pos, *, window=0,
                                     "window", "softcap", "emit_state",
                                     "interpret"))
 def paged_chai_decode_attention(q_rep, k_pool, bt_k, v_pool, bt_v, h2c,
-                                pos, *, k_scale_pool=None,
+                                pos, *, layer=0, k_scale_pool=None,
                                 v_scale_pool=None, reps_per_group=1,
                                 share_values=False, window=0, softcap=0.0,
                                 emit_state=False, interpret=None):
     """The paper's decode op over the serving engine's paged layout — ONE
     fused Pallas launch streaming pages through VMEM (no densifying
-    gather). q_rep: (B, R, hd); k_pool: (nP, KVk, page, hd) clustered
-    pages (MHA: KVk == k_max) or the dense pool (GQA); v_pool:
-    (nP, KVv, page, hd) per-head V pages, or the clustered pool under
-    ``share_values``; bt_k/bt_v: (B, P) int32 block tables; h2c: (B, H)
-    or (H,). int8 pools pass the mirror-shaped scale pools. Returns
-    (B, H, hd) fp32."""
+    gather). q_rep: (B, R, hd); k_pool: (L, nP, KVk, page, hd) clustered
+    pages (MHA: KVk == k_max) or the dense pool (GQA), stacked by layer;
+    v_pool: (L, nP, KVv, page, hd) per-head V pages, or the clustered
+    pool under ``share_values``; ``layer``: int32 scalar (traced), the
+    layer the pages are read from — the stack is never sliced; bt_k/bt_v:
+    (B, P) int32 block tables; h2c: (B, H) or (H,). int8 pools pass the
+    mirror-shaped (L, nP, rows, page) scale pools. Returns (B, H, hd)
+    fp32."""
     return ck.paged_chai_fused_decode(
-        q_rep, k_pool, bt_k, v_pool, bt_v, h2c, pos,
+        q_rep, k_pool, bt_k, v_pool, bt_v, h2c, pos, layer=layer,
         k_scale_pool=k_scale_pool, v_scale_pool=v_scale_pool,
         reps_per_group=reps_per_group, share_values=share_values,
         window=window, softcap=softcap, emit_state=emit_state,
         interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def paged_kv_write_rows(pool, layer, page_idx, row, new, *, interpret=None):
+    """Decode-step token write into a stacked page pool, in place: slot
+    b's rows ``new[b]`` land at ``pool[layer, page_idx[b], :, row[b]]``.
+    pool: (L, nP, rows, page, hd) or (L, nP, rows, page) int8 scales;
+    new: (B, rows, hd) or (B, rows). Pages must be distinct apart from the
+    null sink page 0. Returns the pool (aliased to the input buffer)."""
+    return ck.paged_write_rows(pool, layer, page_idx, row, new,
+                               interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("ts", "softcap", "interpret"))

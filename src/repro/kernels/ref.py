@@ -138,6 +138,19 @@ def paged_chai_decode_ref(q_rep, k_pool, bt_k, v_pool, bt_v, h2c, pos, *,
     return paged_chai_av_ref(a, v_pool, bt_v, h2c)
 
 
+def paged_write_rows_ref(pool, layer, page_idx, row, new, write_mask=None):
+    """Oracle for ``paged_write_rows``: the jnp scatter of each slot's
+    token rows into one layer of a stacked pool (L, nP, rows, page[, hd]);
+    slots outside ``write_mask`` rewrite their old rows."""
+    lp = pool[layer]
+    old = lp[page_idx, :, row]                     # (B, rows[, hd])
+    new = new.astype(pool.dtype)
+    if write_mask is not None:
+        m = write_mask.reshape((-1,) + (1,) * (new.ndim - 1))
+        new = jnp.where(m, new, old)
+    return pool.at[layer].set(lp.at[page_idx, :, row].set(new))
+
+
 # ------------------------------------------------------ fused decode -------
 def chai_fused_decode_ref(q_rep, k_cache, v_cache, h2c, pos, *,
                           k_scale=None, v_scale=None, reps_per_group=0,

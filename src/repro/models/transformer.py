@@ -312,18 +312,6 @@ def decode_state_structs(cfg: ModelConfig, batch: int, max_seq: int):
     return shapes, logical
 
 
-@jax.named_scope("kv_pool_slice")
-def pool_slice(pool, i):
-    """Layer ``i``'s KV pool out of the layer scan's stacked carry."""
-    return tree_index(pool, i)
-
-
-@jax.named_scope("kv_pool_writeback")
-def pool_writeback(pool, i, new):
-    """Write layer ``i``'s KV pool back into the stacked carry."""
-    return tree_update(pool, i, new)
-
-
 def init_decode_state(cfg: ModelConfig, batch: int, max_seq: int):
     shapes, _ = decode_state_structs(cfg, batch, max_seq)
     return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
@@ -760,60 +748,55 @@ def paged_token_coords(bt, pos, page):
     return bt[jnp.arange(b), pos // page], pos % page
 
 
-def _paged_write_rows(pool, page_idx, row, new, old_masker):
-    """Commit one token's rows into pool pages. pool: (nP, rows, page, hd)
-    or (nP, rows, page); page_idx/row: (B,)."""
-    if pool.ndim == 4:
-        old = pool[page_idx, :, row, :]
-        return pool.at[page_idx, :, row, :].set(
-            old_masker(new.astype(pool.dtype), old))
-    old = pool[page_idx, :, row]
-    return pool.at[page_idx, :, row].set(old_masker(new, old))
+def paged_write(state, key, layer, page_idx, row, new, write_mask=None):
+    """Commit one token's rows into layer ``layer`` of the stacked pool
+    ``state[key]`` — (L, nP, rows, page, hd) K/V pages or (L, nP, rows,
+    page) int8 scales — at (page_idx[b], row[b]) for each slot b, in place
+    (``ops.paged_kv_write_rows``: no layer of the pool is sliced out,
+    relaid or written back). Slots outside ``write_mask`` (B,) write into
+    the null sink page 0 instead of their own page. Returns a new state
+    dict."""
+    from repro.kernels import ops as kops
+    if write_mask is not None:
+        page_idx = jnp.where(write_mask, page_idx, 0)
+    return {**state, key: kops.paged_kv_write_rows(state[key], layer,
+                                                   page_idx, row, new)}
 
 
 def _paged_global_write(state, idxs, k, v, pos, write_mask, cfg):
     """Paged-layout global-cache decode write: commit one token's K/V
-    rows into each slot's current page of the shared dense pool. Returns
-    (state, pool, scale_pool-or-None) WITHOUT densifying — the fused
-    decode kernel streams the pool through its block tables directly."""
+    rows into each slot's current page of the shared dense pool ``kvp``
+    (and its int8 scale pool), in place in the layer stack. Returns the
+    new state; the fused decode kernel streams the stacked pool through
+    its block tables directly."""
     from repro.core.cache import quant_rows
-    pool = pool_slice(state["kvp"], idxs["global"])   # (nP, KV, page, hd)
-    page = pool.shape[2]
+    lay = idxs["global"]
+    page = state["kvp"].shape[3]
     pk, row = paged_token_coords(state["bt_kg"], pos, page)
     pv, _ = paged_token_coords(state["bt_vg"], pos, page)
-    mask = functools.partial(_masked_rows, write_mask)
-    state = dict(state)
-    spool = None
     if cfg.kv_cache_dtype == "int8":
-        kq, ks = quant_rows(k)
-        vq, vs = quant_rows(v)
-        pool = _paged_write_rows(pool, pk, row, kq, mask)
-        pool = _paged_write_rows(pool, pv, row, vq, mask)
-        spool = pool_slice(state["kvp_scale"], idxs["global"])
-        spool = _paged_write_rows(spool, pk, row, ks, mask)
-        spool = _paged_write_rows(spool, pv, row, vs, mask)
-        state["kvp_scale"] = pool_writeback(state["kvp_scale"],
-                                            idxs["global"], spool)
-    else:
-        pool = _paged_write_rows(pool, pk, row, k, mask)
-        pool = _paged_write_rows(pool, pv, row, v, mask)
-    state["kvp"] = pool_writeback(state["kvp"], idxs["global"], pool)
-    return state, pool, spool
+        (k, ks), (v, vs) = quant_rows(k), quant_rows(v)
+        state = paged_write(state, "kvp_scale", lay, pk, row, ks, write_mask)
+        state = paged_write(state, "kvp_scale", lay, pv, row, vs, write_mask)
+    state = paged_write(state, "kvp", lay, pk, row, k, write_mask)
+    return paged_write(state, "kvp", lay, pv, row, v, write_mask)
 
 
 def _paged_global_update(state, idxs, k, v, pos, write_mask, cfg):
     """``_paged_global_write`` + dense logical views (B, KV, S, hd)
-    gathered through the block tables — the jnp fallback's interface
-    (the attention math downstream is identical to the dense layout's).
+    gathered through the block tables straight out of the layer stack —
+    the jnp fallback's interface (the attention math downstream is
+    identical to the dense layout's).
     """
     from repro.core.cache import dequant_rows, gather_pages
-    state, pool, spool = _paged_global_write(state, idxs, k, v, pos,
-                                             write_mask, cfg)
-    kc_f = gather_pages(pool, state["bt_kg"])
-    vc_f = gather_pages(pool, state["bt_vg"])
-    if spool is not None:
-        kc_f = dequant_rows(kc_f, gather_pages(spool, state["bt_kg"]))
-        vc_f = dequant_rows(vc_f, gather_pages(spool, state["bt_vg"]))
+    state = _paged_global_write(state, idxs, k, v, pos, write_mask, cfg)
+    lay = idxs["global"]
+    kc_f = gather_pages(state["kvp"], state["bt_kg"], lay)
+    vc_f = gather_pages(state["kvp"], state["bt_vg"], lay)
+    if "kvp_scale" in state:
+        spool = state["kvp_scale"]
+        kc_f = dequant_rows(kc_f, gather_pages(spool, state["bt_kg"], lay))
+        vc_f = dequant_rows(vc_f, gather_pages(spool, state["bt_vg"], lay))
     return state, kc_f, vc_f
 
 

@@ -462,6 +462,99 @@ def test_paged_fused_null_pages_masked(rng):
                                rtol=1e-6, atol=1e-6)
 
 
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("variant", ["bf16", "int8", "emit_state"])
+@pytest.mark.parametrize("mode", ["mha", "mha_share", "gqa"])
+def test_paged_fused_decode_stacked_pool_matches_layer_bitwise(rng, mode,
+                                                               variant,
+                                                               layer):
+    """The decode state holds each pool stacked by layer (L, nP, ...). The
+    fused kernel reads the stack at a scalar-prefetched layer index; it
+    must return, bit for bit, what the call on that layer's own pool
+    returns — first and last layer of a 3-layer stack, through the kernel
+    and through the jitted op with a traced layer."""
+    from repro.core.cache import quant_rows
+    n_layers, b, n_pages, page, hd = 3, 2, 4, 16, 16
+    kv, rpg = (2, 3) if mode == "gqa" else (3, 1)
+    share = mode == "mha_share"
+    r_total = kv * rpg
+    if mode == "gqa":
+        h = kv * 4
+        cluster_of = rng.integers(0, rpg, size=(b, kv, 4))
+        h2c = (np.arange(kv)[None, :, None] * rpg
+               + cluster_of).reshape(b, h)
+        v_rows = kv
+    else:
+        h = 8
+        h2c = rng.integers(0, r_total, size=(b, h))
+        v_rows = r_total if share else h
+    n_pool = 2 * b * n_pages + 1
+    dt = jnp.bfloat16 if variant == "bf16" else jnp.float32
+    k_pool = _mk(rng, (n_layers, n_pool, kv, page, hd), dt)
+    v_pool = (k_pool if share
+              else _mk(rng, (n_layers, n_pool, v_rows, page, hd), dt))
+    bt_k, bt_v = _mk_tables(rng, b, n_pages, n_pool, n=2)
+    q_rep = _mk(rng, (b, r_total, hd), jnp.float32)
+    pos = jnp.asarray([n_pages * page - 1, 37], jnp.int32)
+    kw = dict(reps_per_group=rpg, share_values=share,
+              emit_state=variant == "emit_state")
+    if variant == "int8":
+        k_pool, kw["k_scale_pool"] = quant_rows(k_pool)
+        if share:
+            v_pool = k_pool
+        else:
+            v_pool, kw["v_scale_pool"] = quant_rows(v_pool)
+    args = (q_rep, k_pool, bt_k, v_pool, bt_v, jnp.asarray(h2c, jnp.int32),
+            pos)
+    one = {k: (v[layer] if k.endswith("_pool") else v)
+           for k, v in kw.items()}
+    want = ck.paged_chai_fused_decode(q_rep, k_pool[layer], bt_k,
+                                      v_pool[layer], bt_v, args[5], pos,
+                                      interpret=True, **one)
+    got = ck.paged_chai_fused_decode(*args, layer=layer, interpret=True,
+                                     **kw)
+    got_op = ops.paged_chai_decode_attention(
+        *args, layer=jnp.int32(layer), interpret=True, **kw)
+    for g in (got, got_op):
+        for x, y in zip(jax.tree.leaves(g), jax.tree.leaves(want)):
+            assert (np.asarray(x) == np.asarray(y)).all()
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("kind", ["bf16", "int8", "scale"])
+def test_paged_kv_write_rows_matches_scatter_bitwise(rng, kind, layer):
+    """The decode step's in-place token write (``paged_write`` ->
+    ``ops.paged_kv_write_rows``) against the jnp scatter into the sliced
+    layer it replaces: K/V pages (bf16, int8 codes) and int8 scale pages,
+    with masked-out slots (their own pages untouched) and two slots on
+    the null sink page 0. Every page but the sink equals the scatter's
+    bit for bit, and every other layer is untouched; the sink's contents
+    are unspecified (reads of it are always masked by ``pos``)."""
+    from repro.kernels.ref import paged_write_rows_ref
+    from repro.models.transformer import paged_write
+    n_layers, n_pool, rows, page, hd = 3, 12, 4, 16, 128
+    shape = (n_layers, n_pool, rows, page) + (() if kind == "scale"
+                                              else (hd,))
+    dt = {"bf16": jnp.bfloat16, "int8": jnp.int8}.get(kind, jnp.float32)
+    pool = jnp.asarray(rng.integers(-100, 100, size=shape), dt)
+    # slots: 3 real pages written, 2 masked out on real pages, 2 on the
+    # sink page 0 (one of them masked out)
+    pages = jnp.asarray([5, 0, 9, 2, 0, 7, 11], jnp.int32)
+    rows_ = jnp.asarray([0, 3, 15, 7, 9, 8, 1], jnp.int32)
+    mask = jnp.asarray([True, True, True, False, False, False, True])
+    new = jnp.asarray(rng.integers(-100, 100, size=(7,) + shape[2:3]
+                                   + shape[4:]), dt)
+    got = paged_write({"pool": pool}, "pool", jnp.int32(layer), pages,
+                      rows_, new, mask)["pool"]
+    want = paged_write_rows_ref(pool, layer, pages, rows_, new, mask)
+    got, want, pool = np.asarray(got), np.asarray(want), np.asarray(pool)
+    assert (got[:, 1:] == want[:, 1:]).all()
+    others = [i for i in range(n_layers) if i != layer]
+    assert (got[others] == pool[others]).all()
+    assert (got[layer, [2, 7]] == pool[layer, [2, 7]]).all()   # masked
+    assert not (got[layer, [5, 9, 11]] == pool[layer, [5, 9, 11]]).all()
+
+
 # --------------------------------------------------- tanh logit softcap ----
 @pytest.mark.parametrize("softcap", [5.0, 50.0])
 @pytest.mark.parametrize("mode", ["mha", "mha_share", "gqa"])

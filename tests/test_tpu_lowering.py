@@ -5,12 +5,15 @@ R = 19 representative heads, pages of 16 tokens) for one device of a
 described ``v5e:2x2`` topology and asserts the compiled program holds a
 Mosaic kernel (``tpu_custom_call``). Interpret-mode tests cannot see what
 these catch: BlockSpecs whose last two dims are not (8, 128)-tileable,
-unsupported in-kernel ops, and kernels that outgrow VMEM.
+unsupported in-kernel ops, and kernels that outgrow VMEM. The last test
+compiles the engine's own decode step programs and reads their optimized
+HLO for copies of the KV pools.
 
 The topology is described inside a module fixture (never at import), so
 only the worker that runs this file loads the TPU compiler.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +23,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import chai_attention as ck
 from repro.kernels import flash_attention as fk
 
-B, H, HD, R, PAGE, P, NP = 4, 32, 128, 19, 16, 16, 64
+B, H, HD, R, PAGE, P, NP, L = 4, 32, 128, 19, 16, 16, 64, 6
 BF16, I8, F32, I32 = jnp.bfloat16, jnp.int8, jnp.float32, jnp.int32
 
 
@@ -70,6 +73,44 @@ def test_paged_chai_fused_decode_lowers(one_chip, variant):
     if scaled:
         shapes += [((NP, kv_k, PAGE), F32), ((NP, kv_v, PAGE), F32)]
     _compile(one_chip, fn, *shapes)
+
+
+@pytest.mark.parametrize("int8", [False, True])
+def test_paged_chai_fused_decode_stacked_lowers(one_chip, int8):
+    """The decode state's layer-stacked pools, read at a traced layer."""
+    kv_dt = I8 if int8 else BF16
+
+    def fn(q, kp, vp, bt, h2c, pos, layer, *scales):
+        ks, vs = scales if int8 else (None, None)
+        return ck.paged_chai_fused_decode(
+            q, kp, bt, vp, bt, h2c, pos, layer=layer, k_scale_pool=ks,
+            v_scale_pool=vs, interpret=False)
+
+    shapes = [((B, R, HD), BF16), ((L, NP, R, PAGE, HD), kv_dt),
+              ((L, NP, H, PAGE, HD), kv_dt), ((B, P), I32), ((B, H), I32),
+              ((B,), I32), ((), I32)]
+    if int8:
+        shapes += [((L, NP, R, PAGE), F32), ((L, NP, H, PAGE), F32)]
+    _compile(one_chip, fn, *shapes)
+
+
+@pytest.mark.parametrize("kind", ["bf16", "int8", "scale"])
+def test_paged_kv_write_rows_lowers(one_chip, kind):
+    """The token-row write lowers and aliases the donated pool to its
+    output. A K/V pool is not copied; an f32 scale pool is relaid at entry
+    and exit (the TPU keeps it with the page axis minor)."""
+    dt = {"bf16": BF16, "int8": I8}.get(kind, F32)
+    blk = (H, PAGE) if kind == "scale" else (H, PAGE, HD)
+    args = [jax.ShapeDtypeStruct(sh, d, sharding=one_chip) for sh, d in
+            [((L, NP) + blk, dt), ((), I32), ((B,), I32), ((B,), I32),
+             ((B, H) + blk[2:], dt)]]
+    fn = lambda pool, layer, page, row, new: ck.paged_write_rows(
+        pool, layer, page, row, new, interpret=False)
+    text = jax.jit(fn, donate_argnums=0).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text and "output_to_operand_aliasing" in text
+    pool_shape = re.escape(f"[{','.join(map(str, (L, NP) + blk))}]")
+    assert (re.search(pool_shape + r"\S* copy", text) is None) == (
+        kind != "scale")
 
 
 @pytest.mark.parametrize("int8", [False, True])
@@ -148,3 +189,68 @@ def test_relay_prefix_decode_lowers(one_chip, int8):
     if int8:
         shapes += [((g, R, sp), F32), ((g, H, sp), F32)]
     _compile(one_chip, fn, *shapes)
+
+
+# HLO instructions that may carry a pool's shape without moving it.
+_PLUMBING = {"parameter", "tuple", "get-tuple-element", "bitcast",
+             "custom-call"}
+_INSTR = re.compile(r"^\s*(?:ROOT )?%?(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("program", ["chai_step", "mixed_step"])
+def test_decode_steps_copy_no_whole_pool(one_chip, monkeypatch, program, kv):
+    """The engine's all-STEADY (``make_serve_step(chai=True)``) and mixed
+    decode programs at a small paged MHA CHAI configuration: no instruction
+    of their optimized HLO may have the shape of one layer's pool or of a
+    whole stack of pools (dense ``kvp``, clustered ``cp``), apart from
+    parameters, tuple plumbing, bitcasts and the kernels' aliased outputs:
+    every decode access goes to the stack at (layer, page). A per-layer
+    slice, relayout or write-back of a pool would show here. The pools are
+    large enough (2048 + 1536 pages) that the compiler does not prefetch a
+    whole pool into VMEM. int8 scale pools: the same holds for one layer's
+    shape; their stacks are still relaid at the program's entry and exit
+    (the TPU keeps an f32 (..., rows, 16) array with the page axis minor,
+    and the Pallas kernels take it row-major)."""
+    from repro.configs.base import get_config, reduced
+    from repro.core import cache as chai_cache
+    from repro.core import clustering
+    from repro.launch import steps
+    from repro.models import transformer as tfm
+    monkeypatch.setattr(ck, "_interpret_default", lambda: False)
+    monkeypatch.setattr(fk, "_interpret_default", lambda: False)
+    cfg = reduced(get_config("chai-llama-7b"), n_layers=2, d_model=1024,
+                  n_heads=8, d_ff=256, vocab=256, dtype="bfloat16")
+    cfg = cfg.with_chai(enabled=True, warmup_tokens=3)
+    if kv == "int8":
+        cfg = cfg.replace(kv_cache_dtype="int8")
+    b, max_seq = 2, 128
+    sds = lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=one_chip), tree)
+    state = sds(chai_cache.paged_state_structs(
+        cfg, b, max_seq, page_size=PAGE, dense_pages=2048,
+        chai_pages=1536)[0])
+    args = (sds(tfm.param_structs(cfg)[0]),
+            {"tokens": jax.ShapeDtypeStruct((b,), I32, sharding=one_chip)},
+            state, sds(clustering.ctx_structs(cfg, b)[0]))
+    fn = (steps.make_serve_step(cfg, chai=True, decode_ts=PAGE)
+          if program == "chai_step"
+          else steps.make_mixed_step(cfg, decode_ts=PAGE))
+    text = jax.jit(fn, donate_argnums=2).lower(*args).compile().as_text()
+    forbidden = {}
+    for key in ("kvp", "cp", "kvp_scale", "cp_scale"):
+        if key in state:
+            shape = state[key].shape
+            forbidden[shape[1:]] = key + " layer"
+            if not key.endswith("_scale"):
+                forbidden[shape] = key + " stack"
+    assert len(forbidden) == (4 if kv == "bf16" else 6)
+    moved = []
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        if not m or m.group(3) in _PLUMBING:
+            continue
+        shape = tuple(int(d) for d in m.group(2).split(",") if d)
+        if shape in forbidden:
+            moved.append(f"{forbidden[shape]}: {m.group(1)} {m.group(3)}")
+    assert not moved, moved
