@@ -15,8 +15,9 @@ The attention math itself runs as ONE fused Pallas launch per decode step
 h2c-broadcast AV, streaming dense tiles or block-table pages through VMEM
 with in-kernel int8 dequant — no (B, R, S) score tensor and, on the paged
 layout, no densifying page gather. ``decode_ts`` (the engine passes its
-page size) pins the dense tile size to the paged page size so every KV
-layout performs bit-identical arithmetic (cross-layout greedy parity).
+page size) gives the dense layout the paged kernel's tile for that page
+size, so every KV layout performs bit-identical arithmetic (cross-layout
+greedy parity).
 The pure-jnp math is kept as the fallback for shapes the kernel does not
 cover (attention logit softcap, local ring caches) and as the reference
 path (``USE_FUSED_DECODE = False``). Every int8 layout — dense and paged,
@@ -75,9 +76,10 @@ def chai_decode_attention(xn, p, cfg, state, idxs, chai_ctx, *, local,
 
     ``write_mask`` (B,) bool: cache rows are committed only for masked
     slots (the mixed-phase continuous step runs this path alongside the
-    plain MHA path on one batch). ``decode_ts``: S-tile size for the
-    fused dense kernel (0 = whole sequence; the engine passes its page
-    size so dense and paged layouts tile identically).
+    plain MHA path on one batch). ``decode_ts``: the page size the fused
+    kernel's tiles derive from (``_dense_ts``; 0 = whole sequence). The
+    engine passes its page size, so dense and paged layouts tile
+    identically; the relay prefix pass takes it as its tile.
 
     ``relay`` (shared-prefix relay decode, paged+fused layouts): pytree
     of group-batched arrays — see ``_relay_prefix_state`` for the
@@ -110,8 +112,38 @@ def _fused_ok(cfg):
     return USE_FUSED_DECODE
 
 
-def _dense_ts(decode_ts, s):
-    return decode_ts if decode_ts and s % decode_ts == 0 else s
+def _dense_ts(page, kc, vc, scaled):
+    """Dense-layout S-tile: the paged kernel's tile for pages of ``page``
+    tokens (``decode_tile_pages`` on the same rows, width and dtype), so
+    both layouts tile, and round, identically. The whole sequence when
+    ``page`` is 0 or the tile does not divide it."""
+    from repro.kernels.chai_attention import decode_tile_pages
+    s = kc.shape[2]
+    if not page or s % page:
+        return s
+    ts = page * decode_tile_pages(page, kc.shape[1] + vc.shape[1],
+                                  kc.shape[3], kc.dtype, s // page,
+                                  scaled=scaled)
+    return ts if s % ts == 0 else s
+
+
+def paged_decode_tiling(cfg, state):
+    """(tokens per S-tile, tiles per slot) of the fused paged decode over
+    ``state``'s pools: what ``paged_chai_fused_decode`` derives from the
+    pools the decode passes it (MHA: clustered K pages and per-head V, or
+    clustered V under ``share_values``; GQA: the dense pool for both)."""
+    from repro.kernels.chai_attention import decode_tile_pages
+    if cfg.is_mha:
+        k_pool, bt, scaled = state["cp"], state["bt_kc"], "cp_scale" in state
+        v_pool = k_pool if cfg.chai.share_values else state["kvp"]
+    else:
+        k_pool, bt = state["kvp"], state["bt_kg"]
+        v_pool, scaled = k_pool, "kvp_scale" in state
+    page, n_pages = k_pool.shape[3], bt.shape[1]
+    ppt = decode_tile_pages(page, k_pool.shape[2] + v_pool.shape[2],
+                            k_pool.shape[4], k_pool.dtype, n_pages,
+                            scaled=scaled)
+    return ppt * page, -(-n_pages // ppt)
 
 
 def _layer_ctx(chai_ctx, attn_idx):
@@ -341,7 +373,7 @@ def _chai_mha_decode(xn, p, cfg, state, idxs, chai_ctx, write_mask=None, *,
             out = kops.chai_decode_attention(
                 q_rep, kc, vc, gather_idx, pos, k_scale=ksc, v_scale=vsc,
                 share_values=share_v,
-                ts=_dense_ts(decode_ts, kc.shape[2]), softcap=cap)
+                ts=_dense_ts(decode_ts, kc, vc, int8), softcap=cap)
     else:
         # jnp fallback (softcap configs / reference path): densify and
         # dequantize, then the pre-fusion three-step math.
@@ -552,7 +584,7 @@ def _chai_gqa_decode(xn, p, cfg, state, idxs, chai_ctx, *, local,
             from repro.kernels import ops as kops
             out = kops.chai_decode_attention(
                 q_flat, kc, vc, h2c_flat, pos, k_scale=ksc, v_scale=vsc,
-                reps_per_group=r, ts=_dense_ts(decode_ts, s),
+                reps_per_group=r, ts=_dense_ts(decode_ts, kc, vc, int8),
                 softcap=float(cfg.attn_logit_softcap or 0.0))
             return out.astype(xn.dtype), _commit_dense(state)
         if int8:

@@ -34,14 +34,17 @@ Fused dataflow per (batch, S-tile) grid step:
                                       the h2c gather moves to finalize]
 
 Paged variants (``paged_chai_fused_decode``): K/V live in layer-stacked
-page pools addressed through a scalar-prefetched layer index and int32
-block tables (one S-tile == one page) driving the BlockSpec index maps,
-so the serving engine's clustered pages stream HBM->VMEM straight from
-the ``PagePool`` layout without densification or a per-layer slice. int8
-pools dequantize in-VMEM from the mirror-shaped scale pools — the HBM
-byte saving happens on the stream, where it counts. ``paged_write_rows``
-writes a decode step's token rows into the same stacked pools in place
-(aliased output, one page block per slot).
+page pools in HBM, addressed through a scalar-prefetched layer index and
+int32 block tables. One S-tile is ``decode_tile_pages`` pages (128 tokens
+at bf16 serving shapes), copied page by page into double-buffered VMEM
+while the previous tile computes, so the serving engine's clustered pages
+stream straight from the ``PagePool`` layout without densification or a
+per-layer slice; tiles past a slot's position are neither copied nor
+computed. int8 pools (one page per tile) dequantize in-VMEM from the
+mirror-shaped scale pools — the HBM byte saving happens on the stream,
+where it counts. ``paged_write_rows`` writes a decode step's token rows
+into the same stacked pools in place (aliased output, one page block per
+slot).
 """
 from __future__ import annotations
 
@@ -338,38 +341,36 @@ def paged_chai_av(a, v_pool, bt_v, h2c, *, interpret=None):
 
 
 # ------------------------------------------------- fused one-pass decode ---
-def _fused_tile(pos_ref, q_ref, h2c_ref, k_ref, ks_ref, v_ref, vs_ref,
-                out_refs, m_scr, l_scr, acc_scr, *, scale, ts, window,
-                n_tiles, reps_per_group, v_rep, share_values, softcap=0.0,
-                emit_state=False):
-    """One (batch, S-tile) step of the fused clustered decode.
+def _fused_init(m_scr, l_scr, acc_scr):
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    Shared by the dense and paged variants — the paged caller only differs
-    in how the K/V BlockSpecs locate the tile (block tables vs contiguous
-    cache), so dense and paged produce bit-identical arithmetic for equal
-    tile sizes (the engine's layout-parity guarantee).
+
+def _h2c_onehot(h2c, r_total):
+    """(H, 1) int32 head -> rep-row column to the (H, R) one-hot matrix."""
+    return (h2c == jax.lax.broadcasted_iota(
+        jnp.int32, (h2c.shape[0], r_total), 1)).astype(jnp.float32)
+
+
+def _fused_update(q, h2c, k, ks, v, vs, pos, s, m_scr, l_scr, acc_scr, *,
+                  scale, ts, window, reps_per_group, v_rep, share_values,
+                  softcap=0.0):
+    """One S-tile of the fused clustered decode: fold K/V tile ``s`` into
+    the online-softmax state.
+
+    Shared by the dense and paged kernels, which differ only in how the
+    tile reaches VMEM, so both produce bit-identical arithmetic for equal
+    tile sizes (the engine's layout-parity guarantee). q (R, hd); h2c
+    (H, 1); k (KVk, Ts, hd), v (KVv, Ts, hd) raw cache values; ks/vs
+    (rows, Ts) int8 scales or None.
 
     Scratch: ``m_scr``/``l_scr`` (R, 1) running max / normalizer per rep
     row; ``acc_scr`` (H, hd) per-member-head output accumulators (under
     ``share_values``: (R, hd) per-cluster — the h2c gather then happens at
-    finalize, after normalization).
-
-    ``emit_state``: instead of the finalized (H, hd) output, write the raw
-    online-softmax triple — m (R,), l (R,), acc (rows_acc, hd) — so a
-    caller can merge this pass with another (relay shared-prefix decode)
-    before normalizing. The deferred jnp finalize (h2c gather + divide) is
-    bitwise-identical to the in-kernel one-hot finalize."""
-    b = pl.program_id(0)
-    s = pl.program_id(1)
-
-    @pl.when(s == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    q = q_ref[0].astype(jnp.float32)                     # (R, hd)
-    k = k_ref[0].astype(jnp.float32)                     # (KVk, Ts, hd)
+    finalize, after normalization)."""
+    q = q.astype(jnp.float32)                            # (R, hd)
+    k = k.astype(jnp.float32)                            # (KVk, Ts, hd)
     r_total, hd = q.shape
     kv_k = k.shape[0]
     # Per-group rep scores: rep j reads the K rows of group j // rpg
@@ -377,15 +378,14 @@ def _fused_tile(pos_ref, q_ref, h2c_ref, k_ref, ks_ref, v_ref, vs_ref,
     q3 = q.reshape(kv_k, reps_per_group, hd)
     sc = jax.lax.dot_general(q3, k, (((2,), (2,)), ((0,), (0,))),
                              preferred_element_type=jnp.float32)
-    if ks_ref is not None:   # int8: scores scaled by the per-row K scales
-        sc = sc * ks_ref[0].astype(jnp.float32)[:, None, :]
+    if ks is not None:       # int8: scores scaled by the per-row K scales
+        sc = sc * ks.astype(jnp.float32)[:, None, :]
     sc = sc.reshape(r_total, ts) * scale
     if softcap:
         # tanh logit softcap (gemma2): between QK-scale and the validity
         # mask, matching the jnp oracle's insertion point exactly.
         sc = softcap * jnp.tanh(sc / softcap)
     idx = s * ts + jax.lax.broadcasted_iota(jnp.int32, (1, ts), 1)
-    pos = pos_ref[b]
     valid = idx <= pos
     if window:
         valid &= (pos - idx) < window
@@ -398,14 +398,9 @@ def _fused_tile(pos_ref, q_ref, h2c_ref, k_ref, ks_ref, v_ref, vs_ref,
     p = jnp.exp(sc - m_new)                              # (R, Ts)
     l_new = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
 
-    v = v_ref[0].astype(jnp.float32)                     # (KVv, Ts, hd)
-    if vs_ref is not None:   # int8: dequant V rows before the AV dot
-        v = v * vs_ref[0].astype(jnp.float32)[..., None]
-
-    h2c = h2c_ref[0]                                     # (H, 1) int32
-    h_total = h2c.shape[0]
-    oneh = (h2c == jax.lax.broadcasted_iota(
-        jnp.int32, (h_total, r_total), 1)).astype(jnp.float32)   # (H, R)
+    v = v.astype(jnp.float32)                            # (KVv, Ts, hd)
+    if vs is not None:       # int8: dequant V rows before the AV dot
+        v = v * vs.astype(jnp.float32)[..., None]
 
     if share_values:
         # Clustered V (KVv == R): accumulate per cluster; broadcast to
@@ -417,6 +412,7 @@ def _fused_tile(pos_ref, q_ref, h2c_ref, k_ref, ks_ref, v_ref, vs_ref,
     else:
         # Broadcast the cluster rows to member heads (one-hot matmul: the
         # MXU-friendly spelling of the h2c gather), then per-head AV.
+        oneh = _h2c_onehot(h2c, r_total)                 # (H, R)
         p_full = jnp.dot(oneh, p,
                          preferred_element_type=jnp.float32)     # (H, Ts)
         alpha_full = jnp.dot(oneh, alpha,
@@ -431,48 +427,67 @@ def _fused_tile(pos_ref, q_ref, h2c_ref, k_ref, ks_ref, v_ref, vs_ref,
     m_scr[...] = m_new
     l_scr[...] = l_new
 
+
+def _fused_finalize(h2c, out_refs, m_scr, l_scr, acc_scr, *, share_values,
+                    emit_state):
+    """Write slot b's result: the normalized (H, hd) output, or under
+    ``emit_state`` the raw online-softmax triple — m (R,), l (R,), acc
+    (rows_acc, hd) — so a caller can merge this pass with another (relay
+    shared-prefix decode) before normalizing. The deferred jnp finalize
+    (h2c gather + divide) is bitwise-identical to the in-kernel one-hot
+    finalize."""
+    if emit_state:
+        m_ref, l_ref, acc_ref = out_refs
+        m_ref[0] = m_scr[...]
+        l_ref[0] = l_scr[...]
+        acc_ref[0] = acc_scr[...]
+        return
+    (o_ref,) = out_refs
+    oneh = _h2c_onehot(h2c, m_scr.shape[0])
+    if share_values:
+        out_r = acc_scr[...] / jnp.maximum(l_scr[...], 1e-37)
+        out = jnp.dot(oneh, out_r, preferred_element_type=jnp.float32)
+    else:
+        l_full = jnp.dot(oneh, l_scr[...],
+                         preferred_element_type=jnp.float32)
+        out = acc_scr[...] / jnp.maximum(l_full, 1e-37)
+    o_ref[0] = out.astype(o_ref.dtype)
+
+
+def _split_refs(refs, has_ks, has_vs, n_out):
+    """[q, h2c, k, [ks], v, [vs], <n_out outputs>, *scratch] -> named."""
+    rest = list(refs)
+    q_ref, h2c_ref, k_ref = rest.pop(0), rest.pop(0), rest.pop(0)
+    ks_ref = rest.pop(0) if has_ks else None
+    v_ref = rest.pop(0)
+    vs_ref = rest.pop(0) if has_vs else None
+    return (q_ref, h2c_ref, k_ref, ks_ref, v_ref, vs_ref,
+            tuple(rest[:n_out]), rest[n_out:])
+
+
+def _dense_fused_kernel(pos_ref, *refs, has_ks, has_vs, n_out, n_tiles,
+                        share_values, emit_state, **flags):
+    """Grid (B, S-tiles): BlockSpecs stream slot b's contiguous tile."""
+    q_ref, h2c_ref, k_ref, ks_ref, v_ref, vs_ref, out_refs, scratch = \
+        _split_refs(refs, has_ks, has_vs, n_out)
+    m_scr, l_scr, acc_scr = scratch
+    b = pl.program_id(0)
+    s = pl.program_id(1)
+
+    @pl.when(s == 0)
+    def _init():
+        _fused_init(m_scr, l_scr, acc_scr)
+
+    _fused_update(q_ref[0], h2c_ref[0], k_ref[0],
+                  None if ks_ref is None else ks_ref[0], v_ref[0],
+                  None if vs_ref is None else vs_ref[0], pos_ref[b], s,
+                  m_scr, l_scr, acc_scr, share_values=share_values,
+                  **flags)
+
     @pl.when(s == n_tiles - 1)
     def _fin():
-        if emit_state:
-            m_ref, l_ref, acc_ref = out_refs
-            m_ref[0] = m_scr[...]
-            l_ref[0] = l_scr[...]
-            acc_ref[0] = acc_scr[...]
-        else:
-            (o_ref,) = out_refs
-            if share_values:
-                out_r = acc_scr[...] / jnp.maximum(l_scr[...], 1e-37)
-                out = jnp.dot(oneh, out_r,
-                              preferred_element_type=jnp.float32)  # (H, hd)
-            else:
-                l_full = jnp.dot(oneh, l_scr[...],
-                                 preferred_element_type=jnp.float32)
-                out = acc_scr[...] / jnp.maximum(l_full, 1e-37)
-            o_ref[0] = out.astype(o_ref.dtype)
-
-
-def _fused_arg_router(n_prefetch, has_ks, has_vs, *, n_out=1, **flags):
-    """Positional-ref unpacking for the optional int8 scale inputs.
-
-    Kernel signature: [scalar-prefetch refs] q, h2c, k, [ks], v, [vs],
-    <n_out output refs>, m, l, acc — the first prefetch ref is always
-    ``pos``; paged adds the two block tables and the layer (consumed by
-    the index maps only). ``n_out`` is 1 (finalized output) or 3
-    (emit_state m/l/acc)."""
-    def kernel(*refs):
-        pos_ref = refs[0]
-        rest = list(refs[n_prefetch:])
-        q_ref = rest.pop(0)
-        h2c_ref = rest.pop(0)
-        k_ref = rest.pop(0)
-        ks_ref = rest.pop(0) if has_ks else None
-        v_ref = rest.pop(0)
-        vs_ref = rest.pop(0) if has_vs else None
-        out_refs = tuple(rest[:n_out])
-        m_scr, l_scr, acc_scr = rest[n_out:]
-        _fused_tile(pos_ref, q_ref, h2c_ref, k_ref, ks_ref, v_ref, vs_ref,
-                    out_refs, m_scr, l_scr, acc_scr, **flags)
-    return kernel
+        _fused_finalize(h2c_ref[0], out_refs, m_scr, l_scr, acc_scr,
+                        share_values=share_values, emit_state=emit_state)
 
 
 def _fused_shapes(q_rep, v_rows, h2c, share_values):
@@ -579,12 +594,12 @@ def chai_fused_decode(q_rep, k_cache, v_cache, h2c, pos, *, k_scale=None,
 
     out_specs, out_shape = _fused_outputs(b, r_total, h_total, rows_acc,
                                           hd, emit_state)
-    kernel = _fused_arg_router(
-        1, k_scale is not None, v_scale is not None,
-        n_out=3 if emit_state else 1, scale=scale, ts=ts,
-        window=window, n_tiles=n_tiles, reps_per_group=reps_per_group,
-        v_rep=v_rep, share_values=share_values, softcap=softcap,
-        emit_state=emit_state)
+    kernel = functools.partial(
+        _dense_fused_kernel, has_ks=k_scale is not None,
+        has_vs=v_scale is not None, n_out=3 if emit_state else 1,
+        n_tiles=n_tiles, share_values=share_values, emit_state=emit_state,
+        scale=scale, ts=ts, window=window, reps_per_group=reps_per_group,
+        v_rep=v_rep, softcap=softcap)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -604,6 +619,140 @@ def chai_fused_decode(q_rep, k_cache, v_cache, h2c, pos, *, k_scale=None,
     return _squeeze_state(out) if emit_state else out
 
 
+# Decode S-tile sizing. A tile covers at least this many tokens, so the
+# per-grid-step cost is paid once per DMA of several pages, not per page.
+DECODE_TILE_MIN_TOKENS = 128
+# VMEM for the paged kernel's two K + V tile buffers.
+DECODE_TILE_VMEM_BYTES = 4 << 20
+
+
+def decode_tile_pages(page, rows, hd, dtype, n_pages, *, scaled=False):
+    """Pages per S-tile of the fused decode kernels (``ts = ppt * page``).
+
+    ``rows``: K rows + V rows streamed per token; ``dtype``: the cache's
+    dtype; ``n_pages``: pages per slot. The tile holds at least
+    ``DECODE_TILE_MIN_TOKENS`` tokens, unless the two double-buffered
+    K + V tiles would outgrow ``DECODE_TILE_VMEM_BYTES`` or the slot has
+    fewer pages; it need not divide ``n_pages`` (the paged kernel masks a
+    ragged last tile). int8 caches (``scaled``) walk one page per tile:
+    their f32 scale pages keep the page axis on the lanes, so they stream
+    one page at a time through BlockSpecs, and a 16-row int8 page is not
+    aligned to int8's 32-row sublane tile. The dense layout takes the
+    same size (``ts``), which keeps it bit-identical to the paged one."""
+    if scaled:
+        return 1
+    tile_bytes = 2 * page * rows * hd * jnp.dtype(dtype).itemsize
+    fit = max(1, DECODE_TILE_VMEM_BYTES // tile_bytes)
+    want = -(-DECODE_TILE_MIN_TOKENS // page)
+    return max(1, min(want, fit, n_pages))
+
+
+def _paged_fused_kernel(pos_ref, btk_ref, btv_ref, lay_ref, *refs, has_ks,
+                        has_vs, n_out, n_tiles, ppt, page, n_pages,
+                        share_values, emit_state, **flags):
+    """Grid (B, S-tiles of ``ppt`` pages). The pools stay in HBM; each
+    live tile's pages are copied into one of two VMEM buffers at the
+    scalar-prefetched ``(layer, bt[b, j])``, the next live tile's copies
+    starting before this tile computes (across the slot boundary too).
+
+    A tile is live when its first token index is <= pos[b] (tile 0 always
+    is, so every slot's state is initialized and finalized): a dead tile
+    copies nothing and computes nothing. In a live tile, page j is copied
+    when j * page <= pos[b] and j < n_pages (a ragged last tile); the V
+    rows of the other pages are zeroed, since their softmax weight is 0
+    and only a finite V keeps 0 * V at 0. Their K rows are masked to
+    NEG_INF by the validity test whatever the buffer holds.
+
+    int8 scale pages (tiles of one page) ride BlockSpecs instead: the TPU
+    keeps an f32 (rows, 16) page with its page axis on the lanes, which an
+    in-kernel copy cannot slice. Their index map repeats the last live
+    page on dead tiles, so the pipeline skips those copies."""
+    q_ref, h2c_ref, k_hbm, ks_ref, v_hbm, vs_ref, out_refs, scratch = \
+        _split_refs(refs, has_ks, has_vs, n_out)
+    m_scr, l_scr, acc_scr, k_buf, v_buf, sems, slot_ref = scratch
+    b = pl.program_id(0)
+    t = pl.program_id(1)
+    n_slots = pl.num_programs(0)
+    ts = ppt * page
+    lay = lay_ref[0]
+
+    def tile_live(bb, tt):
+        return (tt == 0) | (tt * ts <= pos_ref[bb])
+
+    def page_copies(bb, tt, slot):
+        """(live, K and V copies, buffer slice) per page of tile
+        (bb, tt) into buffer ``slot``."""
+        out = []
+        for i in range(ppt):
+            j = tt * ppt + i
+            live = j * page <= pos_ref[bb]
+            if n_pages % ppt:
+                live &= j < n_pages
+                j = jnp.minimum(j, n_pages - 1)
+            dst = (slot, slice(None), pl.ds(i * page, page))
+            copies = (
+                pltpu.make_async_copy(k_hbm.at[lay, btk_ref[bb, j]],
+                                      k_buf.at[dst], sems.at[slot]),
+                pltpu.make_async_copy(v_hbm.at[lay, btv_ref[bb, j]],
+                                      v_buf.at[dst], sems.at[slot]))
+            out.append((live, copies, dst))
+        return out
+
+    def start(bb, tt, slot):
+        for live, copies, dst in page_copies(bb, tt, slot):
+            @pl.when(live)
+            def _copy():
+                for c in copies:
+                    c.start()
+
+            @pl.when(jnp.logical_not(live))
+            def _zero():
+                v_buf[dst] = jnp.zeros(v_buf.shape[1:2] + (page,)
+                                       + v_buf.shape[3:], v_buf.dtype)
+
+    def wait(bb, tt, slot):
+        for live, copies, _ in page_copies(bb, tt, slot):
+            @pl.when(live)
+            def _wait():
+                for c in copies:
+                    c.wait()
+
+    @pl.when(t == 0)
+    def _init():
+        _fused_init(m_scr, l_scr, acc_scr)
+
+    @pl.when((b == 0) & (t == 0))
+    def _first():
+        slot_ref[0] = 0
+        start(b, t, 0)
+
+    @pl.when(tile_live(b, t))
+    def _tile():
+        slot = slot_ref[0]
+        # The next live tile: this slot's next one, else the next slot's
+        # first (always live).
+        more = (t + 1 < n_tiles) & ((t + 1) * ts <= pos_ref[b])
+        nb = jnp.where(more, b, b + 1)
+        nt = jnp.where(more, t + 1, 0)
+
+        @pl.when(nb < n_slots)
+        def _prefetch():
+            start(nb, nt, 1 - slot)
+            slot_ref[0] = 1 - slot
+
+        wait(b, t, slot)
+        _fused_update(q_ref[0], h2c_ref[0], k_buf[slot],
+                      ks_ref[...] if has_ks else None, v_buf[slot],
+                      vs_ref[...] if has_vs else None, pos_ref[b], t,
+                      m_scr, l_scr, acc_scr, ts=ts,
+                      share_values=share_values, **flags)
+
+    @pl.when(t == n_tiles - 1)
+    def _fin():
+        _fused_finalize(h2c_ref[0], out_refs, m_scr, l_scr, acc_scr,
+                        share_values=share_values, emit_state=emit_state)
+
+
 def paged_chai_fused_decode(q_rep, k_pool, bt_k, v_pool, bt_v, h2c, pos, *,
                             layer=0, k_scale_pool=None, v_scale_pool=None,
                             reps_per_group=1, share_values=False, window=0,
@@ -615,14 +764,15 @@ def paged_chai_fused_decode(q_rep, k_pool, bt_k, v_pool, bt_v, h2c, pos, *,
     by layer as the decode state holds them; v_pool: (L, nP, KVv, page,
     hd) — the dense per-head pool, or the clustered pool itself under
     ``share_values``; ``layer``: int32 scalar, the layer to read. The
-    layer and the block tables bt_k/bt_v (B, P) int32 are scalar-prefetched
-    and drive the K/V BlockSpec index maps ``(layer, bt[b, s])``, so pool
-    pages stream HBM->VMEM straight out of the stack: no layer is ever
-    sliced out or copied. A single layer's (nP, KVk, page, hd) pools are
-    read as a stack of one. h2c: (B, H) or (H,); pos: (B,). int8 pools
-    pass ``k_scale_pool``/``v_scale_pool`` (L, nP, rows, page) mirrors.
-    Returns (B, H, hd) fp32 — one launch, no (B, R, S) scores, no
-    densified pool gather.
+    pools stay in HBM: the layer and the block tables bt_k/bt_v (B, P)
+    int32 are scalar-prefetched, and each S-tile of ``decode_tile_pages``
+    pages is copied into VMEM from ``(layer, bt[b, j])`` — no layer is
+    ever sliced out or copied whole. Only a slot's live pages (up to
+    ``pos``) are copied and computed. A single layer's (nP, KVk, page, hd)
+    pools are read as a stack of one. h2c: (B, H) or (H,); pos: (B,).
+    int8 pools pass ``k_scale_pool``/``v_scale_pool`` (L, nP, rows, page)
+    mirrors, and walk one page per tile. Returns (B, H, hd) fp32 — one
+    launch, no (B, R, S) scores, no densified pool gather.
 
     ``emit_state``: return (m (B, R), l (B, R), acc (B, rows_acc, hd))
     unfinalized — the relay suffix pass runs this over the private pages
@@ -644,53 +794,60 @@ def paged_chai_fused_decode(q_rep, k_pool, bt_k, v_pool, bt_v, h2c, pos, *,
     assert kv_k * reps_per_group == r_total, (kv_k, reps_per_group, r_total)
     n_pages = bt_k.shape[1]
     assert bt_v.shape == bt_k.shape == (b, n_pages)
-    scale = 1.0 / math.sqrt(hd)
+    has_ks, has_vs = k_scale_pool is not None, v_scale_pool is not None
+    ppt = decode_tile_pages(page, kv_k + kv_v, hd, k_pool.dtype, n_pages,
+                            scaled=has_ks or has_vs)
+    ts = ppt * page
+    n_tiles = -(-n_pages // ppt)
 
     def slot(bb, *_):
         return (bb, 0, 0)
 
-    def pages(table, block):
-        """Pool BlockSpec: the layer axis squeezed, one page per S-tile."""
-        def index(bb, ss, pos_r, btk_r, btv_r, lay_r):
+    def scale_pages(table, rows):
+        """One-page tiles of an int8 scale pool; a dead tile repeats the
+        slot's last live page (no copy)."""
+        def index(bb, tt, pos_r, btk_r, btv_r, lay_r):
             bt_r = btk_r if table == "k" else btv_r
-            return (lay_r[0], bt_r[bb, ss]) + (0,) * len(block)
-        return pl.BlockSpec((pl.squeezed, 1) + block, index)
+            j = jnp.clip(tt, 0, jnp.maximum(pos_r[bb], 0) // page)
+            return (lay_r[0], bt_r[bb, j], 0, 0)
+        return pl.BlockSpec((pl.squeezed, pl.squeezed, rows, page), index)
 
-    in_specs = [
-        pl.BlockSpec((1, r_total, hd), slot),
-        pl.BlockSpec((1, h_total, 1), slot),
-        pages("k", (kv_k, page, hd)),
-    ]
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [pl.BlockSpec((1, r_total, hd), slot),
+                pl.BlockSpec((1, h_total, 1), slot), hbm]
     inputs = [q_rep, h2c, k_pool]
-    if k_scale_pool is not None:
-        in_specs.append(pages("k", (kv_k, page)))
+    if has_ks:
+        in_specs.append(scale_pages("k", kv_k))
         inputs.append(k_scale_pool)
-    in_specs.append(pages("v", (kv_v, page, hd)))
+    in_specs.append(hbm)
     inputs.append(v_pool)
-    if v_scale_pool is not None:
-        in_specs.append(pages("v", (kv_v, page)))
+    if has_vs:
+        in_specs.append(scale_pages("v", kv_v))
         inputs.append(v_scale_pool)
-
     out_specs, out_shape = _fused_outputs(b, r_total, h_total, rows_acc,
                                           hd, emit_state)
-    kernel = _fused_arg_router(
-        4, k_scale_pool is not None, v_scale_pool is not None,
-        n_out=3 if emit_state else 1, scale=scale,
-        ts=page, window=window, n_tiles=n_pages,
-        reps_per_group=reps_per_group, v_rep=v_rep,
-        share_values=share_values, softcap=softcap, emit_state=emit_state)
+
+    scratch = [pltpu.VMEM((r_total, 1), jnp.float32),
+               pltpu.VMEM((r_total, 1), jnp.float32),
+               pltpu.VMEM((rows_acc, hd), jnp.float32),
+               pltpu.VMEM((2, kv_k, ts, hd), k_pool.dtype),
+               pltpu.VMEM((2, kv_v, ts, hd), v_pool.dtype),
+               pltpu.SemaphoreType.DMA((2,)),
+               pltpu.SMEM((1,), jnp.int32)]
+    kernel = functools.partial(
+        _paged_fused_kernel, has_ks=has_ks, has_vs=has_vs,
+        n_out=3 if emit_state else 1, n_tiles=n_tiles, ppt=ppt, page=page,
+        n_pages=n_pages, share_values=share_values, emit_state=emit_state,
+        scale=1.0 / math.sqrt(hd), window=window,
+        reps_per_group=reps_per_group, v_rep=v_rep, softcap=softcap)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(b, n_pages),
+            grid=(b, n_tiles),
             in_specs=in_specs,
             out_specs=out_specs,
-            scratch_shapes=[
-                pltpu.VMEM((r_total, 1), jnp.float32),
-                pltpu.VMEM((r_total, 1), jnp.float32),
-                pltpu.VMEM((rows_acc, hd), jnp.float32),
-            ],
+            scratch_shapes=scratch,
         ),
         out_shape=out_shape,
         interpret=interpret,

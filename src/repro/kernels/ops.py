@@ -101,10 +101,11 @@ def paged_chai_decode_attention(q_rep, k_pool, bt_k, v_pool, bt_v, h2c,
                                 share_values=False, window=0, softcap=0.0,
                                 emit_state=False, interpret=None):
     """The paper's decode op over the serving engine's paged layout — ONE
-    fused Pallas launch streaming pages through VMEM (no densifying
-    gather). q_rep: (B, R, hd); k_pool: (L, nP, KVk, page, hd) clustered
-    pages (MHA: KVk == k_max) or the dense pool (GQA), stacked by layer;
-    v_pool: (L, nP, KVv, page, hd) per-head V pages, or the clustered
+    fused Pallas launch streaming tiles of several pages through VMEM (no
+    densifying gather), none past a slot's ``pos``. q_rep: (B, R, hd);
+    k_pool: (L, nP, KVk, page, hd) clustered pages (MHA: KVk == k_max)
+    or the dense pool (GQA), stacked by layer; v_pool: (L, nP, KVv,
+    page, hd) per-head V pages, or the clustered
     pool under ``share_values``; ``layer``: int32 scalar (traced), the
     layer the pages are read from — the stack is never sliced; bt_k/bt_v:
     (B, P) int32 block tables; h2c: (B, H) or (H,). int8 pools pass the

@@ -88,9 +88,10 @@ def make_serve_prefill(cfg: ModelConfig, batch: int, max_seq: int, *,
 
 def make_serve_step(cfg: ModelConfig, *, chai=False, moe_impl="capacity",
                     unroll=False, decode_ts=0):
-    """``decode_ts``: S-tile size for the fused CHAI decode kernel on
-    dense layouts — the engine passes its page size so the cohort/dense
-    schedulers round exactly like the paged one (token parity)."""
+    """``decode_ts``: the page size the fused CHAI decode kernel's
+    dense-layout tile derives from — the engine passes its page size so
+    the cohort/dense schedulers round exactly like the paged one (token
+    parity)."""
     def serve_step(params, batch_inputs, state, chai_ctx=None):
         kw = {}
         if "embeddings" in batch_inputs:

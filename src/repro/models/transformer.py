@@ -956,10 +956,10 @@ def decode_step(params, cfg: ModelConfig, tokens, state, *, chai_ctx=None,
 
     ``mixed_phase``: with a ``chai_ctx``, route each batch slot through the
     MHA or CHAI attention path according to ``state["phase"]`` (unified
-    per-slot layout — continuous batching). ``decode_ts``: S-tile size for
-    the fused CHAI decode kernel on dense layouts (the engine passes its
-    page size so every KV layout tiles — and therefore rounds —
-    identically).
+    per-slot layout — continuous batching). ``decode_ts``: the page size
+    the fused CHAI decode kernel's dense-layout tile derives from (the
+    engine passes its page size so every KV layout tiles — and therefore
+    rounds — identically).
 
     ``relay`` (shared-prefix relay decode, pytree of group-batched
     arrays built by the engine): STEADY slots grouped by their deepest

@@ -96,6 +96,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core import cache as chai_cache
+from repro.core import chai_attention as chai_mod
 from repro.core import clustering
 from repro.launch import steps as steps_mod
 from repro.serving import exporters as exporters_mod
@@ -473,9 +474,11 @@ class EngineCore(CohortSchedulerMixin):
         self._samp_dirty = True
         # jax.jit wrappers are lazy (no tracing until the first call), so
         # both schedulers' steps are declared here unconditionally.
-        # decode_ts = page_size pins the fused CHAI kernel's dense tile
-        # size to the paged page size, so every layout/scheduler performs
-        # bit-identical attention arithmetic (cross-layout token parity).
+        # decode_ts = page_size: the fused CHAI kernel's dense-layout tile
+        # is the paged kernel's tile for that page size (both come from
+        # kernels.chai_attention.decode_tile_pages), so every layout and
+        # scheduler performs bit-identical attention arithmetic
+        # (cross-layout token parity).
         self._sampler = jax.jit(steps_mod.make_sampler())
         # All-greedy fast path: the full sampler computes its sampling
         # lane (argsort + softmax + PRNG) for every slot and discards it
@@ -2317,7 +2320,6 @@ class EngineCore(CohortSchedulerMixin):
         maps + per-slot scatter coords. Non-grouped slots ride along
         with ``in_group=False`` / ``len=0`` — the merge identity keeps
         them bitwise-identical to the non-relay path."""
-        from repro.core import chai_attention as chai_mod
         from repro.serving.prefix_cache import BlockNode
         if not chai_mod.USE_FUSED_DECODE or self.degraded_decode:
             return None       # jnp fallback attends full tables already
@@ -2474,6 +2476,8 @@ class EngineCore(CohortSchedulerMixin):
             raise EngineFault(
                 f"decode failed on the fused kernel path: {err!r}") from err
         else:
+            if tel.enabled and not self.degraded_decode:
+                self._count_decode_tiles(program, active)
             if self.degraded_decode and self.ecfg.decode_heal_steps > 0:
                 self._maybe_heal()
         if self.faults is not None:
@@ -2558,6 +2562,32 @@ class EngineCore(CohortSchedulerMixin):
             with tel.span("kv.account", step=step_no):
                 self._record_kv_bytes(self._phases)
         return outs
+
+    def _count_decode_tiles(self, program, active):
+        """Registry counters ``decode_attn_tiles_walked`` / ``_live``: the
+        S-tiles of one launch of the fused paged CHAI decode kernel this
+        step (every attention layer walks the same tiles). Walked is the
+        kernel's grid, every slot times the tiles of ``max_seq``; live are
+        the tiles it copies and computes, those up to each slot's
+        position. A decoding slot's position is its request's length less
+        one; any other slot counts its first tile only. Relay steps count
+        the whole sequence, though their suffix pass starts past the
+        shared prefix."""
+        if not (self.paged and self.chai_on) or program == "mha":
+            return
+        ts, n_tiles = chai_mod.paged_decode_tiling(self.cfg,
+                                                   self._dev_state)
+        pos = np.zeros((self.ecfg.batch_slots,), np.int64)
+        for i in active:
+            r = self._slot_req[i]
+            pos[i] = len(r.prompt) + len(r.generated) - 1
+        live = (pos // ts + 1).sum()
+        self.tel.counter("decode_attn_tiles_walked", pos.size * n_tiles,
+                         help="S-tiles in the fused paged decode kernel's "
+                              "grid, per step")
+        self.tel.counter("decode_attn_tiles_live", int(live),
+                         help="S-tiles the fused paged decode kernel "
+                              "copies and computes, per step")
 
     def _decode_program(self, relay, occupied) -> str:
         """The cheapest decode program covering the phase mix of the

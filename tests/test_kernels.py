@@ -365,20 +365,19 @@ def test_chai_fused_decode_cache_dtypes(rng, dtype):
                                **_tol(dtype))
 
 
-@pytest.mark.parametrize("int8", [False, True])
-@pytest.mark.parametrize("mode", ["mha", "mha_share", "gqa"])
-def test_paged_chai_fused_decode_matrix(rng, mode, int8):
-    """Paged fused decode across the same matrix: pools + block tables +
-    scale pools vs the densify-then-reference oracle."""
+def _paged_case(rng, mode, *, b, n_pages, page, hd=16, int8=False):
+    """One paged fused-decode problem: (q_rep, k_pool, bt_k, v_pool, bt_v,
+    h2c) and the kernel kwargs. MHA: clustered K (k_max rows) and per-head
+    V; mha_share: clustered V; GQA: rpg 3 reps over 2 KV groups of 4
+    heads. int8 quantizes the pools and passes the scale pools (clustered
+    V codes stay scale-less)."""
     from repro.core.cache import quant_rows
-    b, n_pages, page, hd = 2, 4, 16, 16
     kv, rpg = (2, 3) if mode == "gqa" else (3, 1)
     share = mode == "mha_share"
     r_total = kv * rpg
     if mode == "gqa":
-        qpk = 4
-        h = kv * qpk
-        cluster_of = rng.integers(0, rpg, size=(b, kv, qpk))
+        h = kv * 4
+        cluster_of = rng.integers(0, rpg, size=(b, kv, 4))
         h2c = (np.arange(kv)[None, :, None] * rpg
                + cluster_of).reshape(b, h)
         v_rows = kv
@@ -386,30 +385,72 @@ def test_paged_chai_fused_decode_matrix(rng, mode, int8):
         h = 8
         h2c = rng.integers(0, r_total, size=(b, h))
         v_rows = r_total if share else h
-    nk = b * n_pages + 1
-    nv = b * n_pages + 1
-    k_pool = _mk(rng, (nk, kv, page, hd), jnp.float32)
-    v_pool = _mk(rng, (nv, v_rows, page, hd), jnp.float32)
-    bt_k = _mk_tables(rng, b, n_pages, nk)
-    bt_v = _mk_tables(rng, b, n_pages, nv)
+    n_pool = 2 * b * n_pages + 1
+    k_pool = _mk(rng, (n_pool, kv, page, hd), jnp.float32)
+    v_pool = _mk(rng, (n_pool, v_rows, page, hd), jnp.float32)
+    bt_k, bt_v = _mk_tables(rng, b, n_pages, n_pool, n=2)
     q_rep = _mk(rng, (b, r_total, hd), jnp.float32)
-    pos = np.asarray(rng.integers(1, n_pages * page, size=b))
-    pos[0] = n_pages * page - 1
     kw = dict(reps_per_group=rpg, share_values=share)
     if int8:
-        kq, ksp = quant_rows(k_pool)
-        k_pool, kw["k_scale_pool"] = kq, ksp
+        k_pool, kw["k_scale_pool"] = quant_rows(k_pool)
         if not share:
-            vq, vsp = quant_rows(v_pool)
-            v_pool, kw["v_scale_pool"] = vq, vsp
-    args = (q_rep, k_pool, bt_k, v_pool, bt_v,
-            jnp.asarray(h2c, jnp.int32), jnp.asarray(pos, jnp.int32))
-    got = ck.paged_chai_fused_decode(*args, interpret=True, **kw)
+            v_pool, kw["v_scale_pool"] = quant_rows(v_pool)
+    return (q_rep, k_pool, bt_k, v_pool, bt_v,
+            jnp.asarray(h2c, jnp.int32)), kw
+
+
+def _paged_ts(k_pool, v_pool, n_pages, int8):
+    """The paged kernel's S-tile for these pools (tokens)."""
+    page = k_pool.shape[-2]
+    return page * ck.decode_tile_pages(
+        page, k_pool.shape[-3] + v_pool.shape[-3], k_pool.shape[-1],
+        k_pool.dtype, n_pages, scaled=int8)
+
+
+@pytest.mark.parametrize("variant", ["f32", "int8", "softcap", "emit_state",
+                                     "relay"])
+@pytest.mark.parametrize("mode", ["mha", "mha_share", "gqa"])
+def test_paged_chai_fused_decode_matrix(rng, mode, variant):
+    """Paged fused decode across the dispatch matrix (pools + block tables
+    + scale pools) vs the densify-then-reference oracle. 20 pages of 8
+    tokens walk as a 16-page tile and a ragged 4-page one (int8: one page
+    per tile), and the four slots sit at pos 0, the last token of the
+    first tile, the first token of the second, and the last of max_seq.
+    emit_state finalizes to the kernel's own output bit for bit; relay
+    runs it over tables rolled past a 3-page prefix with pos shifted
+    down by it (the suffix-only pass)."""
+    from repro.core.chai_attention import _roll_bt
+    b, n_pages, page = 4, 20, 8
+    int8 = variant == "int8"
+    (q, k_pool, bt_k, v_pool, bt_v, h2c), kw = _paged_case(
+        rng, mode, b=b, n_pages=n_pages, page=page, int8=int8)
+    ts = _paged_ts(k_pool, v_pool, n_pages, int8)
+    assert ts == (page if int8 else 16 * page)
+    pos = np.asarray([0, ts - 1, ts, n_pages * page - 1])
+    if variant == "softcap":
+        kw["softcap"] = 30.0
+    if variant == "relay":
+        shift = 3
+        pos[:3] += shift * page
+        bt_k = _roll_bt(bt_k, jnp.full((b,), shift, jnp.int32))
+        bt_v = _roll_bt(bt_v, jnp.full((b,), shift, jnp.int32))
+        pos = pos - shift * page
+    args = (q, k_pool, bt_k, v_pool, bt_v, h2c, jnp.asarray(pos, jnp.int32))
     want = ref.paged_chai_fused_decode_ref(*args, **kw)
+    got = ck.paged_chai_fused_decode(*args, interpret=True, **kw)
+    if variant in ("emit_state", "relay"):
+        state = ck.paged_chai_fused_decode(*args, emit_state=True,
+                                           interpret=True, **kw)
+        fin = ops.finalize_decode_state(state, h2c,
+                                        share_values=kw["share_values"])
+        assert (np.asarray(fin) == np.asarray(got)).all()
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
-    if not int8:   # second oracle: the retired paged three-kernel path
-        pipe = ref.paged_chai_three_kernel_decode(
-            *args, reps_per_group=rpg, share_values=share)
+    if variant == "softcap":   # the flag is live
+        kw.pop("softcap")
+        uncapped = ck.paged_chai_fused_decode(*args, interpret=True, **kw)
+        assert not np.allclose(np.asarray(got), np.asarray(uncapped))
+    if variant == "f32":       # second oracle: the paged three-kernel path
+        pipe = ref.paged_chai_three_kernel_decode(*args, **kw)
         np.testing.assert_allclose(np.asarray(got), np.asarray(pipe),
                                    **TOL)
 
@@ -417,8 +458,11 @@ def test_paged_chai_fused_decode_matrix(rng, mode, int8):
 def test_paged_fused_matches_dense_fused_bitwise(rng):
     """Same logical cache contents, equal tile size: the paged fused
     kernel must reproduce the dense fused kernel BIT-FOR-BIT (this is
-    what pins cross-KV-layout greedy token parity in the engine)."""
-    b, h, r, n_pages, page, hd = 2, 8, 4, 4, 16, 16
+    what pins cross-KV-layout greedy token parity in the engine). 16
+    pages of 16 walk as two 8-page tiles; slot 1 ends in the first, so
+    the paged kernel skips its second tile, which the dense one computes
+    fully masked."""
+    b, h, r, n_pages, page, hd = 2, 8, 4, 16, 16, 16
     s = n_pages * page
     nk = 2 * b * n_pages + 1
     k_pool = _mk(rng, (nk, r, page, hd), jnp.float32)
@@ -435,31 +479,80 @@ def test_paged_fused_matches_dense_fused_bitwise(rng):
     q = _mk(rng, (b, r, hd), jnp.float32)
     h2c = jnp.asarray(rng.integers(0, r, size=(b, h)), jnp.int32)
     pos = jnp.asarray([s - 1, 23], jnp.int32)
+    ts = _paged_ts(k_pool, v_pool, n_pages, False)
+    assert ts == s // 2
     dense = ck.chai_fused_decode(q, jnp.asarray(kc), jnp.asarray(vc), h2c,
-                                 pos, ts=page, interpret=True)
+                                 pos, ts=ts, interpret=True)
     paged = ck.paged_chai_fused_decode(q, k_pool, bt_k, v_pool, bt_v, h2c,
                                        pos, interpret=True)
     assert (np.asarray(dense) == np.asarray(paged)).all()
 
 
-def test_paged_fused_null_pages_masked(rng):
-    """Unallocated block-table entries point at the null sink page 0;
-    its contents must not leak into the fused output."""
-    b, h, r, n_pages, page, hd = 1, 4, 2, 4, 8, 16
-    n_pool = 2 * n_pages + 1
-    k_pool = _mk(rng, (n_pool, r, page, hd), jnp.float32)
-    v_pool = _mk(rng, (n_pool, h, page, hd), jnp.float32)
-    bt = _mk_tables(rng, b, n_pages, n_pool).at[:, 2:].set(0)
-    q = _mk(rng, (b, r, hd), jnp.float32)
-    h2c = jnp.asarray(rng.integers(0, r, size=(b, h)), jnp.int32)
-    pos = jnp.asarray([2 * page - 1], jnp.int32)
-    out1 = ck.paged_chai_fused_decode(q, k_pool, bt, v_pool, bt, h2c, pos,
-                                      interpret=True)
-    out2 = ck.paged_chai_fused_decode(q, k_pool.at[0].set(999.0), bt,
-                                      v_pool.at[0].set(-999.0), bt, h2c,
-                                      pos, interpret=True)
-    np.testing.assert_allclose(np.asarray(out1), np.asarray(out2),
-                               rtol=1e-6, atol=1e-6)
+@pytest.mark.parametrize("int8", [False, True])
+@pytest.mark.parametrize("fill", ["null_page", "nan_pages"])
+def test_paged_fused_null_pages_masked(rng, fill, int8):
+    """Pages past a slot's position are never read. null_page: their
+    block-table entries point at the null sink page 0, filled with +-999;
+    nan_pages: they keep real pages, filled with NaN (int8: NaN scales).
+    Slot 0 ends in the second page of its first 16-page tile (int8: one
+    page per tile); slot 1 two pages into its second. The output equals,
+    bit for bit, the run on clean pools."""
+    b, n_pages, page, mode = 2, 20, 8, "mha"
+    (q, k_pool, bt_k, v_pool, bt_v, h2c), kw = _paged_case(
+        rng, mode, b=b, n_pages=n_pages, page=page, int8=int8)
+    ts = _paged_ts(k_pool, v_pool, n_pages, int8)
+    pos = np.asarray([2 * page - 1, ts + page + 3])
+    past = np.arange(n_pages)[None, :] * page > pos[:, None]
+    clean = ck.paged_chai_fused_decode(
+        q, k_pool, bt_k, v_pool, bt_v, h2c, jnp.asarray(pos, jnp.int32),
+        interpret=True, **kw)
+    if fill == "null_page":
+        bt_k = jnp.where(past, 0, bt_k)
+        bt_v = jnp.where(past, 0, bt_v)
+        k_pages, v_pages = np.asarray([0]), np.asarray([0])
+        bad_k, bad_v = 999.0, -999.0
+    else:
+        k_pages, v_pages = np.asarray(bt_k)[past], np.asarray(bt_v)[past]
+        bad_k = bad_v = np.nan
+    if int8:
+        kw["k_scale_pool"] = kw["k_scale_pool"].at[k_pages].set(bad_k)
+        kw["v_scale_pool"] = kw["v_scale_pool"].at[v_pages].set(bad_v)
+    else:
+        k_pool = k_pool.at[k_pages].set(bad_k)
+        v_pool = v_pool.at[v_pages].set(bad_v)
+    out = ck.paged_chai_fused_decode(
+        q, k_pool, bt_k, v_pool, bt_v, h2c, jnp.asarray(pos, jnp.int32),
+        interpret=True, **kw)
+    assert np.isfinite(np.asarray(out)).all()
+    assert (np.asarray(out) == np.asarray(clean)).all()
+
+
+@pytest.mark.parametrize("page,rows,hd,dtype,n_pages,scaled,want", [
+    (16, 13 + 16, 128, jnp.bfloat16, 256, False, 8),   # DeepSeekMoE cell
+    (16, 8 + 8, 128, jnp.bfloat16, 256, False, 8),     # GQA 48/8 heads
+    (16, 13 + 16, 128, jnp.int8, 256, True, 1),        # int8: one page
+    (8, 3 + 8, 16, jnp.float32, 20, False, 16),        # ragged 16 + 4
+    (16, 13 + 16, 128, jnp.bfloat16, 4, False, 4),     # short max_seq
+    (16, 32 + 32, 128, jnp.float32, 256, False, 4),    # VMEM-bound
+    (256, 64 + 64, 256, jnp.float32, 64, False, 1),    # one page over it
+])
+def test_decode_tile_pages(page, rows, hd, dtype, n_pages, scaled, want):
+    """The tile function: at least DECODE_TILE_MIN_TOKENS tokens unless
+    the double-buffered K + V tiles would outgrow the VMEM budget or the
+    slot is shorter; never more pages than a slot has; int8 walks one
+    page. A tile that does not divide n_pages leaves a ragged last tile,
+    which the kernel masks."""
+    ppt = ck.decode_tile_pages(page, rows, hd, dtype, n_pages,
+                               scaled=scaled)
+    assert ppt == want
+    assert 1 <= ppt <= n_pages
+    tile_bytes = 2 * ppt * page * rows * hd * jnp.dtype(dtype).itemsize
+    assert ppt == 1 or tile_bytes <= ck.DECODE_TILE_VMEM_BYTES
+    if not scaled and ppt < n_pages and 2 * tile_bytes <= \
+            ck.DECODE_TILE_VMEM_BYTES:
+        assert ppt * page >= ck.DECODE_TILE_MIN_TOKENS
+    n_tiles = -(-n_pages // ppt)
+    assert (n_tiles - 1) * ppt < n_pages <= n_tiles * ppt
 
 
 @pytest.mark.parametrize("layer", [0, 2])
@@ -573,39 +666,6 @@ def test_chai_fused_decode_softcap_matches_oracle(rng, mode, softcap):
     # the flag is live: capping must actually change the output
     uncapped = ck.chai_fused_decode(*args, ts=32, interpret=True, **kw)
     assert not np.allclose(np.asarray(got), np.asarray(uncapped))
-
-
-@pytest.mark.parametrize("mode", ["mha", "gqa"])
-def test_paged_chai_fused_decode_softcap_matches_oracle(rng, mode):
-    b, n_pages, page, hd, cap = 2, 4, 16, 16, 30.0
-    kv, rpg = (2, 3) if mode == "gqa" else (3, 1)
-    r_total = kv * rpg
-    if mode == "gqa":
-        qpk = 4
-        h = kv * qpk
-        cluster_of = rng.integers(0, rpg, size=(b, kv, qpk))
-        h2c = (np.arange(kv)[None, :, None] * rpg
-               + cluster_of).reshape(b, h)
-        v_rows = kv
-    else:
-        h = 8
-        h2c = rng.integers(0, r_total, size=(b, h))
-        v_rows = h
-    n_pool = b * n_pages + 1
-    k_pool = _mk(rng, (n_pool, kv, page, hd), jnp.float32)
-    v_pool = _mk(rng, (n_pool, v_rows, page, hd), jnp.float32)
-    bt_k = _mk_tables(rng, b, n_pages, n_pool)
-    bt_v = _mk_tables(rng, b, n_pages, n_pool)
-    q_rep = _mk(rng, (b, r_total, hd), jnp.float32)
-    pos = np.asarray(rng.integers(1, n_pages * page, size=b))
-    pos[0] = n_pages * page - 1
-    args = (q_rep, k_pool, bt_k, v_pool, bt_v,
-            jnp.asarray(h2c, jnp.int32), jnp.asarray(pos, jnp.int32))
-    got = ck.paged_chai_fused_decode(*args, reps_per_group=rpg,
-                                     softcap=cap, interpret=True)
-    want = ref.paged_chai_fused_decode_ref(*args, reps_per_group=rpg,
-                                           softcap=cap)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
 
 
 @pytest.mark.parametrize("offset", [0, 8])

@@ -597,3 +597,48 @@ def test_async_metrics_and_timeline_accessors():
             assert await llm_off.metrics_text() is None
 
     asyncio.run(main())
+
+
+def test_decode_tile_counters_count_live_tiles():
+    """``decode_attn_tiles_walked`` / ``_live`` count one launch of the
+    fused paged decode kernel per step that runs it: the grid (slots x
+    tiles of max_seq) and the tiles up to each decoding slot's position,
+    read here from the device state just before each dispatch. A
+    126-token prompt crosses the 128-token tile boundary while it
+    decodes; the short one stays in the first tile, so tiles are
+    skipped."""
+    from repro.core import cache as chai_cache
+    from repro.core.chai_attention import paged_decode_tiling
+    cfg, params = _model()
+    b = 2
+    core = EngineCore(cfg, params, _ecfg(batch_slots=b, max_seq=256))
+    prompts = _prompts(b, seed=5)
+    prompts[0] = (prompts[0] * 20)[:126]
+    for p in prompts:
+        core.add_request(p, GREEDY)
+    expect = {"walked": 0, "live": 0, "steps": 0}
+    dispatch = core._dispatch_decode
+
+    def spy(program, inputs, relay):
+        if program != "mha":
+            ts, n_tiles = paged_decode_tiling(cfg, core._dev_state)
+            pos = np.asarray(core._dev_state["pos"])
+            decoding = [core._slot_req[i] is not None
+                        and core._phases[i] != chai_cache.PHASE_PREFILL
+                        for i in range(b)]
+            expect["steps"] += 1
+            expect["walked"] += b * n_tiles
+            expect["live"] += sum(int(pos[i]) // ts + 1 if decoding[i]
+                                  else 1 for i in range(b))
+        return dispatch(program, inputs, relay)
+
+    core._dispatch_decode = spy
+    _drain(core)
+    assert paged_decode_tiling(cfg, core._dev_state) == (128, 2)
+    snap = core.metrics()
+    walked = _counter_value(snap, "decode_attn_tiles_walked")
+    live = _counter_value(snap, "decode_attn_tiles_live")
+    assert expect["steps"] > 0
+    assert walked == expect["walked"] == 4 * expect["steps"]
+    assert live == expect["live"]
+    assert expect["steps"] * 2 < live < walked

@@ -94,6 +94,34 @@ def test_paged_chai_fused_decode_stacked_lowers(one_chip, int8):
     _compile(one_chip, fn, *shapes)
 
 
+@pytest.mark.parametrize("cell", ["dsmoe16b", "nemotron15b"])
+def test_paged_chai_fused_decode_cell_shapes_lower(one_chip, cell):
+    """The multi-page kernel at a benchmark configuration's own shapes,
+    the layer stacks read at a traced layer. DeepSeekMoE
+    (``dsmoe16b.decode-long``): 24 slots of 256 pages of 16, 13 clustered
+    K rows and 16 V heads of 128, 4800 clustered and 6000 dense pages in a
+    6-layer stack. Nemotron-4 15B on the GQA path: 32 slots of 288
+    pages, 48 query heads over 8 KV heads, 5 representatives per group,
+    10000 pages in an 8-layer stack."""
+    if cell == "dsmoe16b":
+        b, p, n_layers, h, kv_k, kv_v, rpg, nk, nv = (24, 256, 6, 16, 13,
+                                                     16, 1, 4800, 6000)
+    else:
+        b, p, n_layers, h, kv_k, kv_v, rpg, nk, nv = (32, 288, 8, 48, 8, 8,
+                                                     5, 10000, 10000)
+    assert ck.decode_tile_pages(PAGE, kv_k + kv_v, HD, BF16, p) == 8
+
+    def fn(q, kp, vp, bt_k, bt_v, h2c, pos, layer):
+        return ck.paged_chai_fused_decode(
+            q, kp, bt_k, vp, bt_v, h2c, pos, layer=layer,
+            reps_per_group=rpg, interpret=False)
+
+    _compile(one_chip, fn, ((b, kv_k * rpg, HD), BF16),
+             ((n_layers, nk, kv_k, PAGE, HD), BF16),
+             ((n_layers, nv, kv_v, PAGE, HD), BF16), ((b, p), I32),
+             ((b, p), I32), ((b, h), I32), ((b,), I32), ((), I32))
+
+
 @pytest.mark.parametrize("kind", ["bf16", "int8", "scale"])
 def test_paged_kv_write_rows_lowers(one_chip, kind):
     """The token-row write lowers and aliases the donated pool to its
