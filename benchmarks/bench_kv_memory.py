@@ -5,12 +5,12 @@ Three lanes:
      (the paper's model) and every assigned MHA-regime arch. The paper's
      21.4% saving comes from dropping non-representative K rows; V is
      kept (Table 4).
-  2. **Paged allocator** — the continuous-batching engine with
-     ``kv_layout="paged"`` on a tiny MHA model: resident (allocated-page)
-     bytes sampled across PREFILL -> WARMUP -> CLUSTER -> STEADY. The
-     claim check asserts the saving is *realized by the allocator*:
-     steady-state paged-CHAI bytes fall below the dense-MHA rectangle
-     the dense layouts keep resident (the unified layout exceeds it).
+  2. **Paged allocator** — the continuous-batching engine on a tiny MHA
+     model: resident (allocated-page) bytes sampled across PREFILL ->
+     WARMUP -> CLUSTER -> STEADY. The claim check asserts the saving is
+     *realized by the allocator*: steady-state paged-CHAI bytes fall
+     below the dense-MHA rectangle (every slot's K and V for all of
+     ``max_seq``).
   3. **Tier transitions** — a prefix-family workload past device
      capacity on three engines: A (pressured + host offload), B
      (pressured, HBM-only), C (unpressured reference). Claims: (a)
@@ -29,7 +29,7 @@ import jax
 
 from benchmarks.common import save_result
 from repro.configs.base import get_config, list_configs, reduced
-from repro.core.cache import kv_cache_bytes, unified_kv_bytes
+from repro.core.cache import kv_cache_bytes
 from repro.models import transformer as tfm
 from repro.serving import invariants
 from repro.serving.engine import EngineConfig, EngineCore, ServingEngine
@@ -44,7 +44,6 @@ def _paged_allocator_lane(slots=2, max_seq=64, page_size=16, n_req=4):
     params = tfm.init_params(cfg, jax.random.PRNGKey(0))
     eng = ServingEngine(cfg, params,
                         EngineConfig(batch_slots=slots, max_seq=max_seq,
-                                     kv_layout="paged",
                                      page_size=page_size))
     rng = np.random.default_rng(0)
     for i in range(n_req):
@@ -52,8 +51,7 @@ def _paged_allocator_lane(slots=2, max_seq=64, page_size=16, n_req=4):
                    max_new_tokens=24, uid=i)
     eng.run()
     hist = eng.kv_bytes_history
-    dense_mha = unified_kv_bytes(cfg, slots, max_seq, chai=False)
-    dense_unified = unified_kv_bytes(cfg, slots, max_seq, chai=True)
+    dense_mha = kv_cache_bytes(cfg, slots, max_seq, chai=False)
     # steady state = every occupied slot past CLUSTER (no warmup slot is
     # holding dense K pages); churn steps with a fresh WARMUP admission
     # are transient and excluded. No steady sample means the workload
@@ -78,7 +76,6 @@ def _paged_allocator_lane(slots=2, max_seq=64, page_size=16, n_req=4):
         "peak_bytes": eng.kv_bytes_peak(),
         "steady_chai_bytes": steady_bytes,
         "dense_mha_bytes": dense_mha,
-        "dense_unified_bytes": dense_unified,
         "paged_steady_saving_vs_dense_mha":
             1 - steady_bytes / dense_mha,
     }
@@ -194,12 +191,9 @@ def run():
             "saving_in_paper_range": 0.10 <= llama["saving_frac"] <= 0.30,
             "kv_close_to_1.2GB": 0.8 <= llama["mha_bytes"] / 2**30 <= 1.6,
             # the tentpole: the allocator (not just the formula) realizes
-            # the saving — steady paged-CHAI below the dense-MHA
-            # rectangle, which the unified layout exceeds
+            # the saving — steady paged-CHAI below the dense-MHA rectangle
             "paged_steady_below_dense_mha":
                 paged["steady_chai_bytes"] < paged["dense_mha_bytes"],
-            "unified_layout_exceeds_dense_mha":
-                paged["dense_unified_bytes"] > paged["dense_mha_bytes"],
             "compaction_frees_pages":
                 paged["steady_chai_bytes"] < paged["peak_bytes"],
             # the tier ladder: bitwise promotion, reuse uplift vs an

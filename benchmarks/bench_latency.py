@@ -6,10 +6,10 @@ Five measurements:
   2. **Analytic TPU v5e model** for the full LLaMA-7B config: decode
      attention is HBM-bandwidth-bound, so TTNT speedup ≈ KV-bytes-read
      ratio; prefill is compute-bound, so TTFT speedup ≈ score-FLOP ratio.
-  3. **Scheduler comparison**: the same mixed-length (8–128 new tokens)
-     Poisson-arrival workload through the continuous and cohort
-     schedulers — per-request TTFT and request throughput (continuous
-     must sustain strictly higher throughput: no head-of-line blocking).
+  3. **Poisson lane**: a mixed-length (8–128 new tokens) Poisson-arrival
+     workload through the continuous-batching engine — per-request TTFT,
+     request throughput, decode steps, and the paged pools' peak
+     allocation against their reservation.
   4. **Fused kernel lane**: one decode-attention step through the fused
      one-launch kernel vs the retired three-kernel pipeline — kernel
      launches per step (counted by intercepting ``pallas_call``),
@@ -74,78 +74,47 @@ def _engine_times(cfg, params, pipe, use_chai, n_req=4, max_new=12):
     return {"wall_s": wall, "ttft_s": ttft, "per_token_s": per_tok}
 
 
-def _scheduler_compare(cfg, params, pipe, *, n_req=18, slots=6,
-                       prompt_len=16, new_tokens=(8, 128), mean_gap_s=0.01,
-                       seed=0):
-    """Same Poisson workload (exponential inter-arrival gaps, mixed
-    8-128 new tokens) through both schedulers.
+def _poisson_lane(cfg, params, pipe, *, n_req=18, slots=6, prompt_len=16,
+                  new_tokens=(8, 128), mean_gap_s=0.01, seed=0):
+    """A Poisson workload (exponential inter-arrival gaps, mixed 8-128
+    new tokens) through the engine.
 
     Output lengths are long-tailed (most requests short, a minority
-    near the cap — the regime continuous batching exists for: under the
-    cohort scheduler every short request in a cohort waits for its
-    longest member)."""
+    near the cap — the regime continuous batching exists for). On a CPU
+    the decode step runs the fused kernel in interpret mode, so the
+    wall-clock numbers are advisory."""
     rng = np.random.default_rng(seed)
     arrivals, lens = poisson_workload(rng, n_req, mean_gap_s=mean_gap_s,
                                       new_tokens=new_tokens)
     prompts = [pipe.batch(3000 + i)["tokens"][0, :prompt_len]
                for i in range(n_req)]
-    out = {}
-    lanes = {
-        # paged is the engine default: page-budget admission, dense pages
-        # freed at compaction
-        "continuous": dict(scheduler="continuous", kv_layout="paged"),
-        "continuous_dense": dict(scheduler="continuous",
-                                 kv_layout="dense"),
-        "cohort": dict(scheduler="cohort"),
+    eng = ServingEngine(cfg, params,
+                        EngineConfig(batch_slots=slots, max_seq=192))
+    # Two identical passes; the first warms every jit (prefill per prompt
+    # length, all phase-mix step variants) so the measured pass reflects
+    # steady-state serving, not compile time.
+    for timed in (False, True):
+        t0 = time.time()
+        batch = [eng.submit(prompts[i], max_new_tokens=int(lens[i]),
+                            uid=i, arrival_delay=float(arrivals[i]))
+                 for i in range(n_req)]
+        steps0 = eng.steps_executed
+        eng.run()
+        wall = time.time() - t0
+    ttfts = np.array([r.ttft for r in batch])
+    span = max(r.t_done for r in batch) - min(r.t_arrival for r in batch)
+    return {
+        "wall_s": wall,
+        "req_per_s": n_req / span,
+        "ttft_s_mean": float(ttfts.mean()),
+        "ttft_s_p95": float(np.percentile(ttfts, 95)),
+        "decode_steps": eng.steps_executed - steps0,
+        "kv_bytes_peak": int(eng.kv_bytes_peak()),
+        "kv_bytes_capacity": int(eng.kv_bytes_capacity()),
+        "workload": {"n_req": n_req, "slots": slots,
+                     "new_tokens": list(map(int, lens)),
+                     "arrival_span_s": float(arrivals[-1])},
     }
-    for lane, kw in lanes.items():
-        eng = ServingEngine(cfg, params,
-                            EngineConfig(batch_slots=slots, max_seq=192,
-                                         **kw))
-        # Two identical passes; the first warms every jit (prefill per
-        # prompt length, all phase-mix step variants) so the measured
-        # pass reflects steady-state serving, not compile time.
-        for timed in (False, True):
-            t0 = time.time()
-            batch = [eng.submit(prompts[i], max_new_tokens=int(lens[i]),
-                                uid=i, arrival_delay=float(arrivals[i]))
-                     for i in range(n_req)]
-            steps0 = eng.steps_executed
-            eng.run()
-            wall = time.time() - t0
-        ttfts = np.array([r.ttft for r in batch])
-        span = max(r.t_done for r in batch) - min(r.t_arrival for r in batch)
-        out[lane] = {
-            "wall_s": wall,
-            "req_per_s": n_req / span,
-            "ttft_s_mean": float(ttfts.mean()),
-            "ttft_s_p95": float(np.percentile(ttfts, 95)),
-            "decode_steps": eng.steps_executed - steps0,
-        }
-        if eng.paged:
-            out[lane]["kv_bytes_peak"] = int(eng.kv_bytes_peak())
-            out[lane]["kv_bytes_capacity"] = int(eng.kv_bytes_capacity())
-    out["workload"] = {"n_req": n_req, "slots": slots,
-                       "new_tokens": list(map(int, lens)),
-                       "arrival_span_s": float(arrivals[-1])}
-    # Hardware-independent scheduler claims use batched-decode-STEP
-    # counts (the repo's throughput proxy — see
-    # tests/test_engine_continuous.py): on this CPU container the decode
-    # step itself runs the fused kernel in interpret mode (an emulation,
-    # ~3x slower than compiled jnp), so wall clock measures the
-    # interpreter, not the scheduler. Wall-clock req/s stays reported
-    # (and advisory) for trend-watching.
-    out["continuous_strictly_fewer_steps"] = bool(
-        out["continuous"]["decode_steps"] < out["cohort"]["decode_steps"])
-    out["continuous_wall_clock_faster"] = bool(
-        out["continuous"]["req_per_s"] > out["cohort"]["req_per_s"])
-    out["paged_vs_dense_layout_req_per_s_ratio"] = float(
-        out["continuous"]["req_per_s"]
-        / out["continuous_dense"]["req_per_s"])
-    out["paged_vs_dense_layout_steps_ratio"] = float(
-        out["continuous"]["decode_steps"]
-        / max(out["continuous_dense"]["decode_steps"], 1))
-    return out
 
 
 @contextlib.contextmanager
@@ -721,7 +690,7 @@ def run():
                              cluster_counts=(5,) * cfg.n_attn_layers)
     cpu_mha = _engine_times(cfg, params, pipe, use_chai=False)
     cpu_chai = _engine_times(cfg_chai, params, pipe, use_chai=True)
-    sched = _scheduler_compare(cfg_chai, params, pipe)
+    poisson = _poisson_lane(cfg_chai, params, pipe)
     fused = _fused_kernel_lane()
     prefix = _prefix_reuse_lane(cfg_chai, params, pipe)
     prefix["relay"] = _relay_lane(cfg_chai, params, pipe)
@@ -735,7 +704,7 @@ def run():
         "cpu_tiny": {"mha": cpu_mha, "chai": cpu_chai,
                      "per_token_speedup":
                          cpu_mha["per_token_s"] / cpu_chai["per_token_s"]},
-        "scheduler_compare_poisson": sched,
+        "poisson_continuous": poisson,
         "fused_kernel_lane": fused,
         "prefix_reuse": prefix,
         "streaming": streaming,
@@ -750,19 +719,9 @@ def run():
                 ["ttnt_speedup_bound"] > 1.0,
             "ttft_attn_bound_exceeds_1": _analytic_full()["2048"]
                 ["ttft_attention_speedup_bound"] > 1.0,
-            # scheduler claims on the step-count proxy (deterministic;
-            # wall clock on a CPU interpret-mode container is advisory)
-            "continuous_sustains_higher_throughput":
-                sched["continuous_strictly_fewer_steps"],
-            # paged admission keeps the mixed 8-128-token Poisson
-            # workload flowing: the page-budget gate never exceeds the
-            # pool reservation and does not serialize the workload vs
-            # the dense layout (equal step counts when pages suffice)
+            # page-budget admission never exceeds the pool reservation
             "paged_peak_within_capacity":
-                sched["continuous"]["kv_bytes_peak"]
-                <= sched["continuous"]["kv_bytes_capacity"],
-            "paged_admission_throughput_holds":
-                sched["paged_vs_dense_layout_steps_ratio"] <= 1.1,
+                poisson["kv_bytes_peak"] <= poisson["kv_bytes_capacity"],
             # prefix-reuse lane: deterministic allocator claims + the
             # (advisory-in-CI) cold-vs-warm TTFT ordering
             "prefix_warm_ttft_below_cold":

@@ -2,15 +2,11 @@
 
 Trains a small model on the synthetic corpus (so generations are
 meaningful), then serves the SAME Poisson-arrival workload (exponential
-inter-arrival gaps, mixed output lengths) through:
-
-  * the slot-level ``continuous`` scheduler (per-slot CHAI phase machine,
-    slots admitted/retired independently), and
-  * the legacy ``cohort`` scheduler (lockstep phases; head-of-line
-    blocking by the longest request in each cohort),
-
-reporting per-request TTFT and request throughput for each, plus the
-CHAI-vs-MHA comparison (KV bytes, greedy-token agreement).
+inter-arrival gaps, mixed output lengths) through the slot-level
+continuous-batching engine (per-slot CHAI phase machine, slots
+admitted/retired independently) with CHAI on and off, reporting
+per-request TTFT and request throughput for each, plus the CHAI-vs-MHA
+comparison (KV bytes, greedy-token agreement).
 
   PYTHONPATH=src python examples/serve_batched.py
 """
@@ -39,11 +35,9 @@ def make_workload(pipe, *, n_req=12, prompt_len=24, mean_gap_s=0.02,
             for i in range(n_req)]
 
 
-def serve(cfg, params, workload, *, scheduler, use_chai, slots=6,
-          max_seq=192):
+def serve(cfg, params, workload, *, use_chai, slots=6, max_seq=192):
     eng = ServingEngine(cfg, params,
                         EngineConfig(batch_slots=slots, max_seq=max_seq,
-                                     scheduler=scheduler,
                                      use_chai=use_chai))
     # Two identical passes: the first warms every jit so the reported
     # numbers reflect steady-state serving, not XLA compile time.
@@ -65,11 +59,9 @@ def serve(cfg, params, workload, *, scheduler, use_chai, slots=6,
         "tok_per_s": n_tok / wall,
         "ttft_ms_mean": 1e3 * float(ttfts.mean()),
         "ttft_ms_p95": 1e3 * float(np.percentile(ttfts, 95)),
-        # paged engines drain their pools on retire, so the footprint is
-        # the run's high-water allocated-page bytes; dense layouts report
-        # their constant residency
-        "kv_bytes": int(eng.kv_bytes_peak() if eng.paged
-                        else eng.kv_bytes()),
+        # the engine drains its pools on retire, so the footprint is the
+        # run's high-water allocated-page bytes
+        "kv_bytes": int(eng.kv_bytes_peak()),
         "kv_steady": int(eng.kv_bytes(chai=eng.chai_on)),   # analytic
         "decode_steps": eng.steps_executed - steps0,
     }
@@ -90,39 +82,26 @@ def main():
                              cluster_counts=(5,) * cfg.n_attn_layers)
     workload = make_workload(tr.pipe)
 
-    print("\nserving the Poisson workload: continuous scheduler ...")
-    cont = serve(cfg_chai, params, workload, scheduler="continuous",
-                 use_chai=True)
-    print("serving the Poisson workload: cohort scheduler ...")
-    coh = serve(cfg_chai, params, workload, scheduler="cohort",
-                use_chai=True)
-    print("serving the Poisson workload: continuous, CHAI off ...")
-    mha = serve(cfg, params, workload, scheduler="continuous",
-                use_chai=False)
+    print("\nserving the Poisson workload: CHAI on ...")
+    cont = serve(cfg_chai, params, workload, use_chai=True)
+    print("serving the Poisson workload: CHAI off ...")
+    mha = serve(cfg, params, workload, use_chai=False)
 
     keys = ("wall_s", "req_per_s", "tok_per_s", "ttft_ms_mean",
             "ttft_ms_p95", "kv_bytes")
-    print(f"\n{'':14}{'continuous':>12}{'cohort':>12}{'cont-MHA':>12}")
+    print(f"\n{'':14}{'CHAI':>12}{'MHA':>12}")
     for key in keys:
-        print(f"{key:14}{cont[key]:>12.2f}{coh[key]:>12.2f}"
-              f"{mha[key]:>12.2f}")
+        print(f"{key:14}{cont[key]:>12.2f}{mha[key]:>12.2f}")
 
-    agree_sched = np.mean([np.mean(np.asarray(cont["gen"][u]) ==
-                                   np.asarray(coh["gen"][u]))
-                           for u in cont["gen"]])
     agree_chai = np.mean([np.mean(np.asarray(cont["gen"][u]) ==
                                   np.asarray(mha["gen"][u]))
                           for u in cont["gen"]])
-    print(f"\ntoken parity continuous vs cohort:   {agree_sched:.1%}")
-    print(f"greedy-token agreement CHAI vs MHA:  {agree_chai:.1%}")
-    # steady-state analytic saving; the continuous engine's paged layout
-    # realizes it at the allocator level too (kv_bytes row = peak
-    # allocated-page bytes, which drops as dense pages free at
-    # compaction)
+    print(f"\ngreedy-token agreement CHAI vs MHA:  {agree_chai:.1%}")
+    # steady-state analytic saving; the engine's paged pools realize it
+    # at the allocator level too (kv_bytes row = peak allocated-page
+    # bytes, which drops as dense pages free at compaction)
     print(f"KV saving (CHAI vs MHA, steady):     "
-          f"{1 - coh['kv_steady'] / mha['kv_steady']:.1%}")
-    print(f"throughput gain continuous/cohort:   "
-          f"{cont['req_per_s'] / coh['req_per_s']:.2f}x")
+          f"{1 - cont['kv_steady'] / mha['kv_steady']:.1%}")
 
 
 if __name__ == "__main__":

@@ -1,28 +1,27 @@
-"""CHAI KV-cache layouts: cohort (dense -> clustered), unified per-slot,
-and the *paged* layout the continuous-batching engine serves from.
+"""CHAI KV-cache layouts: the cohort flow (dense -> clustered) and the
+*paged* layout the serving engine runs on.
 
-Three layouts, one phase machine (PREFILL -> WARMUP -> CLUSTER -> STEADY):
+Two layouts, one phase machine (PREFILL -> WARMUP -> CLUSTER -> STEADY):
 
 1. **Cohort** (``chai_state_structs`` / ``compact_kv``) — the paper's
-   batch-lockstep flow. ``compact_kv`` is §3.5's "remove the Key tokens
-   associated [with pruned heads]": after membership identification the
-   dense K cache is gathered down to representative rows. Run it as a
-   donated jit so the full cache's buffer is released on device.
+   batch-lockstep flow, a library flow for the dry-run and the examples
+   (``launch/dryrun.py``, ``examples/``). ``compact_kv`` is §3.5's
+   "remove the Key tokens associated [with pruned heads]": after
+   membership identification the dense K cache is gathered down to
+   representative rows. Run it as a donated jit so the full cache's
+   buffer is released on device.
 
-2. **Unified per-slot** (``unified_state_structs``) — the legacy
-   continuous-batching layout (``EngineConfig.kv_layout="dense"``). Dense
-   ``kg``/``vg`` AND clustered ``kg_chai`` rectangles stay resident side
-   by side for the whole ``batch x max_seq`` envelope, with a per-slot
-   ``phase`` vector; ``insert_slot`` / ``compact_kv_slot`` /
-   ``reset_slot`` move one slot through its lifecycle. Honest but
-   wasteful: resident bytes EXCEED plain MHA.
-
-3. **Paged** (``paged_state_structs``, the engine default) — fixed-size
-   pages of ``page_size`` tokens spanning all global layers, drawn from
-   two device pools (``kvp``: dense K/V rows, ``n_kv_heads`` wide;
-   ``cp``: clustered rows, ``k_max`` wide), addressed through per-slot
-   int32 block tables (``bt_kg``/``bt_vg`` -> ``kvp``, ``bt_kc``/
-   ``bt_vc`` -> ``cp``). Page 0 of every pool is a reserved *null sink*:
+2. **Paged** (``paged_state_structs``, the serving engine's layout) —
+   fixed-size pages of ``page_size`` tokens spanning all global layers,
+   drawn from two device pools (``kvp``: dense K/V rows, ``n_kv_heads``
+   wide; ``cp``: clustered rows, ``k_max`` wide), addressed through
+   per-slot int32 block tables (``bt_kg``/``bt_vg`` -> ``kvp``,
+   ``bt_kc``/``bt_vc`` -> ``cp``), beside a per-slot ``phase`` vector and
+   the ``chai_scores`` warmup-feature buffer. A model with no global
+   attention layer has nothing to page: its state holds no pools and no
+   block tables, only its local rings or recurrent state, ``pos``,
+   ``phase`` and ``chai_scores``, and every per-slot function below skips
+   the pools it lacks. Page 0 of every pool is a reserved *null sink*:
    unallocated block-table entries point at it, so masked/oob writes land
    harmlessly and reads from it are always masked by ``pos`` validity.
    ``PagePool`` is the host-side allocator (free list, page 0 excluded).
@@ -211,130 +210,7 @@ def compact_kv(state, chai_ctx, cfg: ModelConfig):
 
 
 # ---------------------------------------------------------------------------
-# Unified per-slot layout (continuous batching)
-# ---------------------------------------------------------------------------
-
-def unified_state_structs(cfg: ModelConfig, batch: int, max_seq: int, *,
-                          chai: bool = True):
-    """Decode-state structs for the continuous-batching engine.
-
-    Dense (``kg``/``vg``) and clustered (``kg_chai``) caches are BOTH
-    resident so warmup and steady slots coexist in one batch; ``phase``
-    tracks each slot's lifecycle stage and ``chai_scores`` accumulates
-    warmup clustering features per slot.
-    """
-    shapes, logical = decode_state_structs(cfg, batch, max_seq)
-    shapes, logical = dict(shapes), dict(logical)
-    shapes["phase"] = jax.ShapeDtypeStruct((batch,), jnp.int32)
-    logical["phase"] = Ax("batch")
-    if not (chai and cfg.chai.enabled and cfg.k_max > 0):
-        return shapes, logical
-    wf = min(cfg.chai.feature_window, max_seq)
-    shapes["chai_scores"] = jax.ShapeDtypeStruct(
-        (cfg.n_attn_layers, batch, cfg.n_heads, wf), jnp.float32)
-    logical["chai_scores"] = Ax("layers", "batch", "heads", None)
-    if cfg.is_mha and "kg" in shapes:
-        k_max, _ = chai_widths(cfg)
-        dt = shapes["kg"].dtype
-        ng, b, _, s, hd = shapes["kg"].shape
-        shapes["kg_chai"] = jax.ShapeDtypeStruct((ng, b, k_max, s, hd), dt)
-        logical["kg_chai"] = Ax("layers", "batch", "clusters", "seq",
-                                "head_dim")
-        if cfg.kv_cache_dtype == "int8":
-            shapes["kg_chai_scale"] = jax.ShapeDtypeStruct(
-                (ng, b, k_max, s), jnp.float32)
-            logical["kg_chai_scale"] = Ax("layers", "batch", "clusters",
-                                          "seq")
-        if cfg.chai.share_values:
-            shapes["vg_chai"] = jax.ShapeDtypeStruct((ng, b, k_max, s, hd),
-                                                     dt)
-            logical["vg_chai"] = Ax("layers", "batch", "clusters", "seq",
-                                    "head_dim")
-    return shapes, logical
-
-
-def init_unified_state(cfg: ModelConfig, batch: int, max_seq: int, *,
-                       chai: bool = True):
-    shapes, _ = unified_state_structs(cfg, batch, max_seq, chai=chai)
-    return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
-
-
-def insert_slot(state, mini, slot, *, phase=PHASE_WARMUP):
-    """Write a freshly prefilled batch=1 decode state into batch slot
-    ``slot`` of a unified state and reset the slot's CHAI bookkeeping.
-    Donate ``state`` when jitting (in-place slot update on device).
-    """
-    state = dict(state)
-    for k, v in mini.items():
-        axis = 0 if state[k].ndim == 1 else 1
-        state[k] = jax.lax.dynamic_update_index_in_dim(
-            state[k], v.astype(state[k].dtype), slot, axis)
-    if "chai_scores" in state:
-        nA, _, h, wf = state["chai_scores"].shape
-        state["chai_scores"] = jax.lax.dynamic_update_index_in_dim(
-            state["chai_scores"], jnp.zeros((nA, 1, h, wf), jnp.float32),
-            slot, 1)
-    state["phase"] = state["phase"].at[slot].set(phase)
-    return state
-
-
-def compact_kv_slot(state, slot_ctx, cfg: ModelConfig, slot):
-    """Per-slot compaction (unified layout): gather ONE batch slot's
-    representative K rows from the dense cache into the clustered cache
-    and advance that slot's phase to STEADY.
-
-    ``slot_ctx``: batch-free ctx for this request (reps (nA, k)). Donate
-    ``state`` when jitting — the gather updates the clustered buffers in
-    place; the dense buffers stay resident for the other slots.
-    """
-    state = dict(state)
-    if cfg.is_mha and cfg.chai.enabled and "kg_chai" in state:
-        reps = slot_ctx["reps"]                           # (nA, k)
-
-        def gather(dense, clustered, tail_dims):
-            row = jax.lax.dynamic_index_in_dim(dense, slot, 1,
-                                               keepdims=False)
-            idx = reps.reshape(reps.shape + (1,) * tail_dims)
-            g = jnp.take_along_axis(row, idx, axis=1)
-            return jax.lax.dynamic_update_index_in_dim(clustered, g, slot, 1)
-
-        # All-global MHA archs: attention layer i == global layer i.
-        state["kg_chai"] = gather(state["kg"], state["kg_chai"], 2)
-        if cfg.kv_cache_dtype == "int8":
-            state["kg_chai_scale"] = gather(state["kg_scale"],
-                                            state["kg_chai_scale"], 1)
-        if cfg.chai.share_values:
-            state["vg_chai"] = gather(state["vg"], state["vg_chai"], 2)
-    state["phase"] = state["phase"].at[slot].set(PHASE_STEADY)
-    return state
-
-
-def reset_slot(state, slot):
-    """Retire a slot: mark FREE and rewind its write position."""
-    state = dict(state)
-    state["phase"] = state["phase"].at[slot].set(PHASE_FREE)
-    state["pos"] = state["pos"].at[slot].set(0)
-    return state
-
-
-def unified_kv_bytes(cfg: ModelConfig, batch: int, seq: int, *,
-                     chai: bool = True):
-    """Resident KV bytes of the continuous engine's unified layout.
-
-    Unlike the analytic ``kv_cache_bytes`` (cohort steady state: the
-    dense cache is freed after compaction), the unified layout keeps
-    dense AND clustered buffers allocated — summed exactly from the
-    layout's own structs."""
-    import numpy as np
-    shapes, _ = unified_state_structs(cfg, batch, seq, chai=chai)
-    kv_keys = ("kg", "vg", "kg_scale", "vg_scale", "kl", "vl",
-               "kg_chai", "kg_chai_scale", "vg_chai")
-    return int(sum(np.prod(s.shape) * s.dtype.itemsize
-                   for k, s in shapes.items() if k in kv_keys))
-
-
-# ---------------------------------------------------------------------------
-# Paged layout (continuous batching, EngineConfig.kv_layout="paged")
+# Paged layout (the serving engine's state)
 # ---------------------------------------------------------------------------
 
 NULL_PAGE = 0   # reserved per-pool sink; never allocated, never read valid
@@ -455,8 +331,8 @@ def paged_state_structs(cfg: ModelConfig, batch: int, max_seq: int, *,
     block tables ``bt_kg``/``bt_vg``; MHA+CHAI archs add the clustered
     pool ``cp`` (``k_max`` rows) with tables ``bt_kc`` (and ``bt_vc``
     under ``share_values``). Everything else (local ring caches,
-    recurrent state, ``pos``/``phase``/``chai_scores``) matches the
-    unified layout."""
+    recurrent state, ``pos``) is the model's own decode state, plus the
+    per-slot ``phase`` and, with CHAI on, ``chai_scores``."""
     from repro.models.transformer import decode_state_structs as _structs
     assert max_seq % page_size == 0, (max_seq, page_size)
     n_slot_pages = max_seq // page_size
@@ -535,8 +411,8 @@ def _scatter_pages(pool, x, pages):
 
 def insert_slot_paged(state, mini, slot, kg_pages, vg_pages, *,
                       bt_kg_row=None, bt_vg_row=None):
-    """Paged ``insert_slot``: write a prefilled batch=1 dense decode state
-    into slot ``slot``, scattering its global K/V rows into the slot's
+    """Write a prefilled batch=1 dense decode state into slot ``slot``
+    and reset its CHAI bookkeeping, scattering its global K/V rows into the slot's
     freshly allocated pages and recording the block tables. Donate
     ``state`` when jitting.
 
@@ -609,7 +485,7 @@ def compact_kv_slot_paged(state, slot_ctx, cfg: ModelConfig, slot,
         gather("cp", "cp_scale", "bt_kg", kc_pages)
         state["bt_kc"] = state["bt_kc"].at[slot].set(kc_pages)
         if cfg.chai.share_values:
-            # V codes move scale-less, mirroring the unified layout's
+            # V codes move scale-less, mirroring the cohort layout's
             # vg -> vg_chai gather (int8 codes are reinterpreted).
             vd_pages = kc_pages if vc_pages is None else vc_pages
             bt_row = jax.lax.dynamic_index_in_dim(
@@ -783,9 +659,8 @@ def paged_kv_bytes(cfg: ModelConfig, page_size: int, dense_in_use: int,
                    max_seq: int = 0):
     """ACTUAL allocated KV bytes of the paged layout: pages in use times
     page bytes, plus the (non-paged) local ring caches. This is the
-    number the continuous engine reports — it falls when dense pages are
-    freed at compaction, unlike the unified layout's constant
-    dense+clustered residency."""
+    number the serving engine reports — it falls when dense pages are
+    freed at compaction."""
     total = (dense_in_use * paged_page_bytes(cfg, page_size, kind="dense")
              + chai_in_use * paged_page_bytes(cfg, page_size, kind="chai"))
     if batch and cfg.n_local_layers:

@@ -1,6 +1,6 @@
 """Serving driver: ``python -m repro.launch.serve --arch <id> [...]``.
 
-Runs the default serving path — continuous scheduler, paged KV, CHAI on
+Runs the serving engine — continuous batching over paged KV, CHAI on
 (PREFILL -> WARMUP -> CLUSTER -> STEADY) — with random weights from seed 0
 and synthetic prompts, and reports TTFT / latency / KV bytes
 for CHAI vs MHA.
@@ -41,9 +41,9 @@ def model_config(arch, *, full=False, layers=0, chai=True):
 
 
 def build_engine(cfg, *, slots, max_seq, seed=0, num_pages=0, chai=True):
-    """Random weights from ``seed`` and an engine with the default layout
-    and scheduler. ``num_pages`` sizes both page pools (0 = the engine's
-    worst-case auto size)."""
+    """Random weights from ``seed`` and an engine with default settings.
+    ``num_pages`` sizes both page pools (0 = the engine's worst-case auto
+    size)."""
     params = tfm.init_params(cfg, jax.random.PRNGKey(seed))
     ecfg = EngineConfig(batch_slots=slots, max_seq=max_seq, use_chai=chai,
                         num_pages=num_pages, num_chai_pages=num_pages)
@@ -99,8 +99,7 @@ def main(argv=None):
           f"requests={len(done)} tokens={n_tok}")
     print(f"[serve] wall={wall:.2f}s tok/s={n_tok / wall:.1f} "
           f"ttft_mean={np.mean(ttfts)*1e3:.0f}ms "
-          f"lat_mean={np.mean(lats)*1e3:.0f}ms "
-          f"redispatched={eng.redispatched}")
+          f"lat_mean={np.mean(lats)*1e3:.0f}ms")
     print(f"[serve] kv_bytes chai={eng.kv_bytes(chai=True):,} "
           f"mha={eng.kv_bytes(chai=False):,} "
           f"saving={100*(1-eng.kv_bytes(chai=True)/max(eng.kv_bytes(chai=False),1)):.1f}%")

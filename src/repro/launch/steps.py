@@ -72,8 +72,8 @@ def make_serve_prefill(cfg: ModelConfig, batch: int, max_seq: int, *,
     """Whole-cohort prefill. ``batch_inputs`` may carry ``true_lens``
     ((B,) int32): prompts are then right-padded to one power-of-two
     bucket and each row's padding tail is masked per example (last-real
-    logits, per-example ``pos``), so the cohort scheduler compiles one
-    prefill per BUCKET instead of one per padded cohort length."""
+    logits, per-example ``pos``), so a caller compiles one prefill per
+    BUCKET instead of one per padded cohort length."""
     def serve_prefill(params, batch_inputs):
         inputs = batch_inputs.get("tokens", batch_inputs.get("embeddings"))
         state = tfm.init_decode_state(cfg, batch, max_seq)
@@ -88,10 +88,9 @@ def make_serve_prefill(cfg: ModelConfig, batch: int, max_seq: int, *,
 
 def make_serve_step(cfg: ModelConfig, *, chai=False, moe_impl="capacity",
                     unroll=False, decode_ts=0):
-    """``decode_ts``: the page size the fused CHAI decode kernel's
-    dense-layout tile derives from — the engine passes its page size so
-    the cohort/dense schedulers round exactly like the paged one (token
-    parity)."""
+    """``decode_ts``: the page size the fused CHAI decode kernel's S-tile
+    derives from (0 = whole sequence on the cohort layout) — the engine
+    passes its page size."""
     def serve_step(params, batch_inputs, state, chai_ctx=None):
         kw = {}
         if "embeddings" in batch_inputs:
@@ -110,7 +109,7 @@ def make_serve_step(cfg: ModelConfig, *, chai=False, moe_impl="capacity",
 
 def make_sampler(top_k_cap: int = 256):
     """Batched per-slot token sampler — the single device-side sampling
-    path shared by the continuous and cohort schedulers.
+    path of the serving engine.
 
     ``sample(logits, temperature, top_k, top_p, seed, count)``:
 
@@ -127,7 +126,8 @@ def make_sampler(top_k_cap: int = 256):
       lane a zeroed row instead of their (discarded) logits.
     * Sampling slots draw from ``fold_in(PRNGKey(seed), count)``: token
       n of a request depends only on (seed, n, logits) — never the slot
-      id or engine step — so seeded runs reproduce across schedulers.
+      id or engine step — so seeded runs reproduce across slot
+      placements and batch sizes.
     * The candidate set is ``lax.top_k(scaled, min(top_k_cap, V))`` — an
       O(V·cap) selection instead of the old full-vocab argsort. top-k /
       top-p masks apply in descending order within the candidates (top-p
@@ -233,67 +233,26 @@ def make_relay_step(cfg: ModelConfig, *, moe_impl="ragged", unroll=False,
     return relay_step
 
 
-def make_slot_prefill(cfg: ModelConfig, max_seq: int, *,
-                      moe_impl="capacity", unroll=False):
-    """Prefill ONE request (batch=1 forward) and insert it into batch slot
-    ``slot`` of a unified decode state. Donate the state when jitting.
-
-    The returned callable is shape-specialized to the PADDED length of
-    ``tokens`` — the engine right-pads prompts to power-of-two buckets
-    and passes the real length as the traced ``true_len``, so retraces
-    are O(log max_seq) instead of O(distinct prompt lengths). Padding
-    rows beyond ``true_len`` are masked out of the logits, the decode
-    ``pos``, and the local ring caches (``forward_fullseq`` valid_len).
-    """
-    def slot_prefill(params, tokens, true_len, state, slot):
-        mini = tfm.init_decode_state(cfg, 1, max_seq)
-        logits, mini, _ = tfm.forward_fullseq(
-            params, cfg, tokens, state=mini, logits_slice="last",
-            moe_impl=moe_impl, unroll=unroll, valid_len=true_len)
-        state = chai_cache.insert_slot(state, mini, slot)
-        return logits[:, 0], state
-
-    return slot_prefill
-
-
-def make_slot_cluster(cfg: ModelConfig, identify_fn):
-    """CLUSTER transition for one slot: identify membership from the
-    slot's accumulated warmup scores (via ``identify_fn``, the engine's
-    batched identification hook), scatter it into the batched ctx, and
-    compact the slot's dense K rows into the clustered cache."""
-    def cluster_slot(state, ctx, slot):
-        # Batch-of-1 through the batched hook: K-Means runs only for this
-        # slot, and monkeypatched hooks (CHAI-static, tests) still apply.
-        from repro.core import clustering
-        scores = jax.lax.dynamic_slice_in_dim(state["chai_scores"], slot, 1,
-                                              axis=1)[:, 0]
-        slot_ctx = clustering.identify_membership_slot(scores, cfg,
-                                                       identify_fn)
-        ctx = clustering.update_ctx_slot(ctx, slot_ctx, slot)
-        state = chai_cache.compact_kv_slot(state, slot_ctx, cfg, slot)
-        return state, ctx
-
-    return cluster_slot
-
-
-def make_slot_reset(cfg: ModelConfig):
-    def reset(state, slot):
-        return chai_cache.reset_slot(state, slot)
-    return reset
-
-
 # ---------------------------------------------------------------------------
 # Paged KV layout (continuous batching over a block-table page pool)
 # ---------------------------------------------------------------------------
 
 def make_paged_slot_prefill(cfg: ModelConfig, max_seq: int, *,
                             moe_impl="capacity", unroll=False):
-    """Paged ``make_slot_prefill``: the batch=1 forward fills a dense mini
-    state, which is then scattered into the slot's freshly allocated
-    pages (``kg_pages``/``vg_pages``: (P,) int32, null-padded). Donate
-    the state when jitting; shape-specialized per power-of-two prompt
-    BUCKET (padding rows beyond ``true_len`` land either inside the
-    slot's own pages — masked by ``pos`` — or in the null sink page)."""
+    """Prefill ONE request (batch=1 forward) and insert it into batch slot
+    ``slot``: the forward fills a dense mini state, which is then
+    scattered into the slot's freshly allocated pages (``kg_pages``/
+    ``vg_pages``: (P,) int32, null-padded; all null for a model with no
+    pools). Donate the state when jitting.
+
+    The returned callable is shape-specialized to the PADDED length of
+    ``tokens`` — the engine right-pads prompts to power-of-two buckets
+    and passes the real length as the traced ``true_len``, so retraces
+    are O(log max_seq) instead of O(distinct prompt lengths). Padding
+    rows beyond ``true_len`` are masked out of the logits, the decode
+    ``pos`` and the local ring caches (``forward_fullseq`` valid_len),
+    and their cache rows land either inside the slot's own pages —
+    masked by ``pos`` — or in the null sink page."""
     def slot_prefill(params, tokens, true_len, state, slot, kg_pages,
                      vg_pages):
         mini = tfm.init_decode_state(cfg, 1, max_seq)

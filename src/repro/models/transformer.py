@@ -414,8 +414,8 @@ def _mixer_fullseq_branch(kind, cfg, params, plan_arrays, positions,
                     # Latest real position per ring slot: p(s) is the
                     # largest p < valid_len with p ≡ s (mod w); slots
                     # with no such p (valid_len < w tail) keep old rows.
-                    # valid_len may be per-example (B,) — the cohort
-                    # scheduler right-pads ragged prompts to one bucket.
+                    # valid_len may be per-example (B,) — a ragged
+                    # cohort prefill right-pads its prompts to one bucket.
                     vl = jnp.asarray(valid_len, jnp.int32)
                     bN = kn.shape[0]
                     vl_b = (jnp.broadcast_to(vl, (bN,)) if vl.ndim == 0
@@ -574,8 +574,8 @@ def forward_fullseq(params, cfg: ModelConfig, inputs, *, state=None,
     right-padding. "last" logits then come from index valid_len - 1, the
     decode state's ``pos`` starts at valid_len, and local ring-cache
     writes mask the padding tail (the engine's power-of-two prompt
-    buckets reuse one jit per bucket; the cohort scheduler passes a
-    per-example vector for ragged cohorts).
+    buckets reuse one jit per bucket; a ragged cohort prefill,
+    ``make_serve_prefill``'s ``true_lens``, passes a per-example vector).
     ``prefix_len``/``prefix_kv`` (traced scalar + paged pool/block-table
     dict, see ``_mixer_fullseq_branch``): cached-prefix suffix prefill —
     this call's tokens sit at absolute positions ``prefix_len +

@@ -73,10 +73,6 @@ class LLM:
         elif ecfg_kw:
             raise ValueError(f"pass ecfg OR EngineConfig kwargs, not both "
                              f"({sorted(ecfg_kw)})")
-        if ecfg.scheduler != "continuous":
-            raise ValueError("LLM drives EngineCore.step(): continuous "
-                             "scheduler only (use ServingEngine for the "
-                             "legacy cohort path)")
         self.core = EngineCore(cfg, params, ecfg, detokenizer=detokenizer,
                                faults=faults)
         self.detokenizer = detokenizer

@@ -74,9 +74,6 @@ class AsyncLLM:
         elif ecfg_kw:
             raise ValueError(f"pass ecfg OR EngineConfig kwargs, not both "
                              f"({sorted(ecfg_kw)})")
-        if ecfg.scheduler != "continuous":
-            raise ValueError("AsyncLLM drives EngineCore.step(): "
-                             "continuous scheduler only")
         self.core = EngineCore(cfg, params, ecfg, detokenizer=detokenizer,
                                faults=faults)
         self._max_restarts = int(max_restarts)
@@ -332,7 +329,7 @@ class AsyncLLM:
 
     async def tier_stats(self):
         """Hierarchical KV tier counters (per-tier residency, transition
-        totals, prefetch hits/misses); None on non-paged engines."""
+        totals, prefetch hits/misses); None for a model with no page pools."""
         return await self._call(self.core.tier_stats)
 
     def _output_of(self, req: Request) -> RequestOutput:

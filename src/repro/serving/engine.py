@@ -18,7 +18,7 @@ plus three out-of-band edges:
 * chunked PREFILL self-loop — with ``EngineConfig.prefill_chunk_tokens``
   set, a long prompt forwards one page-aligned chunk per ``step()``
   instead of monolithically, so a prompt storm cannot stall the decoding
-  slots for its whole length (greedy tokens are unchanged; paged layout,
+  slots for its whole length (greedy tokens are unchanged;
   global-attention archs only).
 * PREEMPT / RESUME — with ``EngineConfig.preemption`` (default on), a
   strictly-higher-``priority`` arrival that cannot be admitted for page
@@ -46,38 +46,29 @@ The engine is layered:
 * ``ServingEngine`` — the historical ``submit()`` / ``run()`` batch
   surface, now a thin compatibility wrapper that loops ``step()``.
 
-Sampling is one batched device jit (``repro.launch.steps.make_sampler``)
-shared by both schedulers; ``temperature=0`` slots take the raw-logits
-argmax, so greedy decode is bitwise-identical to the historical greedy
-path (CHAI snapshot capture/replay stays gated to greedy requests).
-Seeded draws key on (request seed, tokens sampled so far) — reproducible
-across schedulers and slot placements.
+Sampling is one batched device jit (``repro.launch.steps.make_sampler``);
+``temperature=0`` slots take the raw-logits argmax, so greedy decode is
+bitwise-identical to the historical greedy path (CHAI snapshot
+capture/replay stays gated to greedy requests). Seeded draws key on
+(request seed, tokens sampled so far) — reproducible across slot
+placements and batch sizes.
 
-Two schedulers (``EngineConfig.scheduler``):
+Scheduling is slot-level continuous batching: a fixed pool of batch
+slots (static shapes for XLA) holds requests at *different* phases
+simultaneously; the decode step is one jit that routes each slot to the
+MHA or CHAI attention path according to the per-slot ``phase`` vector
+(mask-and-select), host-dispatching to the cheaper all-MHA / all-CHAI
+jits when no slot is mid-transition.
 
-* ``"continuous"`` (default) — slot-level continuous batching, the
-  step-driven core above. A fixed pool of batch slots (static shapes for
-  XLA) holds requests at *different* phases simultaneously; the decode
-  step is one jit that routes each slot to the MHA or CHAI attention
-  path according to the per-slot ``phase`` vector (mask-and-select),
-  host-dispatching to the cheaper all-MHA / all-CHAI jits when no slot
-  is mid-transition.
-
-  Two KV layouts (``EngineConfig.kv_layout``):
-
-  - ``"paged"`` (default) — block-table paged KV
-    (``repro.core.cache.paged_state_structs``). Admission is
-    page-budget-based, and the CLUSTER transition frees the slot's dense
-    K pages back to the ``PagePool`` the moment the representative rows
-    are gathered into clustered pages — steady-state CHAI occupies less
-    allocator memory than dense MHA (the paper's 21.4%-class saving in
-    ``kv_bytes()``).
-  - ``"dense"`` — the legacy *unified per-slot layout*
-    (``unified_state_structs``), kept for parity testing.
-
-* ``"cohort"`` — the legacy lockstep path
-  (``repro.serving.cohort.CohortSchedulerMixin``), kept for A/B parity
-  testing.
+The device state is block-table paged KV
+(``repro.core.cache.paged_state_structs``). Admission is page-budget
+based, and the CLUSTER transition frees the slot's dense K pages back to
+the ``PagePool`` the moment the representative rows are gathered into
+clustered pages — steady-state CHAI occupies less allocator memory than
+dense MHA (the paper's 21.4%-class saving in ``kv_bytes()``). A model
+with no global attention layer has nothing to page: its state holds no
+pools, and it runs without page-budget admission, KV tiers, preemption,
+a prefix cache or chunked prefill.
 
 Every Request records arrival, admission (slot id + engine step), first
 token, and completion, so per-request TTFT / ITL / latency and engine
@@ -105,7 +96,6 @@ from repro.serving import invariants as invariants_mod
 from repro.serving import kv_tiers as kv_tiers_mod
 from repro.serving import sampling as sampling_mod
 from repro.serving import telemetry as telemetry_mod
-from repro.serving.cohort import CohortSchedulerMixin
 from repro.serving.faults import (CapacityError, EngineFault, FaultInjector,
                                   InjectedFault, QuarantineError,
                                   RequestError, SnapshotRestoreError,
@@ -138,9 +128,9 @@ class Request:                         # abort() membership-test Requests
     t_arrival: float = 0.0             # Poisson workloads: earliest admit
     t_first_token: float = 0.0
     t_done: float = 0.0
-    slot: int = -1                     # continuous: slot the request ran in
-    admit_step: int = -1               # continuous: engine step at admission
-    retire_step: int = -1              # continuous: engine step at retire
+    slot: int = -1                     # slot the request ran in
+    admit_step: int = -1               # engine step at admission
+    retire_step: int = -1              # engine step at retire
     # -- prefix cache --
     cache_hit: str = ""                # "" | "prefix" | "snapshot" | "replay"
     cached_tokens: int = 0             # prompt tokens served from cache
@@ -182,22 +172,18 @@ class StepOutput:
 
 @dataclasses.dataclass
 class EngineConfig:
-    batch_slots: int = 4               # slot-pool / cohort size (static)
+    batch_slots: int = 4               # slot-pool size (static)
     max_seq: int = 256                 # KV capacity per slot (static)
     # Default SamplingParams for requests submitted without one:
     # greedy=True -> temperature 0 (the historical behaviour);
     # greedy=False -> temperature 1.0. Requests carrying explicit
     # SamplingParams ignore this flag entirely.
     greedy: bool = True
-    scheduler: str = "continuous"      # "continuous" | "cohort"
-    cohort_deadline_s: float = 120.0   # cohort straggler re-dispatch
     use_chai: bool = True
-    # -- KV layout (continuous scheduler only) --------------------------
-    # "paged": block-table page pool; a slot's dense K pages are FREED at
+    # -- paged KV ---------------------------------------------------------
+    # Block-table page pools; a slot's dense K pages are FREED at
     # compaction, so steady-state CHAI occupies less allocator memory
-    # than dense MHA (the paper's saving, realized). "dense": the legacy
-    # unified per-slot rectangles (dense + clustered resident together).
-    kv_layout: str = "paged"           # "paged" | "dense"
+    # than dense MHA (the paper's saving, realized).
     page_size: int = 16                # tokens per page (divides max_seq)
     # Pool capacities in pages, INCLUDING the reserved null page 0.
     # 0 = auto: worst case for batch_slots requests of max_seq tokens
@@ -205,7 +191,7 @@ class EngineConfig:
     # page-budget admission path).
     num_pages: int = 0                 # dense K/V pool
     num_chai_pages: int = 0            # clustered pool (MHA+CHAI archs)
-    # -- shared-prefix KV reuse (paged layout only) ---------------------
+    # -- shared-prefix KV reuse (global-attention archs only) -----------
     # Radix-tree prefix cache over token blocks: admission aliases the
     # longest cached block-prefix into the slot's block tables and
     # prefills only the uncached suffix; for MHA+CHAI archs a GREEDY
@@ -217,7 +203,7 @@ class EngineConfig:
     # message. Cached pages are refcounted, copy-on-write, LRU-evicted
     # under pressure.
     prefix_cache: bool = False
-    # -- SLO-aware scheduling (continuous + paged) ----------------------
+    # -- SLO-aware scheduling ---------------------------------------------
     # Chunked prefill (Sarathi-style): a prompt longer than this
     # forwards at most ``prefill_chunk_tokens`` per ``step()`` (rounded
     # up to a page multiple), interleaved with running decodes — a long
@@ -234,7 +220,7 @@ class EngineConfig:
     # membership / warmup scores), so resumed decoding continues where
     # it stopped instead of failing. Equal priorities never preempt.
     preemption: bool = True
-    # -- shared-prefix relay decode (prefix_cache + paged + CHAI) -------
+    # -- shared-prefix relay decode (prefix_cache + CHAI) ---------------
     # Compute system-prompt attention once per batch: STEADY slots
     # admitted through the same radix chain group on their deepest
     # shared node with >= relay_min_group members; each decode step runs
@@ -275,9 +261,10 @@ class EngineConfig:
     # ``decode_heals``. 0 (default) = never heal (the historical
     # permanently-degraded behaviour).
     decode_heal_steps: int = 0
-    # -- hierarchical KV tiers (serving/kv_tiers.py; paged layout) ------
-    # Every paged engine owns a TierManager: preemption swap-out always
-    # routes victim payloads through its host page pool. kv_offload
+    # -- hierarchical KV tiers (serving/kv_tiers.py) ---------------------
+    # Every engine with page pools owns a TierManager: preemption
+    # swap-out always routes victim payloads through its host page pool;
+    # a model with no global attention layer has neither. kv_offload
     # additionally turns prefix-cache eviction into DEMOTION — under
     # pool pressure unlocked radix leaves / CHAI snapshots move to host
     # pages instead of dropping (the LRU ladder walks hot -> host ->
@@ -306,7 +293,7 @@ class EngineConfig:
 _REPLAN = object()
 
 
-class EngineCore(CohortSchedulerMixin):
+class EngineCore:
     """Device-state owner + one-iteration scheduler (``step()``).
 
     ``detokenizer``: optional ``List[int] -> str`` used to match
@@ -318,8 +305,6 @@ class EngineCore(CohortSchedulerMixin):
                  faults: Optional[FaultInjector] = None):
         assert cfg.n_attn_layers > 0 or not ecfg.use_chai, \
             "CHAI needs attention layers"
-        assert ecfg.scheduler in ("continuous", "cohort"), ecfg.scheduler
-        assert ecfg.kv_layout in ("paged", "dense"), ecfg.kv_layout
         if ecfg.audit_level not in ("off", "basic", "deep"):
             raise ValueError(f"audit_level must be off|basic|deep, "
                              f"got {ecfg.audit_level!r}")
@@ -350,32 +335,29 @@ class EngineCore(CohortSchedulerMixin):
         self._fault_blocked = False    # last plan blocked by injection
         self.queue: deque = deque()
         self.done: List[Request] = []
-        self.redispatched = 0
-        self.steps_executed = 0        # continuous: batched decode steps
+        self.steps_executed = 0        # batched decode steps
         self._step_calls = 0           # every _step_inner entry (spans)
         self._span_step = -1           # step ordinal current spans carry
         b, s = ecfg.batch_slots, ecfg.max_seq
 
         chai_on = ecfg.use_chai and cfg.chai.enabled and cfg.k_max > 0
         self.chai_on = chai_on
-        # Paged layout: continuous scheduler over global-attention KV
-        # (archs without global layers have nothing to page).
-        self.paged = (ecfg.scheduler == "continuous"
-                      and ecfg.kv_layout == "paged"
-                      and cfg.n_global_layers > 0)
-        # MHA+CHAI archs carry the clustered page pool.
-        self.chai_clustered = (self.paged and chai_on and cfg.is_mha)
+        # Page pools hold global-attention KV: archs without global
+        # layers have nothing to page. MHA+CHAI archs add the clustered
+        # pool.
+        has_pools = cfg.n_global_layers > 0
+        self.chai_clustered = has_pools and chai_on and cfg.is_mha
         self.dense_pool = None
         self.chai_pool = None
-        # Paged allocated-bytes trajectory (benchmarks/tests). Bounded:
+        # Allocated-bytes trajectory (benchmarks/tests). Bounded:
         # recording stops at _HISTORY_MAX entries (the PREFILL->STEADY
         # head is what the benches read); the peak is a running int.
         self.kv_bytes_history: List[dict] = []
         self._kv_peak = 0
-        if self.paged:
-            assert s % ecfg.page_size == 0, (s, ecfg.page_size)
-            p_slot = s // ecfg.page_size
-            self._slot_pages_max = p_slot
+        assert s % ecfg.page_size == 0, (s, ecfg.page_size)
+        p_slot = s // ecfg.page_size
+        self._slot_pages_max = p_slot
+        if has_pools:
             n_dense = ecfg.num_pages or (2 * b * p_slot + 1)
             self.dense_pool = chai_cache.PagePool(n_dense, ecfg.page_size)
             if self.chai_clustered:
@@ -385,9 +367,6 @@ class EngineCore(CohortSchedulerMixin):
         # -- shared-prefix KV reuse ---------------------------------------
         self.prefix_cache = None
         if ecfg.prefix_cache:
-            if not self.paged:
-                raise ValueError("prefix_cache requires the paged KV "
-                                 "layout on the continuous scheduler")
             if (cfg.n_local_layers or cfg.n_rec_layers
                     or cfg.n_rwkv_layers):
                 # Local rings / recurrent state depend on the whole
@@ -400,17 +379,18 @@ class EngineCore(CohortSchedulerMixin):
             self.prefix_cache = PrefixCache(self.dense_pool,
                                             self.chai_pool, ecfg.page_size)
         # -- hierarchical KV tiers (serving/kv_tiers.py) ------------------
-        # Built for EVERY paged engine: preemption swap-out always routes
-        # its victim payloads through the host pool. Prefix-cache
-        # demotion (eviction -> host instead of drop) additionally needs
-        # ecfg.kv_offload.
+        # Built for EVERY engine with page pools: preemption swap-out
+        # always routes its victim payloads through the host pool.
+        # Prefix-cache demotion (eviction -> host instead of drop)
+        # additionally needs ecfg.kv_offload.
         self.tiers = None
         self._prefetch_q: deque = deque()
         self._prefetch_ids: set = set()
-        if ecfg.kv_offload and not self.paged:
-            raise ValueError("kv_offload requires the paged KV layout "
-                             "on the continuous scheduler")
-        if self.paged:
+        if ecfg.kv_offload and not has_pools:
+            raise ValueError(
+                "kv_offload needs page pools: "
+                f"{cfg.name!r} has no global attention layer")
+        if has_pools:
             host_d = ecfg.host_pages or 2 * self.dense_pool.capacity
             host_c = 0
             if self.chai_pool is not None:
@@ -429,9 +409,9 @@ class EngineCore(CohortSchedulerMixin):
                 self.tiers.droppable_hook = self.prefix_cache._droppable
                 if ecfg.kv_offload:
                     self.prefix_cache.demote_hook = self._demote_entry
-        # -- chunked prefill (page-aligned chunks; paged layout only) -----
+        # -- chunked prefill (page-aligned chunks) ------------------------
         self._chunk = 0
-        if ecfg.prefill_chunk_tokens and self.paged:
+        if ecfg.prefill_chunk_tokens:
             if (cfg.n_local_layers or cfg.n_rec_layers
                     or cfg.n_rwkv_layers):
                 raise ValueError(
@@ -441,15 +421,14 @@ class EngineCore(CohortSchedulerMixin):
                     "or recurrent state from earlier chunks")
             ps = ecfg.page_size
             self._chunk = -(-ecfg.prefill_chunk_tokens // ps) * ps
-        # Device state persists across step()/run() calls: paged, so
-        # cached pages keep their contents between request waves; dense,
-        # so the step-driven core never rebuilds mid-stream (retired
-        # slots rewind pos — stale rows are masked exactly like the zero
-        # tail). None until the first continuous step.
+        # Device state persists across step()/run() calls, so cached
+        # pages keep their contents between request waves (retired slots
+        # rewind pos — stale rows are masked exactly like the zero tail).
+        # None until the first step.
         self._dev_state = None
         self._dev_ctx = None
         self.cluster_transitions = 0   # CLUSTER phase transitions executed
-        # -- step-driven scheduler state (continuous) ---------------------
+        # -- step-driven scheduler state ----------------------------------
         self._uid_counter = 0          # monotonic: uids never collide
         self._requests: dict = {}      # uid -> Request (abort lookup)
         self._slot_req: List[Optional[Request]] = [None] * b
@@ -473,12 +452,9 @@ class EngineCore(CohortSchedulerMixin):
         self._samp_dev = None
         self._samp_dirty = True
         # jax.jit wrappers are lazy (no tracing until the first call), so
-        # both schedulers' steps are declared here unconditionally.
-        # decode_ts = page_size: the fused CHAI kernel's dense-layout tile
-        # is the paged kernel's tile for that page size (both come from
-        # kernels.chai_attention.decode_tile_pages), so every layout and
-        # scheduler performs bit-identical attention arithmetic
-        # (cross-layout token parity).
+        # every step is declared here unconditionally. decode_ts =
+        # page_size: the page size the fused CHAI decode kernel's S-tile
+        # derives from (kernels.chai_attention.decode_tile_pages).
         self._sampler = jax.jit(steps_mod.make_sampler())
         # All-greedy fast path: the full sampler computes its sampling
         # lane (argsort + softmax + PRNG) for every slot and discards it
@@ -497,38 +473,32 @@ class EngineCore(CohortSchedulerMixin):
             steps_mod.make_serve_step(cfg, chai=False,
                                       decode_ts=ecfg.page_size),
             donate_argnums=(2,))
-        self._prefill = jax.jit(steps_mod.make_serve_prefill(cfg, b, s))
-        reset_maker = (steps_mod.make_paged_slot_reset if self.paged
-                       else steps_mod.make_slot_reset)
-        self._reset_slot = jax.jit(reset_maker(cfg), donate_argnums=(0,))
+        self._reset_slot = jax.jit(steps_mod.make_paged_slot_reset(cfg),
+                                   donate_argnums=(0,))
         self._slot_prefills: dict = {}       # pow2 length bucket -> jit
         self._suffix_prefills: dict = {}     # suffix bucket -> jit
         self._chunk_prefills: dict = {}      # chunk bucket -> jit
-        self._cohort_buckets: set = set()    # pow2 buckets seen (observab.)
         self._cluster_slot = None            # built lazily (identify hook)
         self._swap_fns = None                # preemption KV swap (out, in)
-        if self.paged:
-            self._restore_snapshot = jax.jit(
-                steps_mod.make_snapshot_restore(cfg), donate_argnums=(0,))
-            self._copy_page = {
-                kind: jax.jit(steps_mod.make_page_copy(cfg, kind),
-                              donate_argnums=(0,))
-                for kind in ("dense", "chai")}
-            # Tier demote/promote: one-page gather / scatter jits (the
-            # page id is traced — one trace per kind).
-            self._fetch_page = {"dense": jax.jit(
-                steps_mod.make_page_fetch(cfg, "dense"))}
-            self._put_page = {"dense": jax.jit(
-                steps_mod.make_page_put(cfg, "dense"),
-                donate_argnums=(0,))}
-            if self.chai_clustered:
-                self._fetch_page["chai"] = jax.jit(
-                    steps_mod.make_page_fetch(cfg, "chai"))
-                self._put_page["chai"] = jax.jit(
-                    steps_mod.make_page_put(cfg, "chai"),
-                    donate_argnums=(0,))
-            self._set_ctx = jax.jit(clustering.update_ctx_slot,
-                                    donate_argnums=(0,))
+        self._restore_snapshot = jax.jit(
+            steps_mod.make_snapshot_restore(cfg), donate_argnums=(0,))
+        self._copy_page = {
+            kind: jax.jit(steps_mod.make_page_copy(cfg, kind),
+                          donate_argnums=(0,))
+            for kind in ("dense", "chai")}
+        # Tier demote/promote: one-page gather / scatter jits (the page id
+        # is traced — one trace per kind).
+        self._fetch_page = {"dense": jax.jit(
+            steps_mod.make_page_fetch(cfg, "dense"))}
+        self._put_page = {"dense": jax.jit(
+            steps_mod.make_page_put(cfg, "dense"), donate_argnums=(0,))}
+        if self.chai_clustered:
+            self._fetch_page["chai"] = jax.jit(
+                steps_mod.make_page_fetch(cfg, "chai"))
+            self._put_page["chai"] = jax.jit(
+                steps_mod.make_page_put(cfg, "chai"), donate_argnums=(0,))
+        self._set_ctx = jax.jit(clustering.update_ctx_slot,
+                                donate_argnums=(0,))
         if chai_on:
             self._chai_step = jax.jit(
                 steps_mod.make_serve_step(cfg, chai=True,
@@ -537,8 +507,6 @@ class EngineCore(CohortSchedulerMixin):
             self._mixed_step = jax.jit(
                 steps_mod.make_mixed_step(cfg, decode_ts=ecfg.page_size),
                 donate_argnums=(2,))
-            self._compact = jax.jit(steps_mod.make_compact_step(cfg),
-                                    donate_argnums=(0,))
             self._identify = jax.jit(
                 lambda sc: clustering.identify_membership(sc, cfg))
         # -- shared-prefix relay decode -----------------------------------
@@ -559,11 +527,11 @@ class EngineCore(CohortSchedulerMixin):
         self._put_rows = jax.jit(lambda a, idx, v: a.at[idx].set(v))
         self.relay_decode = False
         if ecfg.relay_decode:
-            if not (self.paged and chai_on and ecfg.prefix_cache):
+            if not (chai_on and ecfg.prefix_cache):
                 raise ValueError(
-                    "relay_decode requires prefix_cache on the paged "
-                    "layout with CHAI enabled (the relay groups STEADY "
-                    "slots by their locked radix chain)")
+                    "relay_decode requires prefix_cache with CHAI "
+                    "enabled (the relay groups STEADY slots by their "
+                    "locked radix chain)")
             self.relay_decode = True
             # One jit; jax retraces per relay signature (G, Nmax, Sp) —
             # group shapes recur across steps so the trace cache holds.
@@ -615,8 +583,7 @@ class EngineCore(CohortSchedulerMixin):
         req.generated = []
         self.queue.append(req)
         self._requests[uid] = req
-        if (self.paged and self.ecfg.kv_offload
-                and self.ecfg.tier_prefetch
+        if (self.ecfg.kv_offload and self.ecfg.tier_prefetch
                 and self.prefix_cache is not None):
             # Admission-time prefetch: queue the demoted entries this
             # prompt will hit for promotion ahead of the planning step.
@@ -712,8 +679,7 @@ class EngineCore(CohortSchedulerMixin):
             t0 = time.perf_counter()
         with tel.span("step", step=self._step_calls):
             outs = self._step_inner()
-            if self.ecfg.audit_level != "off" \
-                    and self.ecfg.scheduler == "continuous":
+            if self.ecfg.audit_level != "off":
                 self.audit_steps += 1
                 with tel.span("audit", step=self._span_step):
                     vio = invariants_mod.audit(
@@ -735,9 +701,6 @@ class EngineCore(CohortSchedulerMixin):
         return outs
 
     def _step_inner(self) -> List[StepOutput]:
-        if self.ecfg.scheduler != "continuous":
-            raise RuntimeError("step() drives the continuous scheduler; "
-                               "cohort engines run via run()")
         outs: List[StepOutput] = []
         self._ensure_dev_state()
         if self._prefetch_q:
@@ -925,26 +888,29 @@ class EngineCore(CohortSchedulerMixin):
                   help="Batch slots holding a live request")
         tel.gauge("engine_degraded_decode", int(self.degraded_decode),
                   help="1 while decode runs the jnp reference fallback")
-        if self.paged:
-            tel.gauge("kv_bytes_allocated", self.kv_bytes(),
-                      help="Allocated KV bytes right now")
+        self._tel_kv_gauges(self.kv_bytes())
+        if self.tiers is not None:
+            help_tier = "KV pages resident per tier and pool kind"
+            tel.gauge("kv_tier_pages", self.dense_pool.pages_in_use,
+                      tier="hot", kind="dense", help=help_tier)
+            if self.chai_pool is not None:
+                tel.gauge("kv_tier_pages", self.chai_pool.pages_in_use,
+                          tier="hot", kind="chai", help=help_tier)
+            for (tier, kind), n in self.tiers.tier_pages().items():
+                tel.gauge("kv_tier_pages", n, tier=tier, kind=kind,
+                          help=help_tier)
+
+    def _tel_kv_gauges(self, bytes_now: int):
+        """Allocated-KV gauges: bytes, and pages in use per pool."""
+        tel = self.tel
+        tel.gauge("kv_bytes_allocated", bytes_now,
+                  help="Allocated KV bytes right now")
+        if self.dense_pool is not None:
             tel.gauge("dense_pages_in_use", self.dense_pool.pages_in_use,
                       help="Dense-pool pages in use")
-            if self.chai_pool is not None:
-                tel.gauge("chai_pages_in_use",
-                          self.chai_pool.pages_in_use,
-                          help="Clustered-pool pages in use")
-            if self.tiers is not None:
-                help_tier = "KV pages resident per tier and pool kind"
-                tel.gauge("kv_tier_pages", self.dense_pool.pages_in_use,
-                          tier="hot", kind="dense", help=help_tier)
-                if self.chai_pool is not None:
-                    tel.gauge("kv_tier_pages",
-                              self.chai_pool.pages_in_use,
-                              tier="hot", kind="chai", help=help_tier)
-                for (tier, kind), n in self.tiers.tier_pages().items():
-                    tel.gauge("kv_tier_pages", n, tier=tier, kind=kind,
-                              help=help_tier)
+        if self.chai_pool is not None:
+            tel.gauge("chai_pages_in_use", self.chai_pool.pages_in_use,
+                      help="Clustered-pool pages in use")
 
     def metrics(self):
         """JSON-ready metrics snapshot (refreshes point-in-time gauges
@@ -970,7 +936,7 @@ class EngineCore(CohortSchedulerMixin):
         when telemetry is off)."""
         return exporters_mod.to_chrome_trace(self.tel.spans)
 
-    # -- continuous scheduler ----------------------------------------------
+    # -- scheduler ---------------------------------------------------------
     @staticmethod
     def _prompt_bucket(t: int, cap: int) -> int:
         """Next power of two >= t, capped at max_seq."""
@@ -986,10 +952,8 @@ class EngineCore(CohortSchedulerMixin):
         instead of O(distinct prompt lengths)."""
         fn = self._slot_prefills.get(bucket)
         if fn is None:
-            maker = (steps_mod.make_paged_slot_prefill if self.paged
-                     else steps_mod.make_slot_prefill)
-            fn = jax.jit(maker(self.cfg, self.ecfg.max_seq),
-                         donate_argnums=(3,))
+            fn = jax.jit(steps_mod.make_paged_slot_prefill(
+                self.cfg, self.ecfg.max_seq), donate_argnums=(3,))
             self._slot_prefills[bucket] = fn
         return fn
 
@@ -1046,10 +1010,9 @@ class EngineCore(CohortSchedulerMixin):
         # Built on first use so a monkeypatched ``_identify`` hook (tests,
         # CHAI-static ablations) is honored.
         if self._cluster_slot is None:
-            maker = (steps_mod.make_paged_slot_cluster if self.paged
-                     else steps_mod.make_slot_cluster)
-            self._cluster_slot = jax.jit(maker(self.cfg, self._identify),
-                                         donate_argnums=(0, 1))
+            self._cluster_slot = jax.jit(
+                steps_mod.make_paged_slot_cluster(self.cfg, self._identify),
+                donate_argnums=(0, 1))
         return self._cluster_slot
 
     def _swap_fns_get(self):
@@ -1072,7 +1035,7 @@ class EngineCore(CohortSchedulerMixin):
 
     def _sample_first(self, logits, req: Request) -> int:
         """Sample a request's FIRST token from its prefill logits (count
-        0 — the same draw the cohort scheduler makes for its row)."""
+        0 — the same draw a batched decode step makes for its row)."""
         sp = req.sampling
         if sp.greedy:
             return int(np.asarray(self._argmax(logits))[0])
@@ -1137,15 +1100,15 @@ class EngineCore(CohortSchedulerMixin):
     def _eligible_snapshot(self, req):
         """The single gate for the CHAI snapshot fast path (used by the
         admit loop's replay check AND the planner — one definition, no
-        divergence): paged + cache on + clustered CHAI + a GREEDY request
+        divergence): cache on + clustered CHAI + a GREEDY request
         (replay correctness rests on greedy determinism; sampling
         requests take the block-prefix path instead). Preempted requests
         (generated tokens already emitted) never replay — their tokens
         must continue, not repeat."""
         if req.generated:
             return None
-        if (self.paged and self.prefix_cache is not None
-                and self.chai_clustered and req.sampling.greedy):
+        if (self.prefix_cache is not None and self.chai_clustered
+                and req.sampling.greedy):
             return self.prefix_cache.snapshot_for(req.prompt)
         return None
 
@@ -1171,7 +1134,10 @@ class EngineCore(CohortSchedulerMixin):
         (snapshot covers max_new_tokens entirely — host-side, no slot)
         is handled by the admit loop before planning. Preempted requests
         (``resume_state`` set) take the swap-in plan instead: fresh pages
-        matching what the slot held, restored bitwise — no prefill."""
+        matching what the slot held, restored bitwise — no prefill. A
+        model with no page pools is always admitted cold, with no pages."""
+        if self.dense_pool is None:
+            return {"kind": "cold", "pages": {}, "locked": []}
         spec = self._fault("pool.alloc", uid=req.uid)
         if spec is not None:
             if spec.mode == "error":
@@ -1350,23 +1316,15 @@ class EngineCore(CohortSchedulerMixin):
         bytes_now = self.kv_bytes()
         self._kv_peak = max(self._kv_peak, bytes_now)
         if self.tel.enabled:
-            self.tel.gauge("kv_bytes_allocated", bytes_now,
-                           help="Allocated KV bytes right now")
-            self.tel.gauge("dense_pages_in_use",
-                           self.dense_pool.pages_in_use,
-                           help="Dense-pool pages in use")
-            if self.chai_pool is not None:
-                self.tel.gauge("chai_pages_in_use",
-                               self.chai_pool.pages_in_use,
-                               help="Clustered-pool pages in use")
+            self._tel_kv_gauges(bytes_now)
         if len(self.kv_bytes_history) >= self._HISTORY_MAX:
             return
+        dense_pages, chai_pages = self._pool_pages("pages_in_use")
         rec = {
             "step": self.steps_executed,
             "kv_bytes": bytes_now,
-            "dense_pages": self.dense_pool.pages_in_use,
-            "chai_pages": (self.chai_pool.pages_in_use
-                           if self.chai_pool else 0),
+            "dense_pages": dense_pages,
+            "chai_pages": chai_pages,
         }
         if self.tiers is not None:
             tb = self.tiers.tier_bytes()
@@ -1377,24 +1335,23 @@ class EngineCore(CohortSchedulerMixin):
             rec["n_steady"] = int((phases == chai_cache.PHASE_STEADY).sum())
         self.kv_bytes_history.append(rec)
 
+    def _pool_pages(self, attr: str):
+        """(dense, clustered) pools' ``PagePool`` attribute ``attr``; 0
+        for an absent pool."""
+        return tuple(0 if pool is None else getattr(pool, attr)
+                     for pool in (self.dense_pool, self.chai_pool))
+
     def _ensure_dev_state(self):
-        """Continuous-scheduler device state, built once and kept across
-        ``step()``/``run()`` calls (paged: prefix-cache pages survive
-        between request waves; dense: retired slots rewind ``pos`` so
-        stale rows are masked like the zero tail)."""
+        """Device state, built once and kept across ``step()``/``run()``
+        calls: prefix-cache pages survive between request waves."""
         cfg, ecfg = self.cfg, self.ecfg
         b = ecfg.batch_slots
         if self._dev_state is None:
-            if self.paged:
-                self._dev_state = chai_cache.init_paged_state(
-                    cfg, b, ecfg.max_seq, page_size=ecfg.page_size,
-                    dense_pages=self.dense_pool.num_pages,
-                    chai_pages=(self.chai_pool.num_pages if self.chai_pool
-                                else 0),
-                    chai=self.chai_on)
-            else:
-                self._dev_state = chai_cache.init_unified_state(
-                    cfg, b, ecfg.max_seq, chai=self.chai_on)
+            dense_pages, chai_pages = self._pool_pages("num_pages")
+            self._dev_state = chai_cache.init_paged_state(
+                cfg, b, ecfg.max_seq, page_size=ecfg.page_size,
+                dense_pages=dense_pages, chai_pages=chai_pages,
+                chai=self.chai_on)
             self._dev_ctx = (clustering.init_batched_ctx(cfg, b)
                              if self.chai_on else None)
         return self._dev_state, self._dev_ctx
@@ -1697,8 +1654,7 @@ class EngineCore(CohortSchedulerMixin):
                 if not free_slots:      # preemption just freed a slot
                     continue
                 try:
-                    plan = (self._plan_admission(head) if self.paged
-                            else {"kind": "cold", "pages": {}, "locked": []})
+                    plan = self._plan_admission(head)
                 except RequestError as err:
                     self._quarantine_queued(head, err, outs)
                     continue
@@ -1835,15 +1791,11 @@ class EngineCore(CohortSchedulerMixin):
         else:
             toks, true_len = self._padded_prompt(prompt)
             prefill = self._slot_prefill_fn(toks.shape[1])
-            if self.paged:
-                logits, st = prefill(
-                    self.params, toks, true_len, self._dev_state,
-                    jnp.int32(i),
-                    self._page_vec(self._slot_pages[i]["kg"]),
-                    self._page_vec(self._slot_pages[i]["vg"]))
-            else:
-                logits, st = prefill(self.params, toks, true_len,
-                                     self._dev_state, jnp.int32(i))
+            # A model with no pools holds no pages: null page vectors.
+            logits, st = prefill(
+                self.params, toks, true_len, self._dev_state, jnp.int32(i),
+                self._page_vec(self._slot_pages[i].get("kg", [])),
+                self._page_vec(self._slot_pages[i].get("vg", [])))
         self._dev_state = st
         self._finish_prefill(i, req, logits)
 
@@ -1908,7 +1860,7 @@ class EngineCore(CohortSchedulerMixin):
         """Prefill completed (monolithic, or a chunked prefill's final
         chunk): index the prompt into the prefix cache, enter WARMUP, and
         sample the request's first token."""
-        if self.paged and self.prefix_cache is not None:
+        if self.prefix_cache is not None:
             self.prefix_cache.insert(req.prompt,
                                      self._slot_pages[i]["kg"],
                                      self._slot_pages[i]["vg"])
@@ -2003,8 +1955,8 @@ class EngineCore(CohortSchedulerMixin):
         """Reclaim the lowest-priority running slot that ``head``
         strictly outranks (ties never preempt). Returns True when a slot
         was preempted — the caller retries its admission plan."""
-        if not (self.ecfg.preemption and self.paged):
-            return False
+        if not self.ecfg.preemption or self.tiers is None:
+            return False    # no pools: nothing to swap a victim through
         victims = [i for i in range(self.ecfg.batch_slots)
                    if self._slot_req[i] is not None
                    and self._slot_req[i].priority < head.priority]
@@ -2014,8 +1966,7 @@ class EngineCore(CohortSchedulerMixin):
         # loses (least progress thrown away).
         i = min(victims, key=lambda j: (self._slot_req[j].priority,
                                         -self._slot_req[j].admit_step))
-        if (self.tiers is not None
-                and int(self._phases[i]) != chai_cache.PHASE_PREFILL):
+        if int(self._phases[i]) != chai_cache.PHASE_PREFILL:
             # The victim's payload lands in the host tier: make room
             # there first (compressing / dropping demoted cache entries
             # LRU-first). No room -> no preemption this step.
@@ -2123,8 +2074,8 @@ class EngineCore(CohortSchedulerMixin):
                            step=self.steps_executed)
 
     def _cluster_transitions(self, active, outs: List[StepOutput]):
-        """CLUSTER + compact slots whose warmup just completed; paged:
-        the slot's dense K pages return to the pool here. An injected
+        """CLUSTER + compact slots whose warmup just completed; the
+        slot's dense K pages return to the pool here. An injected
         ``kernel.cluster`` fault quarantines the transitioning request
         BEFORE clustering mutates the pools (``outs`` receives its typed
         StepOutput); other slots keep decoding."""
@@ -2154,34 +2105,29 @@ class EngineCore(CohortSchedulerMixin):
                                      help="WARMUP->CLUSTER->STEADY "
                                           "transitions executed")
                     self.tel.event(req.uid, "phase", phase="CLUSTER", slot=i)
-                if self.paged:
-                    kc_vec = self._page_vec(self._slot_pages[i].get("kc", []))
-                    vc_vec = self._page_vec(self._slot_pages[i].get("vc", []))
-                    self._dev_state, self._dev_ctx = self._cluster_fn()(
-                        self._dev_state, self._dev_ctx, jnp.int32(i),
-                        kc_vec, vc_vec)
-                    self._ctx_version += 1
-                    if (self.prefix_cache is not None
-                            and self.chai_clustered
-                            and self._slot_req[i].sampling.greedy):
-                        self._capture_snapshot(i, self._slot_req[i],
-                                               self._slot_pages[i])
-                    if self.chai_clustered:
-                        freed = len(self._slot_pages[i]["kg"])
-                        self.dense_pool.free(self._slot_pages[i].pop("kg"))
-                        if cfg.chai.share_values:
-                            freed += len(self._slot_pages[i]["vg"])
-                            self.dense_pool.free(self._slot_pages[i].pop("vg"))
-                        if self.tel.enabled:
-                            self.tel.counter(
-                                "chai_dense_pages_freed_total", freed,
-                                help="Dense pages freed at compaction (the "
-                                     "paper's KV saving, realized)")
-                    self._record_kv_bytes(self._phases)
-                else:
-                    self._dev_state, self._dev_ctx = self._cluster_fn()(
-                        self._dev_state, self._dev_ctx, jnp.int32(i))
-                    self._ctx_version += 1
+                kc_vec = self._page_vec(self._slot_pages[i].get("kc", []))
+                vc_vec = self._page_vec(self._slot_pages[i].get("vc", []))
+                self._dev_state, self._dev_ctx = self._cluster_fn()(
+                    self._dev_state, self._dev_ctx, jnp.int32(i),
+                    kc_vec, vc_vec)
+                self._ctx_version += 1
+                if (self.prefix_cache is not None
+                        and self.chai_clustered
+                        and self._slot_req[i].sampling.greedy):
+                    self._capture_snapshot(i, self._slot_req[i],
+                                           self._slot_pages[i])
+                if self.chai_clustered:
+                    freed = len(self._slot_pages[i]["kg"])
+                    self.dense_pool.free(self._slot_pages[i].pop("kg"))
+                    if cfg.chai.share_values:
+                        freed += len(self._slot_pages[i]["vg"])
+                        self.dense_pool.free(self._slot_pages[i].pop("vg"))
+                    if self.tel.enabled:
+                        self.tel.counter(
+                            "chai_dense_pages_freed_total", freed,
+                            help="Dense pages freed at compaction (the "
+                                 "paper's KV saving, realized)")
+                self._record_kv_bytes(self._phases)
                 self._phases[i] = chai_cache.PHASE_STEADY
                 if self.tel.enabled:
                     self.tel.event(req.uid, "phase", phase="STEADY", slot=i)
@@ -2558,9 +2504,8 @@ class EngineCore(CohortSchedulerMixin):
                     self._retire_slot(i, reason)
                 outs.append(StepOutput(r.uid, [int(toks[i])], bool(reason),
                                        reason))
-        if self.paged:
-            with tel.span("kv.account", step=step_no):
-                self._record_kv_bytes(self._phases)
+        with tel.span("kv.account", step=step_no):
+            self._record_kv_bytes(self._phases)
         return outs
 
     def _count_decode_tiles(self, program, active):
@@ -2573,8 +2518,8 @@ class EngineCore(CohortSchedulerMixin):
         one; any other slot counts its first tile only. Relay steps count
         the whole sequence, though their suffix pass starts past the
         shared prefix."""
-        if not (self.paged and self.chai_on) or program == "mha":
-            return
+        if self.dense_pool is None or not self.chai_on or program == "mha":
+            return      # no fused paged decode kernel ran
         ts, n_tiles = chai_mod.paged_decode_tiling(self.cfg,
                                                    self._dev_state)
         pos = np.zeros((self.ecfg.batch_slots,), np.int64)
@@ -2700,14 +2645,14 @@ class EngineCore(CohortSchedulerMixin):
         self._slot_req[i] = None
         self._phases[i] = chai_cache.PHASE_FREE
         self._slot_count[i] = 0
-        if index and self.paged and self.prefix_cache is not None:
+        if index and self.prefix_cache is not None:
             self._index_retired(r, self._slot_pages[i])
         self._dev_state = self._reset_slot(self._dev_state, jnp.int32(i))
-        if self.paged:      # block tables are nulled; pages go back
-            self._free_pages(self._slot_pages[i])
-            if self._slot_locked[i]:
-                self.prefix_cache.unlock(self._slot_locked[i])
-                self._slot_locked[i] = []
+        # block tables are nulled; pages go back
+        self._free_pages(self._slot_pages[i])
+        if self._slot_locked[i]:
+            self.prefix_cache.unlock(self._slot_locked[i])
+            self._slot_locked[i] = []
         self._samp_host["temperature"][i] = 0.0     # FREE slots: greedy
         self._samp_dirty = True
 
@@ -2754,7 +2699,7 @@ class EngineCore(CohortSchedulerMixin):
     def tier_stats(self):
         """Hierarchical KV tier counters: per-tier residency, transition
         totals, and prefetch hit/miss counts (None when the engine has
-        no tiers — i.e. the dense layout)."""
+        no tiers — a model with no global attention layer)."""
         if self.tiers is None:
             return None
         out = self.tiers.stats()
@@ -2767,48 +2712,32 @@ class EngineCore(CohortSchedulerMixin):
         """KV-cache bytes. With explicit ``chai=``: the paper's ANALYTIC
         steady-state size (Fig 11 A/B comparisons) — hardware-independent,
         unchanged by the engine's layout. With no argument: this engine's
-        actual footprint for the continuous scheduler —
-
-        * ``kv_layout="paged"``: allocated-page bytes right now (pages in
-          use x page bytes + the non-paged local rings). This falls when
-          a slot's dense pages are freed at compaction, so steady-state
-          CHAI reports LESS than the dense-MHA rectangle — the paper's
-          saving realized by the allocator. ``kv_bytes_history`` records
-          the trajectory; ``kv_bytes_capacity()`` gives the pools' total
-          reservation.
-        * ``kv_layout="dense"``: the unified layout's constant residency
-          (dense + clustered rectangles side by side — MORE than plain
-          MHA; this over-count is what the paged layout removes).
-        """
-        if chai is None and self.ecfg.scheduler == "continuous":
-            if self.paged:
-                return chai_cache.paged_kv_bytes(
-                    self.cfg, self.ecfg.page_size,
-                    self.dense_pool.pages_in_use,
-                    self.chai_pool.pages_in_use if self.chai_pool else 0,
-                    batch=self.ecfg.batch_slots, max_seq=self.ecfg.max_seq)
-            return chai_cache.unified_kv_bytes(
-                self.cfg, self.ecfg.batch_slots, self.ecfg.max_seq,
-                chai=self.chai_on)
-        chai = self.chai_on if chai is None else chai
+        allocated bytes right now (pages in use x page bytes + the
+        non-paged local rings). This falls when a slot's dense pages are
+        freed at compaction, so steady-state CHAI reports LESS than the
+        dense-MHA rectangle — the paper's saving realized by the
+        allocator. ``kv_bytes_history`` records the trajectory;
+        ``kv_bytes_capacity()`` gives the pools' total reservation. A
+        model with no pools reads its local rings only (0 without
+        them)."""
+        if chai is None:
+            return chai_cache.paged_kv_bytes(
+                self.cfg, self.ecfg.page_size,
+                *self._pool_pages("pages_in_use"),
+                batch=self.ecfg.batch_slots, max_seq=self.ecfg.max_seq)
         return chai_cache.kv_cache_bytes(
             self.cfg, self.ecfg.batch_slots, self.ecfg.max_seq, chai=chai)
 
     def kv_bytes_peak(self):
-        """Paged: high-water allocated bytes over the run (O(1): a
-        running maximum, not a history scan)."""
-        if not self.paged:
-            return 0
+        """High-water allocated bytes over the run (O(1): a running
+        maximum, not a history scan)."""
         return max(self._kv_peak, self.kv_bytes())
 
     def kv_bytes_capacity(self):
-        """Paged: bytes if every pool page were in use (the device-side
-        reservation); dense layouts: the resident footprint."""
-        if not self.paged:
-            return self.kv_bytes()
+        """Bytes if every pool page were in use (the device-side
+        reservation)."""
         return chai_cache.paged_kv_bytes(
-            self.cfg, self.ecfg.page_size, self.dense_pool.capacity,
-            self.chai_pool.capacity if self.chai_pool else 0,
+            self.cfg, self.ecfg.page_size, *self._pool_pages("capacity"),
             batch=self.ecfg.batch_slots, max_seq=self.ecfg.max_seq)
 
     def throughput(self):
@@ -2838,8 +2767,6 @@ class ServingEngine(EngineCore):
 
     def run(self):
         """Drain the queue; returns completed requests."""
-        if self.ecfg.scheduler == "cohort":
-            return self._run_cohort_loop()
         while self.has_work():
             outs = self.step()
             if not outs and not self.has_active and self.queue:

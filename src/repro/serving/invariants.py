@@ -176,7 +176,7 @@ def _audit_phases(core, out: List[str]):
             if core._slot_count[i]:
                 out.append(f"slot {i}: empty but count "
                            f"{core._slot_count[i]}")
-            if core.paged and core._slot_pages[i]:
+            if core._slot_pages[i]:
                 out.append(f"slot {i}: empty but holds pages "
                            f"{sorted(core._slot_pages[i])}")
             if core._slot_locked[i]:
@@ -266,17 +266,15 @@ def audit(core, *, deep: bool = False) -> List[str]:
     """Audit one ``EngineCore``; returns violation strings (empty =
     clean). Safe to call between steps at any time."""
     out: List[str] = []
-    if getattr(core, "_slot_req", None) is None:
-        return out          # cohort engines carry no slot machinery
-    if core.paged:
-        _audit_pool("dense_pool", core.dense_pool, out)
-        if core.chai_pool is not None:
-            _audit_pool("chai_pool", core.chai_pool, out)
-        _audit_refs(core, out)
-        _audit_tiers(core, out)
+    for name, pool in (("dense_pool", core.dense_pool),
+                       ("chai_pool", core.chai_pool)):
+        if pool is not None:
+            _audit_pool(name, pool, out)
+    _audit_refs(core, out)
+    _audit_tiers(core, out)
     _audit_phases(core, out)
     _audit_locks(core, out)
-    if deep and core.paged:
+    if deep:
         _audit_device(core, out)
     return out
 

@@ -4,16 +4,15 @@
 engine: temperature / top-k / top-p with a per-request PRNG seed, stop
 token ids, stop strings (matched against a detokenizer the engine owns),
 and the generation budget. The device-side sampler itself lives in
-``repro.launch.steps.make_sampler`` — one batched jit shared by both
-schedulers — and draws its key as ``fold_in(PRNGKey(seed), n)`` where
-``n`` is the number of tokens the REQUEST has sampled so far, never the
-slot id or engine step. That makes seeded runs reproducible across the
-continuous and cohort schedulers (and across slot placements / restarts):
-token ``n`` of a request depends only on ``(seed, n, logits)``.
+``repro.launch.steps.make_sampler`` — one batched jit — and draws its
+key as ``fold_in(PRNGKey(seed), n)`` where ``n`` is the number of tokens
+the REQUEST has sampled so far, never the slot id or engine step. That
+makes seeded runs reproducible across slot placements, batch sizes and
+restarts: token ``n`` of a request depends only on ``(seed, n, logits)``.
 
 ``temperature == 0`` is greedy decode, bit-identical to the engine's
 historical ``argmax`` path — CHAI snapshot capture/replay and every
-cross-layout parity guarantee key on it (``SamplingParams.greedy``).
+batched-vs-solo parity guarantee key on it (``SamplingParams.greedy``).
 """
 from __future__ import annotations
 
@@ -38,7 +37,7 @@ class SamplingParams:
                  descending-probability vocab whose mass reaches top_p
                  (1.0 = off). Applied after top_k.
     seed         per-request PRNG seed; token n draws from
-                 fold_in(PRNGKey(seed), n) — scheduler-independent.
+                 fold_in(PRNGKey(seed), n) — placement-independent.
     stop_token_ids  finish ("stop") when the last sampled token is one
                  of these; the stop token is kept in the output.
     stop         stop strings, matched against the engine detokenizer's
@@ -123,8 +122,7 @@ def scan_finish(token_ids: Sequence[int], params: SamplingParams,
                 detokenizer: Optional[Callable] = None
                 ) -> Tuple[List[int], str]:
     """Scan a token list from the front and truncate at the FIRST finish
-    condition — the batch-append path (snapshot replay, cohort lockstep
-    output) must land on exactly the tokens the incremental per-token
+    condition — the batch-append path (snapshot replay) must land on exactly the tokens the incremental per-token
     check would have kept. Returns (possibly-truncated tokens, reason);
     reason is "" only when no condition has triggered yet."""
     out: List[int] = []

@@ -3,8 +3,9 @@
 One definition of the "mixed-length Poisson" workload so the latency
 benchmark and the serving example measure the same distribution:
 exponential inter-arrival gaps and a long-tailed output-length mix —
-most requests short, a minority near the cap, the regime where cohort
-scheduling head-of-line blocks short requests behind long ones.
+most requests short, a minority near the cap, the regime where lockstep
+(cohort) scheduling would head-of-line block short requests behind long
+ones.
 """
 from __future__ import annotations
 

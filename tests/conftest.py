@@ -45,8 +45,6 @@ def _page_leak_gate(request):
     from repro.serving import invariants
     problems = []
     for core in cores:
-        if getattr(core, "_slot_req", None) is None or not core.paged:
-            continue        # cohort / dense layouts: nothing paged
         for v in invariants.audit_leaks(core):
             problems.append(v)
     assert not problems, (

@@ -1,5 +1,6 @@
-"""Unified per-slot KV layout: quantization round-trips and per-slot
-compaction parity with the whole-batch gather."""
+"""Per-slot paged KV state: quantization round-trips, per-slot
+compaction parity with the cohort flow's whole-batch gather, and the
+insert/reset round trip of one slot's state."""
 import numpy as np
 import pytest
 
@@ -46,70 +47,6 @@ def _mha_cfg(share_values, int8):
         cfg = cfg.replace(kv_cache_dtype="int8")
     return cfg.with_chai(enabled=True, share_values=share_values,
                          cluster_counts=(3,) * cfg.n_attn_layers)
-
-
-@pytest.mark.parametrize("share_values", [False, True])
-@pytest.mark.parametrize("int8", [False, True])
-def test_compact_kv_slot_matches_whole_batch(rng, share_values, int8):
-    """Per-slot donated gather == the cohort path's whole-batch
-    ``compact_kv`` for every share_values / int8 cache combination."""
-    cfg = _mha_cfg(share_values, int8)
-    b, s = 3, 16
-    dense = init_decode_state(cfg, b, s)
-    for k in dense:
-        if k == "pos":
-            continue
-        if dense[k].dtype == jnp.int8:
-            dense[k] = jnp.asarray(
-                rng.integers(-127, 128, size=dense[k].shape), jnp.int8)
-        else:
-            dense[k] = jnp.asarray(rng.normal(size=dense[k].shape),
-                                   dense[k].dtype)
-    k_max, _ = clustering.chai_widths(cfg)
-    reps = jnp.asarray(
-        rng.integers(0, cfg.n_heads, size=(cfg.n_attn_layers, b, k_max)),
-        jnp.int32)
-
-    whole = chai_cache.compact_kv(dict(dense), {"reps": reps}, cfg)
-
-    unified = chai_cache.init_unified_state(cfg, b, s)
-    for k, v in dense.items():
-        unified[k] = v
-    compact = jax.jit(chai_cache.compact_kv_slot,
-                      static_argnames=("cfg",), donate_argnums=(0,))
-    for i in range(b):
-        slot_ctx = {"reps": reps[:, i]}
-        unified = compact(unified, slot_ctx, cfg, jnp.int32(i))
-
-    for key in ("kg_chai", "kg_chai_scale", "vg_chai"):
-        if key in whole:
-            np.testing.assert_array_equal(np.asarray(whole[key]),
-                                          np.asarray(unified[key]), key)
-    # phase machine advanced every slot to STEADY
-    assert (np.asarray(unified["phase"]) == chai_cache.PHASE_STEADY).all()
-    # unified layout: the dense cache stays resident for warmup slots
-    assert "kg" in unified and "kg" not in whole
-
-
-@pytest.mark.parametrize("share_values", [False, True])
-@pytest.mark.parametrize("int8", [False, True])
-def test_unified_kv_bytes_accounts_both_layouts(share_values, int8):
-    """The unified layout is honest about its cost: resident bytes =
-    dense cache + the clustered extension (MORE than dense alone; the
-    21.4%-style saving is the cohort/steady-state analytic number)."""
-    cfg = _mha_cfg(share_values, int8)
-    b, s = 2, 32
-    dense = chai_cache.kv_cache_bytes(cfg, b, s, chai=False)
-    unified = chai_cache.unified_kv_bytes(cfg, b, s)
-    assert unified > dense
-    # exact: sum of the layout's own KV buffers
-    shapes, _ = chai_cache.unified_state_structs(cfg, b, s)
-    expect = sum(int(np.prod(st.shape)) * st.dtype.itemsize
-                 for k, st in shapes.items()
-                 if k not in ("pos", "phase", "chai_scores"))
-    assert unified == expect
-    # without CHAI the unified layout reduces to the dense cache
-    assert chai_cache.unified_kv_bytes(cfg, b, s, chai=False) == dense
 
 
 @pytest.mark.parametrize("share_values", [False, True])
@@ -191,28 +128,73 @@ def test_compact_kv_slot_paged_matches_whole_batch(rng, share_values, int8):
     assert (np.asarray(paged["phase"]) == chai_cache.PHASE_STEADY).all()
 
 
-def test_insert_and_reset_slot_roundtrip(rng):
-    """insert_slot writes one request's prefill into a slot (phase ->
-    WARMUP, scores cleared); reset_slot frees it (phase -> FREE, pos 0);
-    other slots are untouched."""
-    cfg = _mha_cfg(False, False)
-    b, s = 2, 16
-    state = chai_cache.init_unified_state(cfg, b, s)
-    state["chai_scores"] = jnp.ones_like(state["chai_scores"])
+@pytest.mark.parametrize("arch", ["gemma2-9b", "recurrentgemma-9b",
+                                  "rwkv6-1.6b"])
+def test_insert_and_reset_slot_paged_roundtrip(rng, arch):
+    """``insert_slot_paged`` lands one prefilled request's ring or
+    recurrent state (and, with a global layer, its K/V pages and block
+    tables) in its slot — phase WARMUP, warmup scores cleared — and
+    leaves every other slot and page bit for bit; ``reset_slot_paged``
+    frees the slot (phase FREE, pos 0, block tables null)."""
+    cfg = reduced(get_config(arch), n_layers=2, d_model=32, d_ff=64,
+                  vocab=64).replace(dtype="float32").with_chai(enabled=True)
+    b, s, page = 3, 32, 8
+    n_slot = s // page
+    state = chai_cache.init_paged_state(cfg, b, s, page_size=page,
+                                        dense_pages=2 * b * n_slot + 1)
+    assert ("kvp" in state) == (cfg.n_global_layers > 0)
+    for k, v in state.items():
+        if k.startswith("bt_") or k in ("pos", "phase"):
+            continue
+        state[k] = jnp.asarray(rng.normal(size=v.shape), v.dtype)
+    state["pos"] = jnp.asarray([3, 5, 9], jnp.int32)
+    state["phase"] = jnp.full((b,), chai_cache.PHASE_STEADY, jnp.int32)
     mini = init_decode_state(cfg, 1, s)
-    mini["kg"] = jnp.asarray(rng.normal(size=mini["kg"].shape),
-                             mini["kg"].dtype)
+    for k, v in mini.items():
+        mini[k] = jnp.asarray(rng.normal(size=v.shape), v.dtype)
     mini["pos"] = jnp.full((1,), 7, jnp.int32)
+    pages = np.zeros((n_slot,), np.int32)
+    if "kvp" in state:
+        pages[:] = np.arange(1, n_slot + 1)
+    vpages = np.where(pages > 0, pages + n_slot, 0).astype(np.int32)
 
-    out = chai_cache.insert_slot(state, mini, 1)
-    np.testing.assert_array_equal(np.asarray(out["kg"][:, 1]),
-                                  np.asarray(mini["kg"][:, 0]))
-    assert (np.asarray(out["kg"][:, 0]) == 0).all()      # slot 0 untouched
-    assert int(out["pos"][1]) == 7 and int(out["pos"][0]) == 0
+    out = chai_cache.insert_slot_paged(state, mini, 1, jnp.asarray(pages),
+                                       jnp.asarray(vpages))
+    for k, v in out.items():
+        new, old = np.asarray(v), np.asarray(state[k])
+        if k in ("kvp", "kvp_scale"):
+            continue
+        axis = 0 if new.ndim == 1 or k.startswith("bt_") else 1
+        for i in (0, 2):                 # other slots: bit for bit
+            np.testing.assert_array_equal(np.take(new, i, axis),
+                                          np.take(old, i, axis), k)
+        if k in mini:                    # the request's slot
+            np.testing.assert_array_equal(
+                np.take(new, 1, axis), np.take(np.asarray(mini[k]), 0, axis),
+                k)
     assert int(out["phase"][1]) == chai_cache.PHASE_WARMUP
-    assert (np.asarray(out["chai_scores"][:, 1]) == 0).all()
-    assert (np.asarray(out["chai_scores"][:, 0]) == 1).all()
+    if "chai_scores" in out:            # rwkv6: no attention to cluster
+        assert (np.asarray(out["chai_scores"][:, 1]) == 0).all()
+    if "kvp" in out:
+        pool, old = np.asarray(out["kvp"]), np.asarray(state["kvp"])
+        ng, _, kv, _, hd = pool.shape
+        for key, pg in (("kg", pages), ("vg", vpages)):
+            rows = np.asarray(mini[key])[:, 0].reshape(ng, kv, n_slot, page,
+                                                       hd)
+            np.testing.assert_array_equal(pool[:, pg],
+                                          rows.transpose(0, 2, 1, 3, 4))
+        untouched = np.setdiff1d(np.arange(pool.shape[1]),
+                                 np.concatenate([pages, vpages]))
+        np.testing.assert_array_equal(pool[:, untouched], old[:, untouched])
+        np.testing.assert_array_equal(np.asarray(out["bt_kg"][1]), pages)
+        np.testing.assert_array_equal(np.asarray(out["bt_vg"][1]), vpages)
 
-    out = chai_cache.reset_slot(out, 1)
-    assert int(out["phase"][1]) == chai_cache.PHASE_FREE
-    assert int(out["pos"][1]) == 0
+    out2 = chai_cache.reset_slot_paged(out, 1)
+    assert int(out2["phase"][1]) == chai_cache.PHASE_FREE
+    assert int(out2["pos"][1]) == 0
+    for k in [k for k in out2 if k.startswith("bt_")] + ["pos", "phase"]:
+        if k.startswith("bt_"):
+            assert (np.asarray(out2[k][1]) == chai_cache.NULL_PAGE).all(), k
+        for i in (0, 2):
+            np.testing.assert_array_equal(np.asarray(out2[k][i]),
+                                          np.asarray(out[k][i]), k)

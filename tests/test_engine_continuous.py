@@ -1,9 +1,13 @@
-"""Continuous-batching engine: scheduler parity + slot lifecycle.
+"""Continuous-batching engine: batched-vs-solo parity + slot lifecycle.
 
-(a) Token-for-token parity between ``scheduler="continuous"`` and
-    ``scheduler="cohort"`` on greedy decode, across an MHA arch (clustered
-    K cache) and a GQA arch (compute-only saving) — the per-slot phase
-    machine must reproduce the lockstep cohort path exactly.
+(a) Token-for-token greedy parity between requests served together on
+    several slots and the same requests served alone, one at a time on
+    an engine of the same slot count, across an MHA arch (clustered K
+    cache) and a GQA arch (compute-only saving) — neither the phase mix
+    of the other slots nor a slot's placement may change a token. (The
+    slot count stays: a batch of another size compiles other CPU matmul
+    kernels, whose last-bit differences can flip CHAI's choice between
+    two heads equidistant from their cluster's centroid.)
 (b) A short request admitted beside a long one retires early and its slot
     is reused by a queued request while the long one is still running —
     the head-of-line-blocking fix the scheduler exists for.
@@ -28,16 +32,19 @@ def _cfg(arch, **chai_kw):
     return cfg.with_chai(enabled=True, warmup_tokens=3, **chai_kw)
 
 
-def _run(cfg, scheduler, submissions, *, use_chai=True, slots=2,
-         max_seq=64):
+def _run(cfg, submissions, *, use_chai=True, slots=2, max_seq=64,
+         alone=False):
+    """Serve ``submissions`` together, or with ``alone`` each by itself:
+    one ``run()`` per request, the engine's other slots FREE."""
     params = tfm.init_params(cfg, jax.random.PRNGKey(0))
     eng = ServingEngine(cfg, params,
                         EngineConfig(batch_slots=slots, max_seq=max_seq,
-                                     scheduler=scheduler,
                                      use_chai=use_chai))
-    for i, (prompt, max_new) in enumerate(submissions):
-        eng.submit(prompt, max_new_tokens=max_new, uid=i)
-    done = eng.run()
+    subs = list(enumerate(submissions))
+    for wave in ([[s] for s in subs] if alone else [subs]):
+        for i, (prompt, max_new) in wave:
+            eng.submit(prompt, max_new_tokens=max_new, uid=i)
+        done = eng.run()
     assert len(done) == len(submissions)
     return {r.uid: r for r in done}, eng
 
@@ -52,15 +59,16 @@ def _submissions(cfg, n_req=5, prompt_len=8, seed=0):
 @pytest.mark.slow
 @pytest.mark.parametrize("arch", [MHA_ARCH, GQA_ARCH])
 def test_greedy_parity_continuous_vs_cohort(arch):
-    """Identical greedy tokens per request under both schedulers, through
-    all phases (warmup_tokens=3 < several max_new): PREFILL/WARMUP/
-    CLUSTER/STEADY all exercised."""
+    """Identical greedy tokens per request served batched (phases
+    interleaved across the slots) and alone (no other request in the
+    batch), through all phases (warmup_tokens=3 < several max_new):
+    PREFILL/WARMUP/CLUSTER/STEADY all exercised."""
     cfg = _cfg(arch)
     subs = _submissions(cfg)
-    cont, eng = _run(cfg, "continuous", subs)
-    coh, _ = _run(cfg, "cohort", subs)
-    for uid in coh:
-        assert cont[uid].generated == coh[uid].generated, uid
+    cont, eng = _run(cfg, subs)
+    solo, _ = _run(cfg, subs, alone=True)
+    for uid in solo:
+        assert cont[uid].generated == solo[uid].generated, uid
         assert len(cont[uid].generated) == subs[uid][1]
     # slot scheduling actually interleaved phases (not one-at-a-time)
     assert eng.steps_executed < sum(m for _, m in subs)
@@ -68,14 +76,14 @@ def test_greedy_parity_continuous_vs_cohort(arch):
 
 @pytest.mark.slow
 def test_greedy_parity_without_chai():
-    """use_chai=False: the continuous scheduler reduces to plain MHA
-    continuous decode and still matches the cohort path."""
+    """use_chai=False: the engine reduces to plain MHA continuous decode
+    and batched requests still match the same requests served alone."""
     cfg = _cfg(MHA_ARCH)
     subs = _submissions(cfg, n_req=4)
-    cont, _ = _run(cfg, "continuous", subs, use_chai=False)
-    coh, _ = _run(cfg, "cohort", subs, use_chai=False)
-    for uid in coh:
-        assert cont[uid].generated == coh[uid].generated, uid
+    cont, _ = _run(cfg, subs, use_chai=False)
+    solo, _ = _run(cfg, subs, use_chai=False, alone=True)
+    for uid in solo:
+        assert cont[uid].generated == solo[uid].generated, uid
 
 
 def test_short_request_retires_early_and_slot_is_reused():
@@ -85,7 +93,7 @@ def test_short_request_retires_early_and_slot_is_reused():
     subs = [(prompts[0], 24),   # long: holds its slot for 24 tokens
             (prompts[1], 4),    # short: retires after 4
             (prompts[2], 4)]    # queued: must reuse the short one's slot
-    done, eng = _run(cfg, "continuous", subs, slots=2)
+    done, eng = _run(cfg, subs, slots=2)
     long_req, short_req, queued = done[0], done[1], done[2]
     assert short_req.retire_step < long_req.retire_step
     assert queued.slot == short_req.slot
@@ -100,18 +108,20 @@ def test_short_request_retires_early_and_slot_is_reused():
 
 
 def test_phase_vector_tracks_slot_lifecycle():
-    """The unified state's per-slot phase vector drives the machine:
+    """The paged state's per-slot phase vector drives the machine:
     zero-init state is all FREE; constants are ordered FREE < PREFILL <
     WARMUP < CLUSTER < STEADY (the mixed step's mask relies on it)."""
     assert (chai_cache.PHASE_FREE < chai_cache.PHASE_PREFILL
             < chai_cache.PHASE_WARMUP < chai_cache.PHASE_CLUSTER
             < chai_cache.PHASE_STEADY)
     cfg = _cfg(MHA_ARCH)
-    state = chai_cache.init_unified_state(cfg, 2, 16)
+    state = chai_cache.init_paged_state(cfg, 2, 16, page_size=8,
+                                        dense_pages=9, chai_pages=5)
     assert state["phase"].shape == (2,)
     assert (np.asarray(state["phase"]) == chai_cache.PHASE_FREE).all()
-    # unified layout: dense and clustered K caches resident side by side
-    assert "kg" in state and "kg_chai" in state and "chai_scores" in state
+    # dense and clustered K pools side by side, no dense rectangles
+    assert "kvp" in state and "cp" in state and "chai_scores" in state
+    assert "kg" not in state and "kg_chai" not in state
 
 
 def test_prompt_bucket_rounding():
@@ -126,86 +136,39 @@ def test_prompt_bucket_rounding():
 
 @pytest.mark.slow
 def test_prefill_jit_bucketing_compiles_per_bucket_not_per_length():
-    """Regression: BOTH schedulers must key one prefill jit per
-    power-of-two prompt BUCKET (tail masked), not per exact/padded
-    length — and the padded prefill must not change a single greedy
-    token. The cohort scheduler reuses the continuous scheduler's
-    bucketing (right-pad + per-example ``true_lens``), so single-request
-    cohorts are numerically exact references for the continuous path."""
+    """Regression: the engine keys one prefill jit per power-of-two
+    prompt BUCKET (tail masked), not per exact/padded length — served
+    batched or one request at a time — and the padded prefill does not
+    change a single greedy token of the batched run against the same
+    requests served alone."""
     cfg = _cfg(MHA_ARCH)
     rng = np.random.default_rng(3)
     lengths = [3, 5, 6, 7, 9, 12]          # buckets: {4, 8, 8, 8, 16, 16}
     subs = [(rng.integers(0, cfg.vocab_size, size=t), 8) for t in lengths]
-    cont, eng = _run(cfg, "continuous", subs)
+    cont, eng = _run(cfg, subs)
     assert set(eng._slot_prefills) == {4, 8, 16}
     assert len(eng._slot_prefills) == 3    # O(log max_seq), not 6
-    coh, eng_coh = _run(cfg, "cohort", subs, slots=1)
-    for uid in coh:
-        assert cont[uid].generated == coh[uid].generated, uid
-    # cohort prefill no longer retraces per padded cohort length: one jit
-    # whose shape cache is keyed by the pow2 bucket set (3 compiles, not
-    # one per distinct prompt length)
-    assert eng_coh._cohort_buckets == {4, 8, 16}
-    assert eng_coh._prefill._cache_size() == 3
-
-
-@pytest.mark.slow
-def test_cohort_ragged_prefill_matches_single_cohorts():
-    """Ragged cohorts (mixed prompt lengths admitted together) right-pad
-    to one bucket with per-example masking — tokens must match the same
-    requests run in single-request cohorts (no cross-contamination from
-    padding)."""
-    cfg = _cfg(MHA_ARCH)
-    rng = np.random.default_rng(5)
-    lengths = [5, 9, 12, 7]
-    subs = [(rng.integers(0, cfg.vocab_size, size=t), 6) for t in lengths]
-    ragged, eng = _run(cfg, "cohort", subs, slots=4)   # one ragged cohort
-    assert eng._cohort_buckets == {16}                 # one bucket shape
-    single, _ = _run(cfg, "cohort", subs, slots=1)
-    for uid in single:
-        assert ragged[uid].generated == single[uid].generated, uid
-
-
-def test_cohort_redispatch_regenerates_cleanly():
-    """Regression: a request re-dispatched after a blown cohort deadline
-    restarts from its prompt — partial tokens from the aborted attempt
-    are dropped, so the final output equals an uninterrupted run (the
-    old behaviour appended the fresh decode onto the stale prefix)."""
-    cfg = _cfg(MHA_ARCH)
-    rng = np.random.default_rng(9)
-    prompt = rng.integers(0, cfg.vocab_size, size=8)
-    clean, _ = _run(cfg, "cohort", [(prompt, 8)], slots=1)
-
-    import jax as _jax
-    from repro.models import transformer as _tfm
-    params = _tfm.init_params(cfg, _jax.random.PRNGKey(0))
-    eng = ServingEngine(cfg, params,
-                        EngineConfig(batch_slots=1, max_seq=64,
-                                     scheduler="cohort",
-                                     cohort_deadline_s=0.0))
-    req = eng.submit(prompt, max_new_tokens=8, uid=0)
-    try:        # deadline 0: times out mid-cohort, leaving partial tokens
-        eng._run_cohort([req])
-    except TimeoutError:
-        pass
-    assert req.generated                       # the stale partial prefix
-    eng.ecfg.cohort_deadline_s = 300.0
-    done = eng.run()
-    assert len(done) == 1
-    assert done[0].generated == clean[0].generated
+    solo, eng_solo = _run(cfg, subs, alone=True)
+    for uid in solo:
+        assert cont[uid].generated == solo[uid].generated, uid
+    # each bucket's jit compiled once (3 compiles, not one per distinct
+    # prompt length)
+    assert set(eng_solo._slot_prefills) == {4, 8, 16}
+    assert all(fn._cache_size() == 1
+               for fn in eng_solo._slot_prefills.values())
 
 
 @pytest.mark.slow
 def test_mixed_workload_throughput_beats_cohort():
     """Mixed-length workload: continuous batching needs strictly fewer
-    batched decode steps than the cohort scheduler (the step count is the
-    hardware-independent throughput proxy; bench_latency measures wall
-    time)."""
+    batched decode steps than lockstep cohorts of the same size would
+    (the step count is the hardware-independent throughput proxy;
+    bench_latency measures wall time)."""
     cfg = _cfg(MHA_ARCH)
     rng = np.random.default_rng(2)
     subs = [(rng.integers(0, cfg.vocab_size, size=8), int(m))
             for m in rng.integers(4, 25, size=6)]
-    _, eng_cont = _run(cfg, "continuous", subs, slots=2, max_seq=64)
+    _, eng_cont = _run(cfg, subs, slots=2, max_seq=64)
     # cohort lower bound on decode steps: each cohort runs max(max_new)
     sizes = [m for _, m in subs]
     cohort_steps = sum(max(sizes[i:i + 2]) for i in range(0, len(sizes), 2))

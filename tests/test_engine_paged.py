@@ -1,10 +1,11 @@
-"""Paged KV cache: allocator behaviour + paged-vs-dense engine parity.
+"""Paged KV cache: allocator behaviour + batched-vs-alone engine parity.
 
-The paged layout must be a pure layout change: greedy tokens identical to
-the dense unified layout (and the cohort scheduler) for every arch/flag
-combination, while the page allocator realizes CHAI's memory saving —
-dense K pages return to the pool at compaction, admission is page-budget
-gated, and nothing leaks across slot churn.
+Sharing the page pools must not change a token: requests served together
+decode exactly as the same requests served alone (one at a time on the
+same slots) for every arch/flag combination, while the page allocator
+realizes CHAI's memory saving — dense K pages return to the pool at
+compaction, admission is page-budget gated, and nothing leaks across
+slot churn.
 """
 import numpy as np
 import pytest
@@ -28,18 +29,20 @@ def _cfg(arch, **chai_kw):
     return cfg.with_chai(enabled=True, warmup_tokens=3, **chai_kw)
 
 
-def _run(cfg, submissions, *, scheduler="continuous", kv_layout="paged",
-         use_chai=True, slots=2, max_seq=64, **ecfg_kw):
+def _run(cfg, submissions, *, use_chai=True, slots=2, max_seq=64,
+         alone=False, **ecfg_kw):
+    """Serve ``submissions`` together, or with ``alone`` each by itself:
+    one ``run()`` per request, the engine's other slots FREE."""
     params = tfm.init_params(cfg, jax.random.PRNGKey(0))
     eng = ServingEngine(cfg, params,
                         EngineConfig(batch_slots=slots, max_seq=max_seq,
-                                     scheduler=scheduler,
-                                     kv_layout=kv_layout,
                                      use_chai=use_chai, page_size=16,
                                      **ecfg_kw))
-    for i, (prompt, max_new) in enumerate(submissions):
-        eng.submit(prompt, max_new_tokens=max_new, uid=i)
-    done = eng.run()
+    subs = list(enumerate(submissions))
+    for wave in ([[s] for s in subs] if alone else [subs]):
+        for i, (prompt, max_new) in wave:
+            eng.submit(prompt, max_new_tokens=max_new, uid=i)
+        done = eng.run()
     assert len(done) == len(submissions)
     return {r.uid: r for r in done}, eng
 
@@ -141,16 +144,15 @@ def test_paged_kv_bytes_accounting():
 @pytest.mark.slow
 @pytest.mark.parametrize("arch", [MHA_ARCH, GQA_ARCH])
 def test_paged_greedy_parity(arch):
-    """Token-for-token parity: paged continuous == dense continuous ==
-    cohort, through PREFILL/WARMUP/CLUSTER/STEADY phase mixes."""
+    """Token-for-token parity: requests sharing the pools == the same
+    requests served alone, through PREFILL/WARMUP/CLUSTER/STEADY phase
+    mixes."""
     cfg = _cfg(arch)
     subs = _submissions(cfg, lens=(12, 5, 9, 12, 7))
-    paged, engp = _run(cfg, subs, kv_layout="paged")
-    dense, _ = _run(cfg, subs, kv_layout="dense")
-    cohort, _ = _run(cfg, subs, scheduler="cohort")
-    for uid in dense:
-        assert paged[uid].generated == dense[uid].generated, uid
-        assert paged[uid].generated == cohort[uid].generated, uid
+    paged, engp = _run(cfg, subs)
+    alone, _ = _run(cfg, subs, alone=True)
+    for uid in alone:
+        assert paged[uid].generated == alone[uid].generated, uid
     # every page went home
     assert engp.dense_pool.pages_in_use == 0
     if engp.chai_pool is not None:
@@ -160,33 +162,30 @@ def test_paged_greedy_parity(arch):
 @pytest.mark.slow
 @pytest.mark.parametrize("share_values", [False, True])
 def test_paged_parity_int8_and_shared_values(share_values):
-    """The int8 scale pools and the clustered-V pages reproduce the dense
-    layout's numerics exactly."""
+    """The int8 scale pools and the clustered-V pages keep each
+    request's numerics whatever else shares the pools: batched ==
+    alone."""
     cfg = _cfg(MHA_ARCH, share_values=share_values).replace(
         kv_cache_dtype="int8")
     subs = _submissions(cfg, lens=(10, 6, 8))
-    paged, engp = _run(cfg, subs, kv_layout="paged")
-    dense, _ = _run(cfg, subs, kv_layout="dense")
-    for uid in dense:
-        assert paged[uid].generated == dense[uid].generated, uid
+    paged, engp = _run(cfg, subs)
+    alone, _ = _run(cfg, subs, alone=True)
+    for uid in alone:
+        assert paged[uid].generated == alone[uid].generated, uid
     assert engp.dense_pool.pages_in_use == 0
     assert engp.chai_pool.pages_in_use == 0
 
 
 @pytest.mark.slow
 def test_paged_parity_int8_gqa_dense_layout_carries_scales():
-    """Regression for the legacy dense-GQA int8 corner: the dense layout
-    now gathers real per-row scales exactly like the paged path, so
-    paged-vs-dense greedy parity holds for GQA int8 too (it could not
-    before — dense stored reinterpreted codes with no scales)."""
+    """GQA int8: the dense pool's per-row scales travel with each
+    slot's pages, so greedy tokens batched == alone."""
     cfg = _cfg(GQA_ARCH).replace(kv_cache_dtype="int8")
     subs = _submissions(cfg, lens=(10, 6, 8))
-    paged, engp = _run(cfg, subs, kv_layout="paged")
-    dense, _ = _run(cfg, subs, kv_layout="dense")
-    cohort, _ = _run(cfg, subs, scheduler="cohort")
-    for uid in dense:
-        assert paged[uid].generated == dense[uid].generated, uid
-        assert paged[uid].generated == cohort[uid].generated, uid
+    paged, engp = _run(cfg, subs)
+    alone, _ = _run(cfg, subs, alone=True)
+    for uid in alone:
+        assert paged[uid].generated == alone[uid].generated, uid
     assert engp.dense_pool.pages_in_use == 0
 
 
@@ -200,9 +199,9 @@ def test_exhausted_pool_queues_admission_then_reuses_pages():
     cfg = _cfg(MHA_ARCH)
     subs = _submissions(cfg, lens=(8, 8, 8, 8, 8), prompt_len=8)
     need = chai_cache.pages_needed(8 + 8, 16)
-    tight, engt = _run(cfg, subs, kv_layout="paged",
-                       num_pages=2 * need + 1, num_chai_pages=need + 1)
-    roomy, engr = _run(cfg, subs, kv_layout="paged")
+    tight, engt = _run(cfg, subs, num_pages=2 * need + 1,
+                       num_chai_pages=need + 1)
+    roomy, engr = _run(cfg, subs)
     for uid in roomy:
         assert tight[uid].generated == roomy[uid].generated, uid
     # page-budget admission actually serialized the tight run: with pages
@@ -225,9 +224,8 @@ def test_oversized_request_raises_memory_error():
     params = tfm.init_params(cfg, jax.random.PRNGKey(0))
     eng = ServingEngine(cfg, params,
                         EngineConfig(batch_slots=2, max_seq=64,
-                                     kv_layout="paged", page_size=16,
-                                     num_pages=3))
-    # beyond the KV capacity entirely: rejected at submit, any layout
+                                     page_size=16, num_pages=3))
+    # beyond the KV capacity entirely: rejected at submit
     with pytest.raises(ValueError):
         eng.submit(np.zeros(40, np.int32), max_new_tokens=40)
     # fits max_seq but not this (deliberately tiny) pool: page-budget
@@ -248,32 +246,30 @@ def test_fused_decode_greedy_parity_with_jnp_reference(monkeypatch, arch,
                                                        chai_kw, cfg_kw):
     """End-to-end acceptance: the fused one-launch decode produces
     token-for-token greedy parity with the pre-fusion jnp math across
-    phase mixes, layouts, int8 and share_values."""
+    phase mixes, int8 and share_values."""
     from repro.core import chai_attention as chai_core
     cfg = _cfg(arch, **chai_kw).replace(**cfg_kw)
     subs = _submissions(cfg, lens=(10, 6, 8))
-    fused_p, _ = _run(cfg, subs, kv_layout="paged")
-    fused_d, _ = _run(cfg, subs, kv_layout="dense")
+    fused_p, _ = _run(cfg, subs)
     monkeypatch.setattr(chai_core, "USE_FUSED_DECODE", False)
-    reference, _ = _run(cfg, subs, kv_layout="paged")
+    reference, _ = _run(cfg, subs)
     for uid in reference:
         assert fused_p[uid].generated == reference[uid].generated, uid
-        assert fused_d[uid].generated == reference[uid].generated, uid
 
 
 # ------------------------------------------- the memory win, realized ------
 @pytest.mark.slow
 def test_steady_state_paged_chai_below_dense_mha():
-    """The acceptance criterion: with kv_layout='paged', the allocator's
-    steady-state CHAI footprint is BELOW the dense-MHA rectangle the
-    continuous engine previously kept resident — and the trajectory
-    shows the drop at compaction."""
+    """The acceptance criterion: the allocator's steady-state CHAI
+    footprint is BELOW the dense-MHA rectangle (every slot's K and V for
+    all of ``max_seq``) — and the trajectory shows the drop at
+    compaction."""
     cfg = _cfg(MHA_ARCH)
     subs = _submissions(cfg, lens=(24, 24), prompt_len=8)
-    _, eng = _run(cfg, subs, kv_layout="paged", max_seq=64)
+    _, eng = _run(cfg, subs, max_seq=64)
     hist = eng.kv_bytes_history
-    assert hist, "paged engine records its allocated-bytes trajectory"
-    dense_mha = chai_cache.unified_kv_bytes(cfg, 2, 64, chai=False)
+    assert hist, "the engine records its allocated-bytes trajectory"
+    dense_mha = chai_cache.kv_cache_bytes(cfg, 2, 64, chai=False)
     warm_peak = max(h["kv_bytes"] for h in hist)
     # steady state: every slot past CLUSTER (dense K pages freed)
     steady = [h for h in hist if h["step"] > cfg.chai.warmup_tokens + 1]
@@ -281,5 +277,3 @@ def test_steady_state_paged_chai_below_dense_mha():
     steady_bytes = steady[-1]["kv_bytes"]
     assert steady_bytes < warm_peak          # compaction freed pages
     assert steady_bytes < dense_mha          # CHAI saving, allocator-level
-    # and the dense unified layout cannot say the same
-    assert chai_cache.unified_kv_bytes(cfg, 2, 64, chai=True) > dense_mha

@@ -383,6 +383,11 @@ def test_orphaned_host_page_fails_the_audit():
 
 
 def test_kv_offload_requires_paged_layout():
-    cfg, params = _model()
+    """A model with no global attention layer has no page pools, so no
+    tier to offload into: kv_offload is refused."""
+    cfg = reduced(get_config("h2o-danube-1.8b"), n_layers=2, d_model=32,
+                  d_ff=64, vocab=64).replace(dtype="float32")
+    assert cfg.n_global_layers == 0
+    params = tfm.init_params(cfg, jax.random.PRNGKey(0))
     with pytest.raises(ValueError, match="kv_offload"):
-        EngineCore(cfg, params, _ecfg(kv_layout="dense", kv_offload=True))
+        EngineCore(cfg, params, _ecfg(use_chai=False, kv_offload=True))

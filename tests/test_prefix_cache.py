@@ -312,10 +312,12 @@ def test_eviction_under_pressure_then_no_leaks():
 
 
 def test_prefix_cache_config_validation():
-    cfg = _cfg(MHA_ARCH)
-    params = tfm.init_params(cfg, jax.random.PRNGKey(0))
-    with pytest.raises(ValueError):     # dense layout cannot share pages
-        _engine(cfg, params, kv_layout="dense")
+    dan = reduced(get_config("h2o-danube-1.8b"), n_layers=2, d_model=32,
+                  d_ff=64, vocab=64).replace(dtype="float32")
+    assert dan.n_global_layers == 0     # no pages to share
+    dp = tfm.init_params(dan, jax.random.PRNGKey(0))
+    with pytest.raises(ValueError):
+        _engine(dan, dp, use_chai=False)
     gem = reduced(get_config("gemma2-9b"), n_layers=2, d_model=32,
                   d_ff=64, vocab=64).replace(dtype="float32")
     if gem.n_local_layers:              # local rings are not paged

@@ -1,5 +1,5 @@
 """Per-request sampling: SamplingParams validation, the batched device
-sampler, and scheduler-independence of seeded runs.
+sampler, and batch-independence of seeded runs.
 
 The load-bearing guarantees:
 
@@ -7,11 +7,11 @@ The load-bearing guarantees:
   historical greedy path — regardless of top_k/top_p/seed, so every
   greedy parity/snapshot-replay guarantee survives the sampler.
 * Token n of a request draws from ``fold_in(PRNGKey(seed), n)``: seeded
-  temperature/top-k/top-p runs are reproducible run-to-run AND across
-  the continuous and cohort schedulers (different slot placements,
-  different batch shapes — same tokens).
-* Stop token ids and max_new_tokens finish requests identically under
-  both schedulers.
+  temperature/top-k/top-p runs are reproducible run-to-run AND between
+  requests served together and alone (different slot placements and
+  batch compositions — same tokens).
+* Stop token ids and max_new_tokens finish requests identically served
+  together or alone.
 """
 import numpy as np
 import pytest
@@ -34,15 +34,19 @@ def _cfg():
     return cfg.with_chai(enabled=True, warmup_tokens=3)
 
 
-def _run(cfg, scheduler, subs, *, slots=2, **ecfg_kw):
+def _run(cfg, subs, *, slots=2, alone=False, **ecfg_kw):
+    """Serve ``subs`` together, or with ``alone`` each by itself: one
+    ``run()`` per request, the engine's other slots FREE."""
     params = tfm.init_params(cfg, jax.random.PRNGKey(0))
     eng = ServingEngine(cfg, params,
                         EngineConfig(batch_slots=slots, max_seq=64,
-                                     scheduler=scheduler, **ecfg_kw))
-    for i, (prompt, sp) in enumerate(subs):
-        eng.submit(prompt, max_new_tokens=sp.max_new_tokens, uid=i,
-                   sampling=sp)
-    done = eng.run()
+                                     **ecfg_kw))
+    subs = list(enumerate(subs))
+    for wave in ([[s] for s in subs] if alone else [subs]):
+        for i, (prompt, sp) in wave:
+            eng.submit(prompt, max_new_tokens=sp.max_new_tokens, uid=i,
+                       sampling=sp)
+        done = eng.run()
     assert len(done) == len(subs)
     return {r.uid: r for r in done}
 
@@ -130,22 +134,20 @@ def test_sampler_top_k_top_p_restrict_support():
 @pytest.mark.slow
 def test_seeded_sampling_reproducible_across_schedulers():
     """Same prompts + per-request (temperature, top_k, top_p, seed):
-    token-for-token identical under the continuous scheduler (paged AND
-    dense layouts) and the cohort scheduler, and across repeat runs."""
+    token-for-token identical across repeat runs and between the
+    requests served together and each served alone."""
     cfg = _cfg()
     rng = np.random.default_rng(2)
     sps = [SamplingParams(temperature=0.8, top_k=16, top_p=0.95,
                           seed=100 + i, max_new_tokens=m)
            for i, m in enumerate((12, 5, 9, 7))]
     subs = [(rng.integers(0, cfg.vocab_size, size=8), sp) for sp in sps]
-    cont = _run(cfg, "continuous", subs)
-    cont2 = _run(cfg, "continuous", subs)
-    dense = _run(cfg, "continuous", subs, kv_layout="dense")
-    coh = _run(cfg, "cohort", subs)
+    cont = _run(cfg, subs)
+    cont2 = _run(cfg, subs)
+    alone = _run(cfg, subs, alone=True)
     for uid in cont:
         assert cont[uid].generated == cont2[uid].generated, uid   # rerun
-        assert cont[uid].generated == dense[uid].generated, uid   # layout
-        assert cont[uid].generated == coh[uid].generated, uid     # sched
+        assert cont[uid].generated == alone[uid].generated, uid   # batch
         assert len(cont[uid].generated) == sps[uid].max_new_tokens
         assert cont[uid].finish_reason == "length"
     # different seeds actually diverge (the sampler is not greedy)
@@ -153,7 +155,7 @@ def test_seeded_sampling_reproducible_across_schedulers():
                               seed=sp.seed + 1000,
                               max_new_tokens=sp.max_new_tokens))
            for p, sp in subs]
-    cont_alt = _run(cfg, "continuous", alt)
+    cont_alt = _run(cfg, alt)
     assert any(cont_alt[u].generated != cont[u].generated for u in cont)
 
 
@@ -165,9 +167,9 @@ def test_temperature_zero_engine_matches_legacy_greedy():
     cfg = _cfg()
     rng = np.random.default_rng(3)
     prompts = [rng.integers(0, cfg.vocab_size, size=8) for _ in range(3)]
-    legacy = _run(cfg, "continuous",
-                  [(p, SamplingParams(max_new_tokens=10)) for p in prompts])
-    explicit = _run(cfg, "continuous",
+    legacy = _run(cfg, [(p, SamplingParams(max_new_tokens=10))
+                        for p in prompts])
+    explicit = _run(cfg,
                     [(p, SamplingParams(temperature=0.0, top_k=5,
                                         top_p=0.5, seed=42,
                                         max_new_tokens=10))
@@ -179,22 +181,22 @@ def test_temperature_zero_engine_matches_legacy_greedy():
 @pytest.mark.slow
 def test_stop_tokens_finish_identically_across_schedulers():
     """A stop token retires the request early (reason "stop", stop token
-    kept) with identical truncation under both schedulers."""
+    kept) with identical truncation served together and alone."""
     cfg = _cfg()
     rng = np.random.default_rng(4)
     prompts = [rng.integers(0, cfg.vocab_size, size=8) for _ in range(3)]
     # pick stop ids from an unconstrained greedy run so they actually hit
-    probe = _run(cfg, "continuous",
-                 [(p, SamplingParams(max_new_tokens=12)) for p in prompts])
+    probe = _run(cfg, [(p, SamplingParams(max_new_tokens=12))
+                       for p in prompts])
     stops = tuple(int(probe[u].generated[5]) for u in probe)
     sps = [SamplingParams(stop_token_ids=stops, max_new_tokens=12)
            for _ in prompts]
-    cont = _run(cfg, "continuous", list(zip(prompts, sps)))
-    coh = _run(cfg, "cohort", list(zip(prompts, sps)))
+    cont = _run(cfg, list(zip(prompts, sps)))
+    alone = _run(cfg, list(zip(prompts, sps)), alone=True)
     hit_early = 0
     for uid in cont:
-        assert cont[uid].generated == coh[uid].generated, uid
-        assert cont[uid].finish_reason == coh[uid].finish_reason, uid
+        assert cont[uid].generated == alone[uid].generated, uid
+        assert cont[uid].finish_reason == alone[uid].finish_reason, uid
         if cont[uid].finish_reason == "stop":
             hit_early += 1
             assert cont[uid].generated[-1] in stops
